@@ -31,6 +31,7 @@ import time
 import numpy as np
 
 from benchmarks.common import ART, fitted_tree
+from repro import enable_compile_cache
 from repro.core import compile_tree
 from repro.core import (NonIdealSpec, apply_saf_mask, encode_inputs,
                         sample_saf, simulate)
@@ -194,6 +195,7 @@ def serving_chaos(dataset, seed) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--datasets", default="iris,cancer,car")
     ap.add_argument("--p-grid", default="0.005,0.02")

@@ -1,5 +1,6 @@
 """Shared benchmark infrastructure: dataset -> fitted/compiled DT2CAM with
-on-disk tree caching (Credit takes ~10s to fit; cache under artifacts/),
+on-disk tree caching (Credit takes ~10s to fit; cache under artifacts/,
+keyed on the training data, the fit parameters and the trainer's source),
 plus the seeding / artifact-writing conventions every benchmark follows:
 a ``--seed`` flag (``add_seed_arg``) and a JSON artifact whose content is
 fully seed-determined — wall-clock numbers go to stdout, never into the
@@ -7,6 +8,8 @@ file (``write_artifact``), so same flags + same seed => byte-identical
 artifact."""
 from __future__ import annotations
 
+import hashlib
+import inspect
 import json
 import os
 import time
@@ -42,11 +45,22 @@ def write_artifact(path: str, report) -> None:
     print(f"# wrote {path}")
 
 
+def _fit_key(spec, Xtr: np.ndarray, ytr: np.ndarray) -> str:
+    """Digest of everything that produces the tree: the training split, the
+    fit parameters and the trainer's source."""
+    h = hashlib.sha1(np.ascontiguousarray(Xtr).tobytes())
+    h.update(np.ascontiguousarray(ytr).tobytes())
+    h.update(repr((spec.max_depth, spec.max_leaves,
+                   spec.min_samples_leaf)).encode())
+    h.update(inspect.getsource(inspect.getmodule(train_tree)).encode())
+    return h.hexdigest()[:12]
+
+
 def fitted_tree(name: str) -> tuple[DecisionTree, tuple]:
     spec = DATASETS[name]
     os.makedirs(TREES, exist_ok=True)
-    path = os.path.join(TREES, f"{name}.npz")
     Xtr, ytr, Xte, yte = load_split(name)
+    path = os.path.join(TREES, f"{name}-{_fit_key(spec, Xtr, ytr)}.npz")
     if os.path.exists(path):
         z = np.load(path)
         tree = DecisionTree(z["feature"], z["threshold"], z["left"],

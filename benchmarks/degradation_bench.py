@@ -24,6 +24,7 @@ import threading
 
 import numpy as np
 
+from repro import enable_compile_cache
 from repro.core import DriftSpec, NonIdealSpec
 from repro.serve import ServeConfig, TCAMServer
 
@@ -147,6 +148,7 @@ def run_chaos(name: str, *, s: int, seed: int, requests: int = 256) -> dict:
 
 
 def main(argv=None) -> dict:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--datasets", nargs="+", default=["iris", "cancer"])
     ap.add_argument("--s", type=int, default=32)

@@ -6,6 +6,7 @@ decision; the paper also reports means).
 """
 import numpy as np
 
+from repro import enable_compile_cache
 from repro.core import synthesize
 from repro.core import encode_inputs, simulate
 
@@ -43,6 +44,7 @@ def run(datasets=None) -> list[dict]:
 
 
 def main():
+    enable_compile_cache()
     emit(run(), "Fig 6 — energy / throughput / EDP / SP reduction")
 
 

@@ -3,6 +3,7 @@
 Diabetes / Cancer / Covid at two tile sizes."""
 import numpy as np
 
+from repro import enable_compile_cache
 from repro.core import synthesize
 from repro.core import apply_saf, encode_inputs, noisy_inputs, simulate
 from repro.core import predict
@@ -61,6 +62,7 @@ def run(datasets=DATASETS, trials=TRIALS) -> list[dict]:
 
 
 def main():
+    enable_compile_cache()
     emit(run(), "Fig 7 — accuracy loss under non-idealities")
 
 

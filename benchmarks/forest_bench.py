@@ -17,7 +17,8 @@ import time
 
 import numpy as np
 
-from repro import ForestExecutor, compile_forest, forest_infer_ref, train_forest
+from repro import (ForestExecutor, compile_forest, enable_compile_cache,
+                   forest_infer_ref, train_forest)
 from repro.dt import load_split
 
 from .common import ART, emit
@@ -86,6 +87,7 @@ def run(
 
 
 def main(argv=None) -> dict:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="cancer")
     ap.add_argument("--banks", nargs="+", type=int, default=[1, 2, 4, 8])

@@ -18,6 +18,7 @@ import numpy as np
 
 import jax
 
+from repro import enable_compile_cache
 from repro.core import bitplanes, encode_inputs, simulate
 from repro.kernels import tcam_match_ref, tcam_match_packed_ref, pack_bits
 
@@ -72,6 +73,7 @@ def run() -> list[dict]:
 
 
 def main():
+    enable_compile_cache()
     emit(run(), "Kernel engines — functional throughput + TPU bytes model")
 
 

@@ -36,6 +36,7 @@ from repro import (
     ServeConfig,
     TCAMServer,
     WearTracker,
+    enable_compile_cache,
     encode_inputs,
     plan_delta,
     plan_full,
@@ -197,6 +198,7 @@ def run(dataset: str = "cancer", *, s: int = 128, n_requests: int = 1000,
 
 
 def main(argv=None) -> dict:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="cancer")
     ap.add_argument("--s", type=int, default=128)

@@ -4,6 +4,8 @@ import glob
 import json
 import os
 
+from repro import enable_compile_cache
+
 from .roofline import ART, cell_rows
 
 
@@ -39,6 +41,7 @@ def roofline_table(mesh="singlepod") -> str:
 
 
 def main():
+    enable_compile_cache()
     print("## Dry-run table\n")
     print(dryrun_table())
     print("\n## Roofline (single-pod)\n")

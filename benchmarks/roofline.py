@@ -30,6 +30,7 @@ import glob
 import json
 import os
 
+from repro import enable_compile_cache
 from repro.configs import ARCHS, SHAPES, get_config, shape_cells
 
 from .hlo_analysis import analyze_file
@@ -129,6 +130,7 @@ def _fmt(rows, md=False):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="singlepod",
                     choices=["singlepod", "multipod"])

@@ -3,8 +3,11 @@ the kernel-engine table.  ``python -m benchmarks.run [--fast]``."""
 import argparse
 import time
 
+from repro import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="skip the slow Credit / traffic-scale workloads")

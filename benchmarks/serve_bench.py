@@ -15,6 +15,7 @@ import time
 
 import numpy as np
 
+from repro import enable_compile_cache
 from repro.dt import load_split
 from repro.serve import ServeConfig, TCAMServer
 
@@ -69,6 +70,7 @@ def run(
 
 
 def main(argv=None) -> list[dict]:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--datasets", nargs="+", default=["iris", "cancer", "covid"])
     ap.add_argument("--requests", type=int, default=2048)

@@ -1,4 +1,5 @@
 """Paper Table IV: dynamic-range limit -> max cells/row -> chosen S."""
+from repro import enable_compile_cache
 from repro.core import choose_tile_size, dynamic_range, max_cells_per_row
 
 from .common import emit
@@ -25,6 +26,7 @@ def run() -> list[dict]:
 
 
 def main():
+    enable_compile_cache()
     emit(run(), "Table IV — D_cap limit vs TCAM row size (Eqn 6)")
 
 

@@ -4,6 +4,7 @@ Runs the full DT-HW compiler on every Table II dataset (embedded Iris +
 synthetic stand-ins, DESIGN.md §7) and reports LUT shape + N_rwd x N_cwd
 tiles for S in {16, 32, 64, 128}, side by side with the paper's values.
 """
+from repro import enable_compile_cache
 from repro.core import synthesize
 from repro.dt import DATASETS
 
@@ -30,6 +31,7 @@ def run() -> list[dict]:
 
 
 def main():
+    enable_compile_cache()
     emit(run(), "Table V — LUT sizes and tile counts")
 
 

@@ -11,6 +11,7 @@ import os
 
 import numpy as np
 
+from repro import enable_compile_cache
 from repro.core import compile_tree, train_tree
 from repro.core import DEFAULT_HW, encode_inputs, f_max, simulate
 
@@ -99,6 +100,7 @@ def run(n_inputs: int = 256) -> list[dict]:
 
 
 def main():
+    enable_compile_cache()
     emit(run(), "Table VI — SOTA comparison (traffic-scale LUT, S=128)")
 
 

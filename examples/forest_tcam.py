@@ -16,6 +16,7 @@ from repro.dt import load_split
 
 
 def main():
+    repro.enable_compile_cache()
     Xtr, ytr, Xte, yte = load_split("cancer")
 
     # one CART tree per TCAM bank, bagged

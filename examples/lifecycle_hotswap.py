@@ -28,6 +28,7 @@ from repro.serve import ServeConfig, TCAMServer
 
 
 def main():
+    repro.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="cancer")
     ap.add_argument("--s", type=int, default=128)

@@ -9,11 +9,13 @@ the TCAM-simulated accuracy equals the Python golden-DT accuracy.
 """
 import numpy as np
 
+from repro import enable_compile_cache
 from repro.core import DT2CAM, NonIdealSpec
 from repro.dt import load_split
 
 
 def main():
+    enable_compile_cache()
     Xtr, ytr, Xte, yte = load_split("iris")
     model = DT2CAM(s=16, max_depth=5).fit(Xtr, ytr)
 

@@ -17,12 +17,14 @@ import time
 
 import numpy as np
 
+from repro import enable_compile_cache
 from repro.core import compile_tree, train_tree
 from repro.dt import DATASETS, load_split
 from repro.serve import ServeConfig, TCAMServer
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="covid")
     ap.add_argument("--s", type=int, default=64)
@@ -56,9 +58,11 @@ def main():
 
     preds = np.array([r.prediction for r in results])
     acc = float((preds == yte[idx]).mean())
+    dev = stats["device"]   # jax.devices()[0], as JAX reports it
+    mode = "Pallas interpret mode" if stats["interpret"] else "compiled"
     print(f"served {len(results)} requests in {dt:.2f}s "
-          f"({len(results) / dt:.0f} req/s functional sim on "
-          f"{'CPU' if cfg.interpret is not False else 'TPU'}) "
+          f"({len(results) / dt:.0f} req/s on {dev['platform']} "
+          f"{dev['kind']}, {mode}) "
           f"in {stats['batches']} batches "
           f"(fill {stats['mean_batch_fill']:.2f}, "
           f"jit compiles {stats['jit_cache']['misses']})")

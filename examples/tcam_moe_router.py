@@ -15,6 +15,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import enable_compile_cache
 from repro.core import predict, train_tree
 from repro.models.config import ModelConfig
 from repro.models.moe import moe_ffn
@@ -23,6 +24,7 @@ from repro.models.tcam_router import compile_router, route_tcam
 
 
 def main():
+    enable_compile_cache()
     cfg = ModelConfig(
         name="moe_demo", family="moe", n_layers=1, d_model=64, n_heads=4,
         n_kv_heads=4, head_dim=16, d_ff=256, vocab_size=1024,

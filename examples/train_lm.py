@@ -14,6 +14,7 @@ import dataclasses
 
 import jax
 
+from repro import enable_compile_cache
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data import TokenPipeline
@@ -34,6 +35,7 @@ def hundred_m_config():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

@@ -139,6 +139,8 @@ __all__ = [
     "ForestExecutor", "FOREST_ENGINES",
     "TCAMServer", "ServeConfig", "RequestResult", "PromotionReport",
     "ServingError", "Rejected", "DeadlineExceeded", "ComputeFailed",
+    # jax-dependent (lazy): entry-point setup
+    "enable_compile_cache",
 ]
 
 _LAZY = {
@@ -159,6 +161,7 @@ _LAZY = {
     "Rejected": "serve",
     "DeadlineExceeded": "serve",
     "ComputeFailed": "serve",
+    "enable_compile_cache": "jax_cache",
 }
 
 

@@ -24,8 +24,7 @@ import numpy as np
 
 from ..core.energy import DEFAULT_HW, HardwareParams, forest_figures
 from ..core.encode import encode_inputs
-from ..kernels.banked import tcam_match_banked
-from ..kernels.ops import default_interpret
+from ..kernels.ops import default_interpret, match_cells, place_cells
 from ..serve.batching import BucketPolicy
 from ..serve.cache import CompileCache
 from .compiler import CompiledForest, ForestResult, aggregate_votes, forest_infer_ref
@@ -91,20 +90,24 @@ class ForestExecutor:
         self._kmax = (
             [g.kmax0 for g in self.plan.groups] if kmax is None else list(kmax)
         )
+        self._placed: dict = {}       # (engine, group) -> CellOperands
         self.cache = CompileCache(self._build, self.plan.plan_id)
 
     # -- compile machinery --------------------------------------------------
     def _build(self, bucket: int, key: str):
-        """One jit'd banked match per (batch-bucket, engine, group)."""
+        """One jit'd banked match per (batch-bucket, engine, group); the
+        group's grids are placed on the device once and passed as
+        arguments."""
         engine, gi = key.rsplit(":g", 1)
-        grp = self.plan.groups[int(gi)]
-        km = jnp.asarray(self._kmax[int(gi)])
-        run = functools.partial(
-            tcam_match_banked, grp.cells, s=grp.s, kmax=km, engine=engine,
-            block_b=self.block_b, block_r=self.block_r,
-            interpret=self.interpret,
-        )
-        return jax.jit(lambda xpad: run(xpad))
+        ops = self._placed.get(key)
+        if ops is None:
+            grp = self.plan.groups[int(gi)]
+            ops = self._placed[key] = place_cells(
+                grp.cells, grp.s, self._kmax[int(gi)], engine=engine,
+                block_r=self.block_r,
+            )
+        return functools.partial(match_cells, ops, block_b=self.block_b,
+                                 interpret=self.interpret)
 
     def _bucket_for(self, b: int) -> int:
         top = self.min_bucket

@@ -18,73 +18,24 @@ Engines:
              XLA kernel invocation for the whole group).
   'mxu'    — ``jax.vmap`` of the Pallas MXU bitplane kernel over the bank
              axis (one pallas_call whose grid covers every bank).
-  'ref'    — per-bank python loop over ``tcam_match_ref`` (oracle).
+  'ref'    — ``jax.vmap`` of the single-bank ``tcam_match_ref`` oracle.
+
+Serving paths place a group's planes once (``ops.place_cells``) and call
+``ops.match_cells`` per batch; ``tcam_match_banked`` does both in one call.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from ..core.lut import bitplanes
-from .ops import default_interpret
-from .ref import tcam_match_ref
-from .tcam_match import tcam_match_pallas
+from .ops import default_interpret, match_cells, place_cells
+from .ref import tcam_match_banked_ref
 
 __all__ = ["tcam_match_banked", "tcam_match_banked_ref", "BANKED_ENGINES"]
 
 BANKED_ENGINES = ("banked", "mxu", "ref")
-
-
-@functools.partial(jax.jit, static_argnames=("s",))
-def tcam_match_banked_ref(
-    xpad: jax.Array,    # (G, B, W) {0,1} search words, per-bank encodings
-    is0: jax.Array,     # (G, R, W)
-    is1: jax.Array,     # (G, R, W)
-    s: int,
-    kmax: jax.Array,    # (G, R, D) int32; -1 rows always mismatch
-) -> tuple[jax.Array, jax.Array]:
-    """Batched-einsum banked match: (survive, evals), both (G, B, R) int32."""
-    g, b, w = xpad.shape
-    r = is0.shape[1]
-    assert w % s == 0, (w, s)
-    d = w // s
-    x = xpad.astype(jnp.float32).reshape(g, b, d, s)
-    p0 = is0.astype(jnp.float32).reshape(g, r, d, s)
-    p1 = is1.astype(jnp.float32).reshape(g, r, d, s)
-    # (G, B, R, D) mismatch counts, exact in f32 (counts <= S < 2^24)
-    mism = jnp.einsum("gbds,grds->gbrd", x, p0) + jnp.einsum(
-        "gbds,grds->gbrd", 1.0 - x, p1
-    )
-    match = mism <= kmax[:, None].astype(jnp.float32)
-    if d == 1:
-        # single division: every row is evaluated exactly once and survives
-        # iff it matches — skip the cumprod (slow XLA constant-fold)
-        return (
-            match[:, :, :, 0].astype(jnp.int32),
-            jnp.ones((g, b, r), jnp.int32),
-        )
-    prior = jnp.cumprod(
-        jnp.concatenate(
-            [jnp.ones((g, b, r, 1), bool), match[:, :, :, :-1]], axis=3
-        ),
-        axis=3,
-    )
-    survive = (prior[:, :, :, -1] & match[:, :, :, -1]).astype(jnp.int32)
-    evals = prior.sum(axis=3).astype(jnp.int32)
-    return survive, evals
-
-
-def _pad_to(a: jax.Array, axis: int, mult: int) -> jax.Array:
-    pad = (-a.shape[axis]) % mult
-    if pad == 0:
-        return a
-    widths = [(0, 0)] * a.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(a, widths)
 
 
 def tcam_match_banked(
@@ -108,41 +59,5 @@ def tcam_match_banked(
             f"unknown banked engine {engine!r}; expected one of {BANKED_ENGINES}"
         )
     interpret = default_interpret() if interpret is None else interpret
-    g, r, w = cells.shape
-    b = xpad.shape[1]
-    assert w % s == 0, (w, s)
-    d = w // s
-    if kmax is None:
-        kmax = jnp.zeros((g, r, d), jnp.int32)
-    else:
-        kmax = jnp.asarray(kmax).astype(jnp.int32)
-
-    is0np, is1np = bitplanes(np.asarray(cells))
-    is0, is1 = jnp.asarray(is0np), jnp.asarray(is1np)
-    xpad = jnp.asarray(xpad)
-
-    if engine == "ref":
-        outs = [
-            tcam_match_ref(xpad[i], is0[i], is1[i], s, kmax[i])
-            for i in range(g)
-        ]
-        survive = jnp.stack([o[0] for o in outs])
-        evals = jnp.stack([o[1] for o in outs])
-        return survive, evals
-
-    if engine == "banked":
-        return tcam_match_banked_ref(xpad, is0, is1, s, kmax)
-
-    # engine == "mxu": vmap the Pallas kernel over the bank axis; pad batch
-    # and rows to block multiples (pad rows kmax = -1: always mismatch).
-    xp = _pad_to(xpad, 1, block_b)
-    i0 = _pad_to(is0, 1, block_r)
-    i1 = _pad_to(is1, 1, block_r)
-    km = jnp.pad(kmax, ((0, 0), (0, i0.shape[1] - r), (0, 0)),
-                 constant_values=-1)
-    kernel = functools.partial(
-        tcam_match_pallas, s=s, block_b=block_b, block_r=block_r,
-        interpret=interpret,
-    )
-    survive, evals = jax.vmap(kernel)(xp, i0, i1, km)
-    return survive[:, :b, :r], evals[:, :b, :r]
+    ops = place_cells(cells, s, kmax, engine=engine, block_r=block_r)
+    return match_cells(ops, xpad, block_b=block_b, interpret=interpret)

@@ -1,5 +1,6 @@
-"""Public jit'd TCAM-match ops: engine selection, padding, packing, and the
-JAX serving path (`tcam_infer`) that the examples / serving stack use.
+"""Public jit'd TCAM-match ops: engine selection, cell placement, padding,
+packing, and the JAX serving path (`tcam_infer`) that the examples / serving
+stack use.
 
 Engines:
   'mxu'    — float bitplane matmul kernel (tcam_match.py); handles every cell
@@ -8,13 +9,21 @@ Engines:
              requires S % 32 == 0 and no CELL_MM cells.
   'ref'    — pure-jnp oracle (ref.py).
   'auto'   — packed when legal, else mxu.
+  'banked' — batched einsum over a leading bank axis (forest groups only).
 
 All engines share the contract: inputs are the *padded search words* from
 ``TCAMLayout.pad_inputs`` (decoder bit + encoded features + padding) and the
 layout's cell grid; outputs are (survive, evals) as defined in ref.py.
+
+A cell grid is placed on the device once (``place_cells``) in the layout its
+engine's kernel reads, and every batch passes those arrays to the jitted
+match (``match_cells``) as arguments: the compiled program holds no cells, so
+one executable serves every grid of the same shape — after repair, scrub or
+a promotion too.  ``tcam_match`` does both steps in one call.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -26,12 +35,13 @@ from ..core.energy import DEFAULT_HW, HardwareParams, f_max, t_cwd
 from ..core.lut import CELL_MM, bitplanes
 from ..core.simulate import SimResult, sense_voltage
 from ..core.synth import TCAMLayout
-from .ref import pack_bits, tcam_match_packed_ref, tcam_match_ref
+from .ref import pack_bits, tcam_match_banked_ref, tcam_match_ref
 from .tcam_match import tcam_match_pallas
 from .tcam_packed import tcam_match_packed_pallas
 
 __all__ = ["tcam_match", "tcam_infer", "sa_kmax", "select_engine",
-           "finalize_result", "default_interpret", "ENGINES"]
+           "finalize_result", "default_interpret", "ENGINES", "CellOperands",
+           "place_cells", "match_cells", "serve_batch"]
 
 ENGINES = ("auto", "mxu", "packed", "ref")
 
@@ -58,6 +68,85 @@ def select_engine(cells: np.ndarray, s: int, engine: str = "auto") -> str:
     return engine
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["arrays"],
+                   meta_fields=["engine", "s", "rows", "block_r"])
+@dataclasses.dataclass(frozen=True)
+class CellOperands:
+    """A cell grid (optionally a stack of bank grids) on the device, in the
+    layout one engine reads.  ``arrays`` are jit arguments; the rest is
+    static.  'mxu' / 'packed' hold the division-major kernel operands with
+    rows padded to ``block_r`` (pad rows kmax = -1: always mismatch); 'ref' /
+    'banked' hold the (…, R, W) u8 bitplanes and (…, R, D) kmax."""
+
+    arrays: tuple
+    engine: str
+    s: int
+    rows: int
+    block_r: int
+
+
+def _division_major(a, width: int):
+    """(..., N, D·width) -> (..., D, N, width): one division per leading
+    slice."""
+    *lead, n, w = a.shape
+    return a.reshape(*lead, n, w // width, width).swapaxes(-3, -2)
+
+
+def _pack_words(bits: np.ndarray) -> np.ndarray:
+    """numpy twin of ``ref.pack_bits``: (..., W) {0,1} -> (..., W/32) u32,
+    bit i of word j = column 32·j + i."""
+    *lead, w = bits.shape
+    assert w % 32 == 0, w
+    b = bits.astype(np.uint32).reshape(*lead, w // 32, 32)
+    return (b << np.arange(32, dtype=np.uint32)).sum(-1, dtype=np.uint32)
+
+
+def place_cells(
+    cells: np.ndarray,            # (..., R, W) int8 cell states
+    s: int,
+    kmax=None,                    # (..., R, D) int32; None = ideal (zeros)
+    *,
+    engine: str,                  # a resolved engine: no 'auto'
+    block_r: int = 128,
+) -> CellOperands:
+    """Copy a cell grid to the device in ``engine``'s layout (see
+    ``CellOperands``).  Done once per grid; every batch reuses the arrays."""
+    cells = np.asarray(cells)
+    *lead, r, w = cells.shape
+    assert w % s == 0, (w, s)
+    d = w // s
+    km = (np.zeros((*lead, r, d), np.int32) if kmax is None
+          else np.asarray(kmax, np.int32))
+    is0, is1 = bitplanes(cells)
+    if engine in ("ref", "banked"):
+        arrays = (is0, is1, km)
+    elif engine in ("mxu", "packed"):
+        rows_pad = [(0, 0)] * len(lead) + [(0, (-r) % block_r), (0, 0)]
+        km = np.pad(km, rows_pad, constant_values=-1)
+        km = km.swapaxes(-1, -2)[..., None, :]              # (..., D, 1, R)
+        is0, is1 = np.pad(is0, rows_pad), np.pad(is1, rows_pad)
+        if engine == "mxu":
+            # (..., D, S, R) f32: the kernel's RHS, rows on the lanes
+            arrays = tuple(
+                _division_major(p, s).swapaxes(-2, -1).astype(np.float32)
+                for p in (is0, is1)
+            ) + (km,)
+        else:
+            if s % 32:
+                raise ValueError("packed engine needs S % 32 == 0")
+            arrays = tuple(
+                _division_major(_pack_words(p), s // 32).swapaxes(-2, -1)
+                for p in (is1, is0 | is1)                   # val, care
+            ) + (km,)
+    else:
+        raise ValueError(f"unknown engine {engine!r}")
+    return CellOperands(
+        arrays=tuple(jnp.asarray(a) for a in arrays),
+        engine=engine, s=s, rows=r, block_r=block_r,
+    )
+
+
 def _pad_to(a: jax.Array, axis: int, mult: int) -> jax.Array:
     n = a.shape[axis]
     pad = (-n) % mult
@@ -66,6 +155,41 @@ def _pad_to(a: jax.Array, axis: int, mult: int) -> jax.Array:
     widths = [(0, 0)] * a.ndim
     widths[axis] = (0, pad)
     return jnp.pad(a, widths)
+
+
+@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
+def match_cells(
+    ops: CellOperands,
+    xpad: jax.Array,              # (..., B, W) padded search words {0,1}
+    *,
+    block_b: int = 128,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """Match search words against placed cells; returns (survive, evals),
+    both (..., B, R) int32, selective-precharge semantics (see ref.py).
+    Leading axes are banks: the kernels are vmapped over them."""
+    *lead, b, _ = xpad.shape
+    s = ops.s
+    if ops.engine == "banked":
+        return tcam_match_banked_ref(xpad, *ops.arrays[:2], s, ops.arrays[2])
+    if ops.engine == "ref":
+        fn = lambda x, p0, p1, km: tcam_match_ref(x, p0, p1, s, km)
+    else:
+        # batch blocks: the TPU tile's 8 sublanes at least, block_b at most
+        bb = min(block_b, b + (-b) % 8)
+        if ops.engine == "packed":
+            kernel = tcam_match_packed_pallas
+            prep = lambda x: _division_major(pack_bits(x), s // 32)
+        else:
+            kernel = tcam_match_pallas
+            prep = lambda x: _division_major(x, s).astype(jnp.float32)
+        xpad = prep(_pad_to(xpad, -2, bb))
+        fn = functools.partial(kernel, block_b=bb, block_r=ops.block_r,
+                               interpret=interpret)
+    for _ in lead:
+        fn = jax.vmap(fn)
+    survive, evals = fn(xpad, *ops.arrays)
+    return survive[..., :b, :ops.rows], evals[..., :b, :ops.rows]
 
 
 def tcam_match(
@@ -82,44 +206,10 @@ def tcam_match(
     """Match search words against a tiled TCAM; returns (survive, evals),
     both (B, R) int32, selective-precharge semantics (see ref.py)."""
     interpret = default_interpret() if interpret is None else interpret
-    r, w = cells.shape
-    b = xpad.shape[0]
-    d = w // s
-    assert w % s == 0
     engine = select_engine(cells, s, engine)
-
-    kmax = jnp.zeros((r, d), jnp.int32) if kmax is None else kmax.astype(jnp.int32)
-    is0np, is1np = bitplanes(np.asarray(cells))
-
-    if engine == "ref":
-        surv, ev = tcam_match_ref(xpad, jnp.asarray(is0np), jnp.asarray(is1np),
-                                  s, kmax)
-        return surv, ev
-
-    # pad batch and rows to block multiples; padded kmax = -1 so pad rows
-    # mismatch immediately (sliced away anyway).
-    xp = _pad_to(jnp.asarray(xpad), 0, block_b)
-    is0 = _pad_to(jnp.asarray(is0np), 0, block_r)
-    is1 = _pad_to(jnp.asarray(is1np), 0, block_r)
-    km = jnp.pad(kmax, ((0, is0.shape[0] - r), (0, 0)), constant_values=-1)
-
-    if engine == "packed":
-        xq = pack_bits(xp)
-        val = pack_bits(is1)
-        care = pack_bits(jnp.asarray(is0np | is1np))
-        care = _pad_to(care, 0, block_r)
-        surv, ev = tcam_match_packed_pallas(
-            xq, val, care, km, s=s,
-            block_b=block_b, block_r=block_r, interpret=interpret,
-        )
-    elif engine == "mxu":
-        surv, ev = tcam_match_pallas(
-            xp, is0, is1, km, s=s,
-            block_b=block_b, block_r=block_r, interpret=interpret,
-        )
-    else:
-        raise ValueError(f"unknown engine {engine!r}")
-    return surv[:b, :r], ev[:b, :r]
+    ops = place_cells(cells, s, kmax, engine=engine, block_r=block_r)
+    return match_cells(ops, jnp.asarray(xpad), block_b=block_b,
+                       interpret=interpret)
 
 
 def sa_kmax(
@@ -165,6 +255,15 @@ def _finalize(survive, evals, classes):
     preds = jnp.where(n_survivors > 0, classes[jnp.maximum(survivors, 0)], 0)
     active_evals = evals.sum(axis=1)
     return preds.astype(jnp.int32), survivors, n_survivors, active_evals
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def serve_batch(ops: CellOperands, classes: jax.Array, xpad: jax.Array, *,
+                interpret: bool = False):
+    """One served batch: (B, W) padded search words -> (preds, survivors,
+    n_survivors, active_evals), the cells and classes passed as arguments."""
+    survive, evals = match_cells(ops, xpad, interpret=interpret)
+    return _finalize(survive, evals, classes)
 
 
 def finalize_result(

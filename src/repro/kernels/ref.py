@@ -21,10 +21,13 @@ Returns:
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-__all__ = ["tcam_match_ref", "tcam_match_packed_ref", "pack_bits"]
+__all__ = ["tcam_match_ref", "tcam_match_packed_ref", "tcam_match_banked_ref",
+           "pack_bits"]
 
 
 def tcam_match_ref(
@@ -102,4 +105,43 @@ def tcam_match_packed_ref(
     )
     survive = (prior[:, :, -1] & match[:, :, -1]).astype(jnp.int32)
     evals = prior.sum(axis=2).astype(jnp.int32)
+    return survive, evals
+
+
+@functools.partial(jax.jit, static_argnames=("s",))
+def tcam_match_banked_ref(
+    xpad: jax.Array,    # (G, B, W) {0,1} search words, per-bank encodings
+    is0: jax.Array,     # (G, R, W)
+    is1: jax.Array,     # (G, R, W)
+    s: int,
+    kmax: jax.Array,    # (G, R, D) int32; -1 rows always mismatch
+) -> tuple[jax.Array, jax.Array]:
+    """Batched-einsum banked match: (survive, evals), both (G, B, R) int32."""
+    g, b, w = xpad.shape
+    r = is0.shape[1]
+    assert w % s == 0, (w, s)
+    d = w // s
+    x = xpad.astype(jnp.float32).reshape(g, b, d, s)
+    p0 = is0.astype(jnp.float32).reshape(g, r, d, s)
+    p1 = is1.astype(jnp.float32).reshape(g, r, d, s)
+    # (G, B, R, D) mismatch counts, exact in f32 (counts <= S < 2^24)
+    mism = jnp.einsum("gbds,grds->gbrd", x, p0) + jnp.einsum(
+        "gbds,grds->gbrd", 1.0 - x, p1
+    )
+    match = mism <= kmax[:, None].astype(jnp.float32)
+    if d == 1:
+        # single division: every row is evaluated exactly once and survives
+        # iff it matches — skip the cumprod (slow XLA constant-fold)
+        return (
+            match[:, :, :, 0].astype(jnp.int32),
+            jnp.ones((g, b, r), jnp.int32),
+        )
+    prior = jnp.cumprod(
+        jnp.concatenate(
+            [jnp.ones((g, b, r, 1), bool), match[:, :, :, :-1]], axis=3
+        ),
+        axis=3,
+    )
+    survive = (prior[:, :, :, -1] & match[:, :, :, -1]).astype(jnp.int32)
+    evals = prior.sum(axis=3).astype(jnp.int32)
     return survive, evals

@@ -6,18 +6,23 @@ Hardware mapping of the paper's ReCAM array:
     (sequential) grid axis; TPU grids execute sequentially so the carried
     ``active`` block implements selective precharge *for free*,
   * match-line evaluation          -> two MXU matmuls per division:
-    ``mism = X·is0ᵀ + (1-X)·is1ᵀ`` (a don't-care cell sets neither plane and
+    ``mism = X·is0 + (1-X)·is1`` (a don't-care cell sets neither plane and
     contributes nothing — exactly the TCAM semantics),
-  * sense-amp threshold            -> ``mism <= kmax[row, division]``
+  * sense-amp threshold            -> ``mism <= kmax[division, row]``
     (kmax = 0 is ideal hardware; per-SA reference-voltage offsets lower to a
     precomputed integer tolerance, keeping the analog model out of the hot
     loop),
   * row-parallel tiles             -> the (batch-block × row-block) grid axes.
 
-Block shapes: X (Bb, S) · is0ᵀ (S, Rb) with Bb = Rb = 128 default — MXU-sized
-operands; the S (contraction) dimension is the physical TCAM row width, a
-power of two in {16..128} by Table IV, zero-padded to 128 lanes by Mosaic
-when smaller.
+Division-major layout: every operand carries the division as its leading
+axis, so each block's last two dimensions are either whole array dimensions
+(S, 1) or multiples of the (8, 128) TPU tile — for every S in {16..128}
+(Table IV) and any number of divisions:
+  X    (D, B, S)  block (Bb, S)   — search words of one division,
+  is0  (D, S, R)  block (S, Rb)   — planes pre-transposed: a plain X·P matmul,
+  kmax (D, 1, R)  block (1, Rb).
+The {0,1} operands arrive as f32 (``ops.place_cells`` / ``ops.match_cells``
+cast outside the kernel; Mosaic does not lower a u8 -> f32 cast).
 
 Outputs are revisited accumulator blocks (index map ignores the sequential
 axis), so the carry lives in VMEM without explicit scratch:
@@ -46,9 +51,9 @@ def _kernel(x_ref, is0_ref, is1_ref, kmax_ref, active_ref, evals_ref):
     x = x_ref[...]                                    # (Bb, S) f32 {0,1}
     # Two MXU matmuls; f32 accumulation is exact (counts <= S).
     mism = jnp.dot(
-        x, is0_ref[...].T, preferred_element_type=jnp.float32
-    ) + jnp.dot(1.0 - x, is1_ref[...].T, preferred_element_type=jnp.float32)
-    match = (mism <= kmax_ref[...].T.astype(jnp.float32)).astype(jnp.int32)
+        x, is0_ref[...], preferred_element_type=jnp.float32
+    ) + jnp.dot(1.0 - x, is1_ref[...], preferred_element_type=jnp.float32)
+    match = (mism <= kmax_ref[...].astype(jnp.float32)).astype(jnp.int32)
 
     act = active_ref[...]                             # carried across d
     evals_ref[...] += act                             # active => evaluated
@@ -56,40 +61,35 @@ def _kernel(x_ref, is0_ref, is1_ref, kmax_ref, active_ref, evals_ref):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("s", "block_b", "block_r", "interpret")
+    jax.jit, static_argnames=("block_b", "block_r", "interpret")
 )
 def tcam_match_pallas(
-    xbits: jax.Array,           # (B, W) — {0,1}, any dtype
-    is0: jax.Array,             # (R, W)
-    is1: jax.Array,             # (R, W)
-    kmax: jax.Array,            # (R, D) int32  (D = W // s)
+    x: jax.Array,               # (D, B, S) f32 {0,1}
+    is0: jax.Array,             # (D, S, R) f32
+    is1: jax.Array,             # (D, S, R) f32
+    kmax: jax.Array,            # (D, 1, R) int32
     *,
-    s: int,
     block_b: int = 128,
     block_r: int = 128,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns (survive (B,R) int32, evals (B,R) int32).  B % block_b == 0,
-    R % block_r == 0, W % s == 0 — callers pad via ``ops.tcam_match``."""
-    b, w = xbits.shape
-    r = is0.shape[0]
-    assert w % s == 0 and b % block_b == 0 and r % block_r == 0, (b, r, w, s)
-    d = w // s
-    assert kmax.shape == (r, d), (kmax.shape, (r, d))
-
-    x = xbits.astype(jnp.float32)
-    p0 = is0.astype(jnp.float32)
-    p1 = is1.astype(jnp.float32)
+    """Returns (survive (B,R) int32, evals (B,R) int32).  B % block_b == 0
+    and R % block_r == 0 — callers pad via ``ops.match_cells``."""
+    d, b, s = x.shape
+    r = is0.shape[2]
+    assert b % block_b == 0 and r % block_r == 0, (b, r, block_b, block_r)
+    assert is0.shape == is1.shape == (d, s, r), (is0.shape, (d, s, r))
+    assert kmax.shape == (d, 1, r), (kmax.shape, (d, 1, r))
 
     grid = (b // block_b, r // block_r, d)
-    survive, evals = pl.pallas_call(
+    return pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_b, s), lambda i, j, k: (i, k)),    # x
-            pl.BlockSpec((block_r, s), lambda i, j, k: (j, k)),    # is0
-            pl.BlockSpec((block_r, s), lambda i, j, k: (j, k)),    # is1
-            pl.BlockSpec((block_r, 1), lambda i, j, k: (j, k)),    # kmax
+            pl.BlockSpec((None, block_b, s), lambda i, j, k: (k, i, 0)),
+            pl.BlockSpec((None, s, block_r), lambda i, j, k: (k, 0, j)),
+            pl.BlockSpec((None, s, block_r), lambda i, j, k: (k, 0, j)),
+            pl.BlockSpec((None, 1, block_r), lambda i, j, k: (k, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((block_b, block_r), lambda i, j, k: (i, j)),
@@ -100,5 +100,4 @@ def tcam_match_pallas(
             jax.ShapeDtypeStruct((b, r), jnp.int32),
         ],
         interpret=interpret,
-    )(x, p0, p1, kmax.astype(jnp.int32))
-    return survive, evals
+    )(x, is0, is1, kmax)

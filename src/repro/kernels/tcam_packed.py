@@ -5,12 +5,16 @@ MXU kernel streams f32 bitplanes (8 bytes/cell for both planes); this kernel
 packs 32 cells into one uint32 word per plane (1/16 the bytes), and replaces
 the matmuls with XOR/AND + ``lax.population_count`` on the VPU:
 
-    mism[b, r] = Σ_w popcount((x[b, w] ^ val[r, w]) & care[r, w])
+    mism[b, r] = Σ_w popcount((x[b, w] ^ val[w, r]) & care[w, r])
 
-The selective-precharge carry and grid layout are identical to
-``tcam_match.py``.  CELL_MM (SAF-induced always-mismatch) is not
-representable packed — ``ops.tcam_match`` falls back to the MXU kernel when
-the LUT contains MM cells.
+The selective-precharge carry and the division-major layout are those of
+``tcam_match.py``, with SW = S/32 words per division in place of S bits:
+  X    (D, B, SW)  block (Bb, SW),
+  val  (D, SW, R)  block (SW, Rb)   — rows on the lanes,
+  care (D, SW, R)  block (SW, Rb),
+  kmax (D, 1, R)   block (1, Rb).
+CELL_MM (SAF-induced always-mismatch) is not representable packed —
+``ops.select_engine`` picks the MXU kernel when the LUT contains MM cells.
 
 The word loop is a static Python unroll (S/32 <= 4 words per division for
 Table IV sizes) of (Bb × Rb) broadcast compares — fully vectorized on the
@@ -37,50 +41,48 @@ def _kernel(sw: int, x_ref, val_ref, care_ref, kmax_ref, active_ref, evals_ref):
 
     mism = jnp.zeros(active_ref.shape, jnp.int32)
     for w in range(sw):  # static unroll: S/32 words per division
-        xw = x_ref[:, w][:, None]          # (Bb, 1) uint32
-        vw = val_ref[:, w][None, :]        # (1, Rb) uint32
-        cw = care_ref[:, w][None, :]
+        xw = x_ref[:, w:w + 1]             # (Bb, 1) uint32
+        vw = val_ref[w:w + 1, :]           # (1, Rb) uint32
+        cw = care_ref[w:w + 1, :]
         diff = (xw ^ vw) & cw              # (Bb, Rb)
         mism += jax.lax.population_count(diff).astype(jnp.int32)
 
-    match = (mism <= kmax_ref[...].T).astype(jnp.int32)
+    match = (mism <= kmax_ref[...]).astype(jnp.int32)
     act = active_ref[...]
     evals_ref[...] += act
     active_ref[...] = act * match
 
 
 @functools.partial(
-    jax.jit, static_argnames=("s", "block_b", "block_r", "interpret")
+    jax.jit, static_argnames=("block_b", "block_r", "interpret")
 )
 def tcam_match_packed_pallas(
-    xpacked: jax.Array,        # (B, W32) uint32
-    val: jax.Array,            # (R, W32) uint32
-    care: jax.Array,           # (R, W32) uint32
-    kmax: jax.Array,           # (R, D) int32, D = W32 // (s // 32)
+    xpacked: jax.Array,        # (D, B, SW) uint32
+    val: jax.Array,            # (D, SW, R) uint32 — packed is1
+    care: jax.Array,           # (D, SW, R) uint32 — packed (is0 | is1)
+    kmax: jax.Array,           # (D, 1, R) int32
     *,
-    s: int,                    # division width in bits (multiple of 32)
-    block_b: int = 256,
-    block_r: int = 256,
+    block_b: int = 128,
+    block_r: int = 128,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    b, w32 = xpacked.shape
-    r = val.shape[0]
-    assert s % 32 == 0
-    sw = s // 32
-    assert w32 % sw == 0 and b % block_b == 0 and r % block_r == 0
-    d = w32 // sw
-    assert kmax.shape == (r, d), (kmax.shape, (r, d))
+    """Returns (survive (B,R) int32, evals (B,R) int32).  B % block_b == 0
+    and R % block_r == 0 — callers pad via ``ops.match_cells``."""
+    d, b, sw = xpacked.shape
+    r = val.shape[2]
+    assert b % block_b == 0 and r % block_r == 0, (b, r, block_b, block_r)
+    assert val.shape == care.shape == (d, sw, r), (val.shape, (d, sw, r))
+    assert kmax.shape == (d, 1, r), (kmax.shape, (d, 1, r))
 
     grid = (b // block_b, r // block_r, d)
-    kern = functools.partial(_kernel, sw)
-    survive, evals = pl.pallas_call(
-        kern,
+    return pl.pallas_call(
+        functools.partial(_kernel, sw),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_b, sw), lambda i, j, k: (i, k)),
-            pl.BlockSpec((block_r, sw), lambda i, j, k: (j, k)),
-            pl.BlockSpec((block_r, sw), lambda i, j, k: (j, k)),
-            pl.BlockSpec((block_r, 1), lambda i, j, k: (j, k)),
+            pl.BlockSpec((None, block_b, sw), lambda i, j, k: (k, i, 0)),
+            pl.BlockSpec((None, sw, block_r), lambda i, j, k: (k, 0, j)),
+            pl.BlockSpec((None, sw, block_r), lambda i, j, k: (k, 0, j)),
+            pl.BlockSpec((None, 1, block_r), lambda i, j, k: (k, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((block_b, block_r), lambda i, j, k: (i, j)),
@@ -91,5 +93,4 @@ def tcam_match_packed_pallas(
             jax.ShapeDtypeStruct((b, r), jnp.int32),
         ],
         interpret=interpret,
-    )(xpacked, val, care, kmax.astype(jnp.int32))
-    return survive, evals
+    )(xpacked, val, care, kmax)

@@ -83,8 +83,8 @@ from ..core.nonideal import (
 )
 from ..degradation import ScrubPolicy, ScrubReport, ScrubScheduler, \
     layout_margins
-from ..kernels.banked import tcam_match_banked
-from ..kernels.ops import _finalize, sa_kmax, select_engine, tcam_match
+from ..kernels.ops import (default_interpret, match_cells, place_cells,
+                           sa_kmax, select_engine, serve_batch)
 from ..reliability.bist import BistReport, run_bist
 from ..reliability.canary import CanaryProbe, CircuitBreaker, make_canary
 from ..reliability.repair import RepairReport, repair_layout
@@ -96,6 +96,13 @@ from .metrics import ServeMetrics
 __all__ = ["PromotionReport", "RequestResult", "ServeConfig", "TCAMServer"]
 
 
+def _device() -> dict:
+    """The device the batch functions run on, as JAX reports it."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
 @dataclasses.dataclass(frozen=True)
 class ServeConfig:
     """Knobs of the serving engine (see module docstring)."""
@@ -104,7 +111,8 @@ class ServeConfig:
     max_delay_s: float = 0.002    # oldest-request queueing deadline
     min_bucket: int = 8           # smallest padded batch shape
     engine: str = "auto"          # 'auto' | 'mxu' | 'packed' | 'ref'
-    interpret: Optional[bool] = None   # Pallas interpret mode (None = auto)
+    interpret: Optional[bool] = None   # Pallas interpret mode (None: on
+                                       # unless the backend is a TPU)
     background: bool = True       # worker thread vs explicit pump()/drain()
     # -- serving protections ----------------------------------------------
     max_queue: Optional[int] = None    # admission control: shed when this
@@ -226,6 +234,9 @@ class TCAMServer:
         self._spec = nonideal
         self._clock = clock
         self._rng = rng or np.random.default_rng(0)
+        # resolved once: what the server runs is what metrics() reports
+        self.interpret = (default_interpret() if config.interpret is None
+                          else config.interpret)
         self.metrics_store = ServeMetrics()
         # endurance ledger shared with the lifecycle subsystem: refresh
         # pulses and redeploy pulses debit the same per-cell counts
@@ -421,7 +432,7 @@ class TCAMServer:
     def _make_cache(self, builder=None, layout_id: Optional[str] = None
                     ) -> CompileCache:
         return CompileCache(
-            builder if builder is not None else self._build,
+            builder if builder is not None else self._builder(),
             layout_id if layout_id is not None else self._layout_id(),
             maxsize=self._config.compile_cache_size,
         )
@@ -465,44 +476,51 @@ class TCAMServer:
             self.metrics_store.on_fallback()
             return "mxu"
 
-    def _build(self, bucket: int, engine: str):
-        """One jit'd batch function per (bucket, engine): (bucket, W) padded
-        search words -> (preds, survivors, n_survivors, active_evals).
-        Forest mode builds one jit'd banked match per plan group instead."""
+    def _builder(self):
+        """Batch-function builder for the live chip state: one jit'd batch
+        function per (bucket, engine) — (bucket, W) padded search words ->
+        (preds, survivors, n_survivors, active_evals).  Forest mode builds
+        one jit'd banked match per plan group instead."""
         if self._forest is not None:
-            return self._build_forest(bucket, engine)
-        return self._build_for(self._layout, self._kmax, bucket, engine)
+            return self._forest_builder()
+        return self._single_builder(self._layout, self._kmax)
 
-    def _build_for(self, layout, kmax, bucket: int, engine: str):
-        """Single-model batch function for an explicit chip state — shared
-        by the live path and the staged candidate's own compile cache."""
-        interpret = self._config.interpret
+    def _single_builder(self, layout, kmax):
+        """Single-model builder for an explicit chip state — shared by the
+        live path and the staged candidate's own compile cache.
+
+        The grid is placed on the device once per engine and enters the
+        jitted ``serve_batch`` as arguments: every bucket shares one copy,
+        and a cache rebuilt after repair, scrub or promotion places the new
+        grid without recompiling."""
+        placed = {}
         classes = jnp.asarray(layout.classes)
-        km = None if kmax is None else jnp.asarray(kmax)
 
-        @jax.jit
-        def run(xpad: jax.Array):
-            survive, evals = tcam_match(
-                layout.cells, xpad, layout.s, km,
-                engine=engine, interpret=interpret,
-            )
-            return _finalize(survive, evals, classes)
+        def build(bucket: int, engine: str):
+            if engine not in placed:
+                placed[engine] = place_cells(layout.cells, layout.s, kmax,
+                                             engine=engine)
+            return functools.partial(serve_batch, placed[engine], classes,
+                                     interpret=self.interpret)
 
-        return run
+        return build
 
-    def _build_forest(self, bucket: int, engine: str):
-        """Forest compute for one (bucket, engine): a list of jit'd banked
-        match functions, one per plan group — each evaluates its whole stack
-        of banks in a single kernel invocation."""
-        interpret = self._config.interpret
-        fns = []
-        for grp, km in zip(self._f_plan.groups, self._f_group_kmax):
-            run = functools.partial(
-                tcam_match_banked, grp.cells, s=grp.s,
-                kmax=jnp.asarray(km), engine=engine, interpret=interpret,
-            )
-            fns.append(jax.jit(lambda xpad, run=run: run(xpad)))
-        return fns
+    def _forest_builder(self):
+        """Forest builder: per (bucket, engine), a list of banked matches,
+        one per plan group — each evaluates its whole stack of banks in a
+        single kernel invocation on the group's placed grids."""
+        groups = list(zip(self._f_plan.groups, self._f_group_kmax))
+        placed = {}
+
+        def build(bucket: int, engine: str):
+            if engine not in placed:
+                placed[engine] = [place_cells(g.cells, g.s, km, engine=engine)
+                                  for g, km in groups]
+            return [functools.partial(match_cells, ops,
+                                      interpret=self.interpret)
+                    for ops in placed[engine]]
+
+        return build
 
     def warmup(self) -> int:
         """Pre-compile every bucket shape for the resolved engine so no
@@ -938,8 +956,7 @@ class TCAMServer:
             kmax = sa_kmax(lay, offsets, self._hw)
         engine = self._resolve_engine(self._config.engine, lay)
         cache = self._make_cache(
-            functools.partial(self._build_for, lay, kmax),
-            self._layout_id(lay),
+            self._single_builder(lay, kmax), self._layout_id(lay),
         )
         n_canary = min(self._config.canary_size, self._config.max_batch)
         canary = (make_canary(candidate.layout, n_canary, self._rng)
@@ -1475,6 +1492,8 @@ class TCAMServer:
             agg = figs["aggregate"]
             return self.metrics_store.snapshot(
                 engine=self.engine,
+                interpret=self.interpret,
+                device=_device(),
                 buckets=list(self.policy.buckets),
                 jit_cache=self.cache.stats(),
                 health=self.health(),
@@ -1496,6 +1515,8 @@ class TCAMServer:
         fm = f_max(lay.s, hw)
         return self.metrics_store.snapshot(
             engine=self.engine,
+            interpret=self.interpret,
+            device=_device(),
             buckets=list(self.policy.buckets),
             jit_cache=self.cache.stats(),
             health=self.health(),
