@@ -1,0 +1,7 @@
+"""The benchmark's tests import its modules by name, as ``bench/run.py``
+does, and the program from ``src/``."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+sys.path[:0] = [str(BENCH), str(BENCH.parent / "src")]
