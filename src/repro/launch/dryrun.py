@@ -16,15 +16,13 @@ and records, per cell:
   * memory_analysis  — per-device argument/temp/output bytes (proves fit),
   * cost_analysis    — per-device HLO FLOPs & bytes accessed,
   * collective bytes — parsed from the partitioned HLO, by collective type,
-into ``artifacts/dryrun/<cell>.json`` — the roofline analysis
-(benchmarks/roofline.py, EXPERIMENTS.md §Roofline) is derived from these.
+into ``artifacts/dryrun/<cell>.json``.
 
 Usage:
   python -m repro.launch.dryrun --arch olmo_1b --shape train_4k
   python -m repro.launch.dryrun --all [--multipod-only | --singlepod-only]
 """
 import argparse
-import gzip
 import json
 import re
 import time
@@ -165,9 +163,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         path = os.path.join(ART_DIR, f"{arch}__{shape_name}__{tag}.json")
         with open(path, "w") as f:
             json.dump(rec, f, indent=1)
-        # gzipped partitioned HLO for the loop-aware roofline analyzer
-        with gzip.open(path.replace(".json", ".hlo.gz"), "wt") as f:
-            f.write(compiled.as_text())
     return rec
 
 

@@ -55,6 +55,7 @@ via ``pump()``/``drain()``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import hashlib
@@ -91,7 +92,7 @@ from ..reliability.repair import RepairReport, repair_layout
 from .batching import AdaptiveBatcher, BucketPolicy
 from .cache import CompileCache
 from .errors import ComputeFailed, DeadlineExceeded, Rejected
-from .metrics import ServeMetrics
+from .metrics import PHASES, ServeMetrics
 
 __all__ = ["PromotionReport", "RequestResult", "ServeConfig", "TCAMServer"]
 
@@ -150,6 +151,8 @@ class RequestResult:
     compute_s: float              # batch dispatch -> results ready
     bucket: int                   # padded batch shape it rode in
     engine: str
+    batch: int = -1               # id of its batch: the batch record's and
+                                  # the serve.* spans' ``batch``
 
     @property
     def total_s(self) -> float:
@@ -161,6 +164,39 @@ class _Request:
     x: np.ndarray
     future: Future
     deadline: Optional[float] = None   # absolute clock time; None = no limit
+
+
+class _BatchSpans:
+    """One batch's spans and copies.  ``span(phase)`` is a profiler
+    annotation ``serve.<phase>`` carrying the batch id, so it lands in the
+    same trace as the device's operations, on that trace's clock; its
+    seconds on the server's clock add to the phase's total (a phase entered
+    once per plan group sums over the groups).  ``h2d_bytes`` and
+    ``d2h_bytes`` count the arrays copied to and from the device."""
+
+    def __init__(self, batch: int, clock: Callable[[], float]) -> None:
+        self.batch = batch
+        self._clock = clock
+        self.t_form = clock()
+        self.seconds = dict.fromkeys(PHASES, 0.0)
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
+
+    @contextlib.contextmanager
+    def span(self, phase: str):
+        with jax.profiler.TraceAnnotation(f"serve.{phase}", batch=self.batch):
+            t = self._clock()
+            try:
+                yield
+            finally:
+                self.seconds[phase] += self._clock() - t
+
+    def record(self, metrics: ServeMetrics, n: int, bucket: int) -> None:
+        """Close the batch's own span and write its record."""
+        self.seconds["batch"] = self._clock() - self.t_form
+        metrics.on_batch_record(self.batch, self.t_form, n, bucket,
+                                self.seconds.values(), self.h2d_bytes,
+                                self.d2h_bytes)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -292,6 +328,7 @@ class TCAMServer:
         )
         self._cond = threading.Condition()
         self._outstanding = 0
+        self._batches_formed = 0      # the next batch's id
         self._stop = False
         self._closed = False
         self._thread: Optional[threading.Thread] = None
@@ -706,78 +743,102 @@ class TCAMServer:
         self._maybe_scrub()
 
     def _process_inner(self, batch: list, deadline_flush: bool) -> None:
+        """One batch under the model lock, inside its ``serve.batch`` span,
+        whose record is written once the batch has released its requests."""
         with self._model_lock:
-            if self._forest is not None:
-                self._process_inner_forest(batch, deadline_flush)
-            else:
-                self._process_inner_single(batch, deadline_flush)
+            sp = _BatchSpans(self._batches_formed, self._clock)
+            self._batches_formed += 1
+            with jax.profiler.TraceAnnotation("serve.batch", batch=sp.batch):
+                run = (self._process_inner_single if self._forest is None
+                       else self._process_inner_forest)
+                bucket = run(batch, deadline_flush, sp)
+                sp.record(self.metrics_store, len(batch), bucket)
 
-    def _process_inner_single(self, batch: list, deadline_flush: bool) -> None:
-        t_form = self._clock()
+    def _process_inner_single(self, batch: list, deadline_flush: bool,
+                              sp: _BatchSpans) -> int:
+        t_form = sp.t_form
         reqs: Sequence[_Request] = [p.item for p in batch]
         queue_lat = np.array([t_form - p.t_enqueue for p in batch])
         n = len(reqs)
         bucket = self.policy.bucket_for(n)
 
-        X = np.stack([r.x for r in reqs])
-        if self.fault_injection_hook is not None:
-            self.fault_injection_hook(X)
-        if self._spec.sigma_in > 0:
-            X = X + self._rng.normal(0.0, self._spec.sigma_in, size=X.shape)
-        xbits = encode_inputs(self._lut, X)
-        xpad = self._layout.pad_inputs(xbits)
-        if bucket > n:
-            xpad = np.pad(xpad, ((0, bucket - n), (0, 0)))
+        with sp.span("encode"):
+            X = np.stack([r.x for r in reqs])
+            if self.fault_injection_hook is not None:
+                self.fault_injection_hook(X)
+            if self._spec.sigma_in > 0:
+                X = X + self._rng.normal(0.0, self._spec.sigma_in,
+                                         size=X.shape)
+            xbits = encode_inputs(self._lut, X)
+            xpad = self._layout.pad_inputs(xbits)
+            if bucket > n:
+                xpad = np.pad(xpad, ((0, bucket - n), (0, 0)))
 
-        fn = self.cache.get(bucket, self.engine)
-        out = fn(jnp.asarray(xpad))
-        jax.block_until_ready(out)
+        with sp.span("dispatch"):
+            fn = self.cache.get(bucket, self.engine)
+            out = fn(jnp.asarray(xpad))
+            sp.h2d_bytes += xpad.nbytes
+        with sp.span("device_wait"):
+            jax.block_until_ready(out)
         compute_s = self._clock() - t_form
-        if self._scrub is not None:
-            # this batch was served by the pre-advance chip state; the clock
-            # ticks and the read-disturb counters accumulate afterwards
-            self._scrub.advance(self._config.time_per_batch_s)
-            self._scrub.note_reads(n)
 
-        preds, survivors, nsurv, active = (np.asarray(o)[:n] for o in out)
-        # shadow deployment: mirror this (post-noise) batch to the staged
-        # candidate before resolving futures — a candidate-side failure must
-        # not fail, retry, or double-resolve the live batch
-        cand = self._candidate
-        if cand is not None and self._mirror_due(cand):
-            self._shadow_mirror(cand, X, bucket, preds)
-        active = active.astype(np.int64)
-        energy = active.astype(np.float64) * self._hw.e_row + self._hw.e_mem
+        with sp.span("d2h"):
+            host = [np.asarray(o) for o in out]
+            sp.d2h_bytes += sum(o.nbytes for o in host)
+        with sp.span("finalize"):
+            if self._scrub is not None:
+                # this batch was served by the pre-advance chip state; the
+                # clock ticks and the read-disturb counters accumulate
+                # afterwards
+                self._scrub.advance(self._config.time_per_batch_s)
+                self._scrub.note_reads(n)
+            preds, survivors, nsurv, active = (o[:n] for o in host)
+            # shadow deployment: mirror this (post-noise) batch to the
+            # staged candidate before resolving futures — a candidate-side
+            # failure must not fail, retry, or double-resolve the live batch
+            cand = self._candidate
+            if cand is not None and self._mirror_due(cand):
+                self._shadow_mirror(cand, X, bucket, preds)
+            active = active.astype(np.int64)
+            energy = (active.astype(np.float64) * self._hw.e_row
+                      + self._hw.e_mem)
 
-        self.metrics_store.on_batch(
-            n, bucket,
-            deadline_flush=deadline_flush,
-            energy_j=float(energy.sum()),
-            active_evals=int(active.sum()),
-        )
-        self.metrics_store.queue.record_many(queue_lat)
-        self.metrics_store.compute.record(compute_s)
-        self.metrics_store.total.record_many(queue_lat + compute_s)
-
-        for i, req in enumerate(reqs):
-            req.future.set_result(
-                RequestResult(
-                    prediction=int(preds[i]),
-                    survivor=int(survivors[i]),
-                    n_survivors=int(nsurv[i]),
-                    active_evals=int(active[i]),
-                    energy_j=float(energy[i]),
-                    queue_s=float(queue_lat[i]),
-                    compute_s=compute_s,
-                    bucket=bucket,
-                    engine=self.engine,
-                )
+        with sp.span("resolve"):
+            self.metrics_store.on_batch(
+                n, bucket,
+                deadline_flush=deadline_flush,
+                energy_j=float(energy.sum()),
+                active_evals=int(active.sum()),
             )
-        with self._cond:
-            self._outstanding -= n
-            self._cond.notify_all()
+            self.metrics_store.queue.record_many(queue_lat)
+            self.metrics_store.compute.record(compute_s)
+            self.metrics_store.total.record_many(queue_lat + compute_s)
 
-    def _process_inner_forest(self, batch: list, deadline_flush: bool) -> None:
+            for i, req in enumerate(reqs):
+                req.future.set_result(
+                    RequestResult(
+                        prediction=int(preds[i]),
+                        survivor=int(survivors[i]),
+                        n_survivors=int(nsurv[i]),
+                        active_evals=int(active[i]),
+                        energy_j=float(energy[i]),
+                        queue_s=float(queue_lat[i]),
+                        compute_s=compute_s,
+                        bucket=bucket,
+                        engine=self.engine,
+                        batch=sp.batch,
+                    )
+                )
+            with self._cond:
+                self._outstanding -= n
+                self._cond.notify_all()
+            # the outputs go last and inside the span: the callers' threads
+            # often hold the interpreter lock while they are freed
+            del out, host
+        return bucket
+
+    def _process_inner_forest(self, batch: list, deadline_flush: bool,
+                              sp: _BatchSpans) -> int:
         """Forest-mode batch: pipelined per-group compute + vote aggregation.
 
         Group g+1's host-side input encoding overlaps group g's device
@@ -788,86 +849,103 @@ class TCAMServer:
         from ..forest.executor import encode_group
 
         forest = self._forest
-        t_form = self._clock()
+        t_form = sp.t_form
         reqs: Sequence[_Request] = [p.item for p in batch]
         queue_lat = np.array([t_form - p.t_enqueue for p in batch])
         n = len(reqs)
         bucket = self.policy.bucket_for(n)
 
-        X = np.stack([r.x for r in reqs])
-        if self.fault_injection_hook is not None:
-            self.fault_injection_hook(X)
-        if self._spec.sigma_in > 0:
-            X = X + self._rng.normal(0.0, self._spec.sigma_in, size=X.shape)
-        Xp = forest.prepare_inputs(X, who="TCAMServer")
+        with sp.span("encode"):
+            X = np.stack([r.x for r in reqs])
+            if self.fault_injection_hook is not None:
+                self.fault_injection_hook(X)
+            if self._spec.sigma_in > 0:
+                X = X + self._rng.normal(0.0, self._spec.sigma_in,
+                                         size=X.shape)
+            Xp = forest.prepare_inputs(X, who="TCAMServer")
 
         fns = self.cache.get(bucket, self.engine)
         pending = []
         for grp, fn in zip(self._f_plan.groups, fns):
-            xpad = encode_group(forest, grp, Xp)
-            if bucket > n:
-                xpad = np.pad(xpad, ((0, 0), (0, bucket - n), (0, 0)))
-            pending.append((grp, fn(jnp.asarray(xpad))))
+            with sp.span("encode"):
+                xpad = encode_group(forest, grp, Xp)
+                if bucket > n:
+                    xpad = np.pad(xpad, ((0, 0), (0, bucket - n), (0, 0)))
+            with sp.span("dispatch"):
+                pending.append((grp, fn(jnp.asarray(xpad))))
+                sp.h2d_bytes += xpad.nbytes
 
         survivors = np.empty((forest.n_banks, n), np.int32)
         n_survivors = np.empty((forest.n_banks, n), np.int32)
         active = np.empty((forest.n_banks, n), np.int64)
         for grp, out in pending:
-            jax.block_until_ready(out)
-            survive, evals = (np.asarray(o) for o in out)
-            for slot, bank_id in enumerate(grp.bank_ids):
-                i = int(bank_id)
-                rows_i = int(grp.rows[slot])
-                sv = survive[slot, :n, :rows_i]
-                ns = sv.sum(axis=1).astype(np.int32)
-                first = np.argmax(sv, axis=1).astype(np.int32)
-                # translate physical rows (spares after repair) to LUT rows
-                rm = self._f_row_map[i]
-                survivors[i] = np.where(ns > 0, rm[first], -1)
-                n_survivors[i] = ns
-                ev = np.minimum(evals[slot, :n, :rows_i],
-                                int(grp.d_real[slot]))
-                active[i] = ev.sum(axis=1).astype(np.int64)
+            with sp.span("device_wait"):
+                jax.block_until_ready(out)
+            with sp.span("d2h"):
+                survive, evals = (np.asarray(o) for o in out)
+                sp.d2h_bytes += survive.nbytes + evals.nbytes
+            with sp.span("finalize"):
+                for slot, bank_id in enumerate(grp.bank_ids):
+                    i = int(bank_id)
+                    rows_i = int(grp.rows[slot])
+                    sv = survive[slot, :n, :rows_i]
+                    ns = sv.sum(axis=1).astype(np.int32)
+                    first = np.argmax(sv, axis=1).astype(np.int32)
+                    # translate physical rows (spares after repair) to LUT
+                    # rows
+                    rm = self._f_row_map[i]
+                    survivors[i] = np.where(ns > 0, rm[first], -1)
+                    n_survivors[i] = ns
+                    ev = np.minimum(evals[slot, :n, :rows_i],
+                                    int(grp.d_real[slot]))
+                    active[i] = ev.sum(axis=1).astype(np.int64)
         compute_s = self._clock() - t_form
 
-        predictions, _score = aggregate_votes(
-            forest, survivors, self._f_enabled
-        )
-        enabled = self._f_enabled
-        n_voting = int(enabled.sum())
-        active_total = active[enabled].sum(axis=0)
-        energy = (active_total.astype(np.float64) * self._hw.e_row
-                  + n_voting * self._hw.e_mem)
-
-        self.metrics_store.on_batch(
-            n, bucket,
-            deadline_flush=deadline_flush,
-            energy_j=float(energy.sum()),
-            active_evals=int(active_total.sum()),
-        )
-        self.metrics_store.queue.record_many(queue_lat)
-        self.metrics_store.compute.record(compute_s)
-        self.metrics_store.total.record_many(queue_lat + compute_s)
-
-        for i, req in enumerate(reqs):
-            pred = predictions[i]
-            req.future.set_result(
-                RequestResult(
-                    prediction=(int(pred) if np.issubdtype(
-                        np.asarray(pred).dtype, np.integer) else pred),
-                    survivor=-1,   # ensemble decision: no single row
-                    n_survivors=int((n_survivors[enabled, i] > 0).sum()),
-                    active_evals=int(active_total[i]),
-                    energy_j=float(energy[i]),
-                    queue_s=float(queue_lat[i]),
-                    compute_s=compute_s,
-                    bucket=bucket,
-                    engine=self.engine,
-                )
+        with sp.span("finalize"):
+            predictions, _score = aggregate_votes(
+                forest, survivors, self._f_enabled
             )
-        with self._cond:
-            self._outstanding -= n
-            self._cond.notify_all()
+            enabled = self._f_enabled
+            n_voting = int(enabled.sum())
+            active_total = active[enabled].sum(axis=0)
+            energy = (active_total.astype(np.float64) * self._hw.e_row
+                      + n_voting * self._hw.e_mem)
+
+        with sp.span("resolve"):
+            self.metrics_store.on_batch(
+                n, bucket,
+                deadline_flush=deadline_flush,
+                energy_j=float(energy.sum()),
+                active_evals=int(active_total.sum()),
+            )
+            self.metrics_store.queue.record_many(queue_lat)
+            self.metrics_store.compute.record(compute_s)
+            self.metrics_store.total.record_many(queue_lat + compute_s)
+
+            for i, req in enumerate(reqs):
+                pred = predictions[i]
+                req.future.set_result(
+                    RequestResult(
+                        prediction=(int(pred) if np.issubdtype(
+                            np.asarray(pred).dtype, np.integer) else pred),
+                        survivor=-1,   # ensemble decision: no single row
+                        n_survivors=int((n_survivors[enabled, i] > 0).sum()),
+                        active_evals=int(active_total[i]),
+                        energy_j=float(energy[i]),
+                        queue_s=float(queue_lat[i]),
+                        compute_s=compute_s,
+                        bucket=bucket,
+                        engine=self.engine,
+                        batch=sp.batch,
+                    )
+                )
+            with self._cond:
+                self._outstanding -= n
+                self._cond.notify_all()
+            # the groups' (G, B, R) outputs, on the device and on the host,
+            # go last and inside the span: freeing them takes tens of ms
+            del pending, out, survive, evals, sv
+        return bucket
 
     # -- lifecycle: shadow deployment, promotion, rollback ------------------
     _SWAP_ATTRS = ("_lut", "_intent", "_saf_mask", "_layout", "_base_layout",
@@ -1261,10 +1339,12 @@ class TCAMServer:
         if self._batches_since_canary < self._config.canary_every_batches:
             return
         self._batches_since_canary = 0
-        acc = self.run_canary()
-        if self.breaker.observe(acc):
-            self.metrics_store.on_trip()
-            self._recover()
+        with jax.profiler.TraceAnnotation(
+                "serve.canary", batch=self._batches_formed - 1):
+            acc = self.run_canary()
+            if self.breaker.observe(acc):
+                self.metrics_store.on_trip()
+                self._recover()
 
     def _recover(self) -> None:
         """Degradation ladder: scrub drifted rows, then repair the chip,
@@ -1405,7 +1485,9 @@ class TCAMServer:
         if self._batches_since_scrub < self._config.scrub_every_batches:
             return
         self._batches_since_scrub = 0
-        self.scrub_now()
+        with jax.profiler.TraceAnnotation(
+                "serve.scrub", batch=self._batches_formed - 1):
+            self.scrub_now()
 
     def _degradation_health(self) -> dict:
         snap = self._scrub.snapshot()

@@ -3,7 +3,10 @@ figures of merit (nJ/decision, M decisions/s) the paper reports.
 
 ``LatencyStats`` keeps a bounded ring of samples; percentiles are computed on
 demand.  ``ServeMetrics`` aggregates everything a load test needs into one
-``snapshot()`` dict (JSON-serializable — the serve benchmark dumps it as-is).
+``snapshot()`` dict (JSON-serializable — the serve benchmark dumps it as-is),
+and keeps a bounded ring of per-batch records (``BATCH_RECORD``): the
+seconds of each of the batch's spans (``PHASES``) and the bytes it copied to
+and from the device.
 """
 from __future__ import annotations
 
@@ -11,7 +14,18 @@ import threading
 
 import numpy as np
 
-__all__ = ["LatencyStats", "ServeMetrics"]
+__all__ = ["BATCH_CAPACITY", "BATCH_RECORD", "PHASES", "LatencyStats",
+           "ServeMetrics"]
+
+# The spans of one batch, each a ``serve.<phase>`` profiler annotation:
+# ``batch`` is the parent, the others tile it in this order.
+PHASES = ("batch", "encode", "dispatch", "device_wait", "d2h", "finalize",
+          "resolve")
+BATCH_CAPACITY = 16384          # batch records kept, newest last
+BATCH_RECORD = np.dtype(
+    [("batch", "i8"), ("t_form", "f8"), ("n", "i8"), ("bucket", "i8")]
+    + [(f"{p}_s", "f8") for p in PHASES]
+    + [("h2d_bytes", "i8"), ("d2h_bytes", "i8")])
 
 
 class LatencyStats:
@@ -28,8 +42,15 @@ class LatencyStats:
             self._n += 1
 
     def record_many(self, seconds: np.ndarray) -> None:
-        for s in np.asarray(seconds, np.float64).ravel():
-            self.record(float(s))
+        s = np.asarray(seconds, np.float64).ravel()
+        cap = self._buf.size
+        drop = max(0, s.size - cap)      # overwritten within this call
+        with self._lock:
+            i = (self._n + drop) % cap
+            k = min(s.size - drop, cap - i)
+            self._buf[i:i + k] = s[drop:drop + k]
+            self._buf[:s.size - drop - k] = s[drop + k:]
+            self._n += s.size
 
     @property
     def count(self) -> int:
@@ -71,6 +92,10 @@ class ServeMetrics:
     Latency is split the way serving systems report it: *queue* (enqueue ->
     batch formation) and *compute* (batch dispatch -> device results ready);
     a request's end-to-end latency is queue + compute of its batch.
+
+    Each batch also leaves one record in a ring of ``BATCH_CAPACITY``
+    (``batch_records()``); ``snapshot()["phases"]`` gives each span's p50 and
+    p99 over it, beside the cumulative ``h2d_bytes`` / ``d2h_bytes``.
     """
 
     def __init__(self) -> None:
@@ -110,6 +135,11 @@ class ServeMetrics:
         self.queue = LatencyStats()
         self.compute = LatencyStats()
         self.total = LatencyStats()
+        # -- per-batch records: spans and copies ------------------------------
+        self.h2d_bytes = 0            # Σ bytes sent to the device by batches
+        self.d2h_bytes = 0            # Σ bytes of batch outputs copied back
+        self._records = np.zeros(BATCH_CAPACITY, BATCH_RECORD)
+        self._n_records = 0
 
     def on_enqueue(self, n: int = 1) -> None:
         with self._lock:
@@ -189,6 +219,26 @@ class ServeMetrics:
             self.energy_j += energy_j
             self.active_evals += active_evals
 
+    def on_batch_record(self, batch: int, t_form: float, n: int,
+                        bucket: int, seconds, h2d_bytes: int,
+                        d2h_bytes: int) -> None:
+        """One batch's record: its id, formation time, size, the seconds of
+        each span in ``PHASES`` order, and its copies' bytes."""
+        with self._lock:
+            self._records[self._n_records % self._records.size] = (
+                batch, t_form, n, bucket, *seconds, h2d_bytes, d2h_bytes)
+            self._n_records += 1
+            self.h2d_bytes += h2d_bytes
+            self.d2h_bytes += d2h_bytes
+
+    def batch_records(self) -> np.ndarray:
+        """A copy of the ring's records (``BATCH_RECORD``), oldest first."""
+        with self._lock:
+            r, n = self._records, self._n_records
+            if n <= r.size:
+                return r[:n].copy()
+            return np.roll(r, -(n % r.size))
+
     def on_fallback(self) -> None:
         with self._lock:
             self.engine_fallbacks += 1
@@ -244,8 +294,25 @@ class ServeMetrics:
                     "rollbacks": self.rollbacks,
                 },
             }
+            h2d, d2h = self.h2d_bytes, self.d2h_bytes
+        out["h2d_bytes"] = h2d
+        out["d2h_bytes"] = d2h
+        out["h2d_bytes_per_decision"] = h2d / served if served else float("nan")
+        out["d2h_bytes_per_decision"] = d2h / served if served else float("nan")
+        rec = self.batch_records()
+        out["phases"] = {
+            p: {"p50_ms": _pct_ms(rec[f"{p}_s"], 50),
+                "p99_ms": _pct_ms(rec[f"{p}_s"], 99)}
+            for p in PHASES
+        }
         out["queue_latency"] = self.queue.summary_ms()
         out["compute_latency"] = self.compute.summary_ms()
         out["total_latency"] = self.total.summary_ms()
         out.update(extra)
         return out
+
+
+def _pct_ms(seconds: np.ndarray, q: float) -> float:
+    if not seconds.size:
+        return float("nan")
+    return float(np.percentile(seconds, q)) * 1e3

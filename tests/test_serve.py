@@ -12,6 +12,7 @@ from repro.dt import load_split
 from repro.serve import (AdaptiveBatcher, BucketPolicy, CompileCache,
                          ComputeFailed, DeadlineExceeded, LatencyStats,
                          Rejected, ServeConfig, TCAMServer)
+from repro.serve.metrics import BATCH_CAPACITY, PHASES
 
 
 @pytest.fixture(scope="module")
@@ -446,3 +447,140 @@ def test_retry_budget_absorbs_transient_faults(iris_model):
     rel = srv.metrics()["reliability"]
     assert rel["retries"] == 2 and rel["compute_failures"] == 0
     srv.close()
+
+
+# --------------------------------------------------------------------------
+# batch records, spans and copy counters
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("capacity,chunks", [
+    (16, [5, 7]),                 # fits the ring
+    (16, [10, 9, 3]),             # wraps inside a call
+    (16, [3, 40, 2]),             # one call longer than the ring
+])
+def test_latency_stats_record_many_equals_record(capacity, chunks):
+    rng = np.random.default_rng(capacity + sum(chunks))
+    many, one = LatencyStats(capacity), LatencyStats(capacity)
+    for k in chunks:
+        x = rng.random(k)
+        many.record_many(x)
+        for v in x:
+            one.record(float(v))
+    assert many.count == one.count == sum(chunks)
+    np.testing.assert_array_equal(many._buf, one._buf)
+
+
+@pytest.fixture(scope="module")
+def iris_forest():
+    from repro.forest import compile_forest, train_forest
+    Xtr, ytr, Xte, _ = load_split("iris")
+    trees = train_forest(Xtr, ytr, n_trees=3, max_depth=4, seed=0)
+    return compile_forest(trees, s=32), Xte
+
+
+def _served(model, X, sizes):
+    """Serve rows of ``X`` in one synchronous batch per entry of
+    ``sizes``."""
+    srv = TCAMServer(model, config=ServeConfig(
+        max_batch=16, min_bucket=4, background=False))
+    results = []
+    for k in sizes:
+        futs = srv.submit_many(X[np.arange(k) % len(X)])
+        srv.pump(force=True)
+        results += [f.result() for f in futs]
+    return srv, results
+
+
+def _copy_bytes(srv, bucket):
+    """(h2d, d2h) bytes of one batch of ``bucket`` rows, from the shapes."""
+    if srv._forest is None:
+        return bucket * srv._layout.n_cwd * srv._layout.s, 4 * 4 * bucket
+    groups = srv._f_plan.groups
+    return (sum(g.n_banks * bucket * g.width for g in groups),
+            sum(2 * 4 * g.n_banks * bucket * g.r_pad for g in groups))
+
+
+@pytest.mark.parametrize("mode", ["tree", "forest"])
+def test_batch_records_spans_and_bytes(mode, iris_model, iris_forest):
+    model, X = ((iris_model[0].compiled, iris_model[1]) if mode == "tree"
+                else iris_forest)
+    sizes = [16, 3, 9]
+    srv, results = _served(model, X, sizes)
+    rec = srv.metrics_store.batch_records()
+    assert rec.size == len(sizes)
+    np.testing.assert_array_equal(rec["n"], sizes)
+    np.testing.assert_array_equal(rec["bucket"], [16, 4, 16])
+    assert len(set(rec["batch"])) == len(sizes)
+    ids = dict(zip(rec["batch"], rec["n"]))
+    assert {r.batch for r in results} == set(ids)
+    for b, n in ids.items():
+        assert sum(r.batch == b for r in results) == n
+    want = np.array([_copy_bytes(srv, b) for b in rec["bucket"]])
+    np.testing.assert_array_equal(rec["h2d_bytes"], want[:, 0])
+    np.testing.assert_array_equal(rec["d2h_bytes"], want[:, 1])
+    phases = np.stack([rec[f"{p}_s"] for p in PHASES[1:]], 1)
+    assert (phases >= 0).all() and (rec["batch_s"] > 0).all()
+    assert (phases.sum(1) <= rec["batch_s"]).all()
+    assert np.all(np.diff(rec["t_form"]) > 0)
+
+    snap = srv.metrics()
+    assert snap["h2d_bytes"] == want[:, 0].sum()
+    assert snap["d2h_bytes"] == want[:, 1].sum()
+    assert snap["d2h_bytes_per_decision"] == pytest.approx(
+        want[:, 1].sum() / sum(sizes))
+    assert set(snap["phases"]) == set(PHASES)
+    for p, v in snap["phases"].items():
+        assert 0 <= v["p50_ms"] <= v["p99_ms"], p
+
+
+def test_batch_records_ring_keeps_the_newest():
+    from repro.serve import ServeMetrics
+    m = ServeMetrics()
+    assert m.batch_records().size == 0
+    assert np.isnan(m.snapshot()["phases"]["encode"]["p50_ms"])
+    n = BATCH_CAPACITY + 2
+    for b in range(n):
+        m.on_batch_record(b, float(b), 1, 8, [0.1] * len(PHASES), 10, 20)
+    rec = m.batch_records()
+    np.testing.assert_array_equal(rec["batch"], np.arange(2, n))
+    assert m.h2d_bytes == 10 * n and m.d2h_bytes == 20 * n
+    rec["n"] = 0                       # a copy: the ring is untouched
+    assert (m.batch_records()["n"] == 1).all()
+
+
+def test_serve_batch_spans_in_profiler_trace(iris_model, tmp_path):
+    import glob
+
+    import jax
+    m, Xte, _ = iris_model
+    srv = TCAMServer(m.compiled, config=ServeConfig(
+        max_batch=16, min_bucket=4, background=False))
+    srv.warmup()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        srv.serve(Xte[np.arange(40) % len(Xte)])
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    ids = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    batch, = (v for k, v in ev.stats if k == "batch")
+                    ids.setdefault(ev.name, []).append(batch)
+    rec = srv.metrics_store.batch_records()
+    assert rec.size == 3                         # 16 + 16 + 8
+    assert sorted(ids["serve.batch"]) == sorted(rec["batch"])
+    for p in PHASES[1:]:
+        assert sorted(ids[f"serve.{p}"]) == sorted(rec["batch"]), p
+
+
+def test_background_worker_records_every_batch(iris_model):
+    m, Xte, _ = iris_model
+    cfg = ServeConfig(max_batch=16, min_bucket=4, max_delay_s=0.001)
+    with TCAMServer(m.compiled, config=cfg) as srv:
+        res = srv.serve(Xte[np.arange(50) % len(Xte)])
+    rec = srv.metrics_store.batch_records()      # the worker has stopped
+    assert rec["n"].sum() == len(res) == 50
+    assert {r.batch for r in res} == set(rec["batch"])
