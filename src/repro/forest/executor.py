@@ -2,11 +2,13 @@
 
 Runs a compiled forest's execution plan on the banked kernels: every
 ``PlanGroup`` evaluates as ONE batched/vmapped kernel invocation (engine
-'banked' = batched einsum, 'mxu' = vmapped Pallas bitplane kernel), with
-groups *pipelined* — group g+1's host-side input encoding overlaps group g's
-device compute via JAX async dispatch.  Engine 'ref' delegates to the
-pure-numpy oracle (``forest_infer_ref``); all engines produce bit-identical
-survivors and therefore bit-identical votes.
+'banked' = batched einsum, 'mxu' = vmapped Pallas bitplane kernel), reduced
+on the device to each bank's first survivor, survivor count and active
+evaluations (``kernels.ops.serve_group``), with groups *pipelined* — group
+g+1's host-side input encoding overlaps group g's device compute via JAX
+async dispatch.  Engine 'ref' delegates to the pure-numpy oracle
+(``forest_infer_ref``); all engines produce bit-identical survivors and
+therefore bit-identical votes.
 
 Compiled batch functions are cached per (batch-bucket, engine, group,
 plan_id) through the serving engine's ``CompileCache``, with batch shapes
@@ -24,7 +26,7 @@ import numpy as np
 
 from ..core.energy import DEFAULT_HW, HardwareParams, forest_figures
 from ..core.encode import encode_inputs
-from ..kernels.ops import default_interpret, match_cells, place_cells
+from ..kernels.ops import default_interpret, place_cells, serve_group
 from ..serve.batching import BucketPolicy
 from ..serve.cache import CompileCache
 from .compiler import CompiledForest, ForestResult, aggregate_votes, forest_infer_ref
@@ -90,23 +92,25 @@ class ForestExecutor:
         self._kmax = (
             [g.kmax0 for g in self.plan.groups] if kmax is None else list(kmax)
         )
-        self._placed: dict = {}       # (engine, group) -> CellOperands
+        self._placed: dict = {}       # "engine:g<i>" -> serve_group args
         self.cache = CompileCache(self._build, self.plan.plan_id)
 
     # -- compile machinery --------------------------------------------------
     def _build(self, bucket: int, key: str):
-        """One jit'd banked match per (batch-bucket, engine, group); the
-        group's grids are placed on the device once and passed as
-        arguments."""
+        """One jit'd ``serve_group`` per (batch-bucket, engine, group); the
+        group's grids, real rows and real divisions are placed on the device
+        once and passed as arguments."""
         engine, gi = key.rsplit(":g", 1)
-        ops = self._placed.get(key)
-        if ops is None:
+        args = self._placed.get(key)
+        if args is None:
             grp = self.plan.groups[int(gi)]
-            ops = self._placed[key] = place_cells(
-                grp.cells, grp.s, self._kmax[int(gi)], engine=engine,
-                block_r=self.block_r,
+            args = self._placed[key] = (
+                place_cells(grp.cells, grp.s, self._kmax[int(gi)],
+                            engine=engine, block_r=self.block_r),
+                jnp.asarray(grp.rows, jnp.int32),
+                jnp.asarray(grp.d_real, jnp.int32),
             )
-        return functools.partial(match_cells, ops, block_b=self.block_b,
+        return functools.partial(serve_group, *args, block_b=self.block_b,
                                  interpret=self.interpret)
 
     def _bucket_for(self, b: int) -> int:
@@ -160,23 +164,13 @@ class ForestExecutor:
         n_survivors = np.empty((forest.n_banks, b), np.int32)
         active = np.empty((forest.n_banks, b), np.int64)
         for grp, out in pending:
-            survive, evals = (np.asarray(o) for o in out)
-            for slot, bank_id in enumerate(grp.bank_ids):
-                i = int(bank_id)
-                rows_i = int(grp.rows[slot])
-                d_i = int(grp.d_real[slot])
-                sv = survive[slot, :b, :rows_i]
-                ns = sv.sum(axis=1).astype(np.int32)
-                first = np.argmax(sv, axis=1).astype(np.int32)
-                survivors[i] = np.where(ns > 0, first, -1)
-                n_survivors[i] = ns
-                if selective_precharge:
-                    # padding divisions trivially match: clamp each row's
-                    # eval count back to the bank's real division count
-                    ev = np.minimum(evals[slot, :b, :rows_i], d_i)
-                    active[i] = ev.sum(axis=1).astype(np.int64)
-                else:
-                    active[i] = rows_i * d_i
+            first, ns, act = np.asarray(out)[:, :, :b]
+            survivors[grp.bank_ids] = np.where(ns > 0, first, -1)
+            n_survivors[grp.bank_ids] = ns
+            active[grp.bank_ids] = (
+                act if selective_precharge
+                else (grp.rows * grp.d_real)[:, None]
+            )
 
         predictions, score = aggregate_votes(forest, survivors, enabled)
         en = (np.ones(forest.n_banks, bool) if enabled is None

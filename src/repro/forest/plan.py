@@ -9,9 +9,10 @@ equal bucketed shape into a ``PlanGroup``:
 
 * padding rows beyond a bank's physical array carry ``kmax = -1`` (always
   mismatch: they can neither survive nor disturb the vote);
-* padding divisions are all-CELL_X (trivially match), and the executor
-  corrects the activity counts with ``min(evals, d_real)`` per bank —
-  safe because no row can die inside a fully-masked division.
+* padding divisions are all-CELL_X (trivially match), and
+  ``kernels.ops.serve_group`` corrects the activity counts with
+  ``min(evals, d_real)`` per bank — safe because no row can die inside a
+  fully-masked division.
 
 The plan is content-addressed (``plan_id``) so compiled batch functions can
 be cached per (plan, engine, batch-bucket), mirroring the serving engine's

@@ -10,8 +10,11 @@ as one batched kernel invocation over a leading bank axis:
 
 with the same selective-precharge cumprod over divisions as the single-bank
 kernels (ref.py).  Padding rows carry ``kmax = -1`` (always mismatch) and
-padding divisions are all-CELL_X (trivially match, then corrected out of the
-activity counts by the caller via ``min(evals, d_real)``).
+padding divisions are all-CELL_X (trivially match).  The match returns
+(survive, evals), both (G, B, R); padding rows still report one evaluation
+each, and padding divisions inflate the counts, so whoever reduces them
+masks rows at or above each bank's real row count and clamps with
+``min(evals, d_real)``.
 
 Engines:
   'banked' — one batched einsum over all banks (default jax path; a single
@@ -21,7 +24,10 @@ Engines:
   'ref'    — ``jax.vmap`` of the single-bank ``tcam_match_ref`` oracle.
 
 Serving paths place a group's planes once (``ops.place_cells``) and call
-``ops.match_cells`` per batch; ``tcam_match_banked`` does both in one call.
+``ops.serve_group`` per batch, which does that reduction on the device and
+copies back one (3, G, B) array of first survivor, survivor count and
+clamped evals instead of the (G, B, R) pair.  ``tcam_match_banked`` places
+and matches in one call and returns the unreduced pair.
 """
 from __future__ import annotations
 

@@ -15,6 +15,10 @@ All engines share the contract: inputs are the *padded search words* from
 ``TCAMLayout.pad_inputs`` (decoder bit + encoded features + padding) and the
 layout's cell grid; outputs are (survive, evals) as defined in ref.py.
 
+The serving paths reduce those (…, B, R) outputs on the device and copy back
+only what the host needs: ``serve_batch`` (one tree) returns four (B,)
+arrays, ``serve_group`` (one forest plan group) one (3, G, B) array.
+
 A cell grid is placed on the device once (``place_cells``) in the layout its
 engine's kernel reads, and every batch passes those arrays to the jitted
 match (``match_cells``) as arguments: the compiled program holds no cells, so
@@ -41,7 +45,7 @@ from .tcam_packed import tcam_match_packed_pallas
 
 __all__ = ["tcam_match", "tcam_infer", "sa_kmax", "select_engine",
            "finalize_result", "default_interpret", "ENGINES", "CellOperands",
-           "place_cells", "match_cells", "serve_batch"]
+           "place_cells", "match_cells", "serve_batch", "serve_group"]
 
 ENGINES = ("auto", "mxu", "packed", "ref")
 
@@ -264,6 +268,32 @@ def serve_batch(ops: CellOperands, classes: jax.Array, xpad: jax.Array, *,
     n_survivors, active_evals), the cells and classes passed as arguments."""
     survive, evals = match_cells(ops, xpad, interpret=interpret)
     return _finalize(survive, evals, classes)
+
+
+@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
+def serve_group(ops: CellOperands, rows: jax.Array, d_real: jax.Array,
+                xpad: jax.Array, *, block_b: int = 128,
+                interpret: bool = False) -> jax.Array:
+    """One served batch of a forest plan group: (G, B, W) padded search
+    words -> one (3, G, B) int32 array holding, per bank and request,
+
+      0: the first surviving row (0 when none survives),
+      1: the number of surviving rows,
+      2: the active row-division evaluations, each row's evals clamped to
+         the bank's real division count ``d_real[g]`` (padding divisions
+         trivially match).
+
+    Rows at or above a bank's real row count ``rows[g]`` count in none of
+    the three.  ``rows`` and ``d_real`` are (G,) int32 arguments like the
+    placed cells, so a plan rebuilt with the same shapes reuses the
+    compiled program."""
+    survive, evals = match_cells(ops, xpad, block_b=block_b,
+                                 interpret=interpret)
+    real = jnp.arange(survive.shape[-1]) < rows[:, None, None]
+    survive = jnp.where(real, survive, 0)
+    evals = jnp.where(real, jnp.minimum(evals, d_real[:, None, None]), 0)
+    return jnp.stack([jnp.argmax(survive, axis=-1), survive.sum(axis=-1),
+                      evals.sum(axis=-1)]).astype(jnp.int32)
 
 
 def finalize_result(

@@ -84,8 +84,8 @@ from ..core.nonideal import (
 )
 from ..degradation import ScrubPolicy, ScrubReport, ScrubScheduler, \
     layout_margins
-from ..kernels.ops import (default_interpret, match_cells, place_cells,
-                           sa_kmax, select_engine, serve_batch)
+from ..kernels.ops import (default_interpret, place_cells, sa_kmax,
+                           select_engine, serve_batch, serve_group)
 from ..reliability.bist import BistReport, run_bist
 from ..reliability.canary import CanaryProbe, CircuitBreaker, make_canary
 from ..reliability.repair import RepairReport, repair_layout
@@ -517,7 +517,7 @@ class TCAMServer:
         """Batch-function builder for the live chip state: one jit'd batch
         function per (bucket, engine) — (bucket, W) padded search words ->
         (preds, survivors, n_survivors, active_evals).  Forest mode builds
-        one jit'd banked match per plan group instead."""
+        one jit'd ``serve_group`` per plan group instead."""
         if self._forest is not None:
             return self._forest_builder()
         return self._single_builder(self._layout, self._kmax)
@@ -543,19 +543,26 @@ class TCAMServer:
         return build
 
     def _forest_builder(self):
-        """Forest builder: per (bucket, engine), a list of banked matches,
-        one per plan group — each evaluates its whole stack of banks in a
-        single kernel invocation on the group's placed grids."""
+        """Forest builder: per (bucket, engine), a list of ``serve_group``
+        calls, one per plan group — each evaluates its whole stack of banks
+        in a single kernel invocation on the group's placed grids and
+        reduces the match to (3, G, B) on the device.  The grids and each
+        group's real rows and divisions are placed once and passed as
+        arguments."""
         groups = list(zip(self._f_plan.groups, self._f_group_kmax))
         placed = {}
 
         def build(bucket: int, engine: str):
             if engine not in placed:
-                placed[engine] = [place_cells(g.cells, g.s, km, engine=engine)
-                                  for g, km in groups]
-            return [functools.partial(match_cells, ops,
+                placed[engine] = [
+                    (place_cells(g.cells, g.s, km, engine=engine),
+                     jnp.asarray(g.rows, jnp.int32),
+                     jnp.asarray(g.d_real, jnp.int32))
+                    for g, km in groups
+                ]
+            return [functools.partial(serve_group, *args,
                                       interpret=self.interpret)
-                    for ops in placed[engine]]
+                    for args in placed[engine]]
 
         return build
 
@@ -882,23 +889,17 @@ class TCAMServer:
             with sp.span("device_wait"):
                 jax.block_until_ready(out)
             with sp.span("d2h"):
-                survive, evals = (np.asarray(o) for o in out)
-                sp.d2h_bytes += survive.nbytes + evals.nbytes
+                host = np.asarray(out)
+                sp.d2h_bytes += host.nbytes
             with sp.span("finalize"):
+                first, ns, act = host[:, :, :n]
+                n_survivors[grp.bank_ids] = ns
+                active[grp.bank_ids] = act
+                # translate physical rows (spares after repair) to LUT rows
                 for slot, bank_id in enumerate(grp.bank_ids):
-                    i = int(bank_id)
-                    rows_i = int(grp.rows[slot])
-                    sv = survive[slot, :n, :rows_i]
-                    ns = sv.sum(axis=1).astype(np.int32)
-                    first = np.argmax(sv, axis=1).astype(np.int32)
-                    # translate physical rows (spares after repair) to LUT
-                    # rows
-                    rm = self._f_row_map[i]
-                    survivors[i] = np.where(ns > 0, rm[first], -1)
-                    n_survivors[i] = ns
-                    ev = np.minimum(evals[slot, :n, :rows_i],
-                                    int(grp.d_real[slot]))
-                    active[i] = ev.sum(axis=1).astype(np.int64)
+                    rm = self._f_row_map[int(bank_id)]
+                    survivors[bank_id] = np.where(ns[slot] > 0,
+                                                  rm[first[slot]], -1)
         compute_s = self._clock() - t_form
 
         with sp.span("finalize"):
@@ -942,9 +943,9 @@ class TCAMServer:
             with self._cond:
                 self._outstanding -= n
                 self._cond.notify_all()
-            # the groups' (G, B, R) outputs, on the device and on the host,
-            # go last and inside the span: freeing them takes tens of ms
-            del pending, out, survive, evals, sv
+            # the groups' (3, G, B) outputs, on the device and on the host,
+            # go last and inside the span, as in tree mode
+            del pending, out, host
         return bucket
 
     # -- lifecycle: shadow deployment, promotion, rollback ------------------

@@ -16,11 +16,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import CellOperands, match_cells, place_cells, serve_batch
+from repro.kernels import (CellOperands, match_cells, place_cells,
+                           serve_batch, serve_group)
 
 # Give-Me-Some-Credit at S=128 (Table II shape, DATASETS["credit"]):
 # 8576 physical rows, 39 column divisions.
 CREDIT = dict(s=128, d=39, r=8576)
+# scikit-learn's default 100-tree forest on the COVID-19 shape at S=128:
+# one plan group of 100 banks, 4096 rows, 4 divisions.
+COVID_RF100 = dict(g=100, s=128, d=4, r=4096)
 
 
 @pytest.fixture(scope="module")
@@ -118,3 +122,28 @@ def test_forest_mxu_vmapped_banks_compile(one_chip):
     x = jax.ShapeDtypeStruct((4, 256, 2 * 128), jnp.uint8, sharding=one_chip)
     _compile_for_chip(lambda o, xp: match_cells(o, xp, interpret=False),
                       ops, x)
+
+
+@pytest.mark.parametrize("engine", ["banked", "mxu"])
+def test_forest_group_program_compiles(one_chip, engine):
+    """The server's whole batch program for one forest plan group at the
+    100-tree forest's size, bucket 256: it copies back (3, G, B) int32."""
+    g, s, d, r = (COVID_RF100[k] for k in ("g", "s", "d", "r"))
+    if engine == "banked":
+        arrays = tuple(
+            jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+            for shape, dt in [((g, r, d * s), jnp.uint8)] * 2
+            + [((g, r, d), jnp.int32)])
+        ops = CellOperands(arrays=arrays, engine=engine, s=s, rows=r,
+                           block_r=128)
+    else:
+        ops = _operands(one_chip, engine, s, d, r, lead=(g,))
+    per_bank = jax.ShapeDtypeStruct((g,), jnp.int32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((g, 256, d * s), jnp.uint8, sharding=one_chip)
+    compiled = jax.jit(
+        lambda o, rows, d_real, xp: serve_group(o, rows, d_real, xp,
+                                                interpret=False)
+    ).lower(ops, per_bank, per_bank, x).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == (engine == "mxu")
+    out = compiled.out_info
+    assert (out.shape, out.dtype) == ((3, g, 256), jnp.int32)

@@ -496,7 +496,7 @@ def _copy_bytes(srv, bucket):
         return bucket * srv._layout.n_cwd * srv._layout.s, 4 * 4 * bucket
     groups = srv._f_plan.groups
     return (sum(g.n_banks * bucket * g.width for g in groups),
-            sum(2 * 4 * g.n_banks * bucket * g.r_pad for g in groups))
+            sum(3 * 4 * g.n_banks * bucket for g in groups))
 
 
 @pytest.mark.parametrize("mode", ["tree", "forest"])
