@@ -3,7 +3,8 @@
 The paper's pipelined multi-array throughput story generalizes from one tree
 on one chip to a forest sharded across TCAM banks:
 
-  sklearn_io.py — lossless import of fitted sklearn trees/forests
+  sklearn_io.py — lossless import of fitted sklearn trees/forests and
+                  histogram gradient-boosting predictors
   compiler.py   — compile_forest / ForestBank / CompiledForest + the
                   pure-numpy reference executor and vote aggregation
   plan.py       — ForestPlan: power-of-two shape bucketing, bank stacking
@@ -24,13 +25,21 @@ from .compiler import (
     train_forest,
 )
 from .plan import ForestPlan, PlanGroup, plan_forest
-from .sklearn_io import from_sklearn_tree, is_sklearn_forest, leaf_proba_rows
+from .sklearn_io import (
+    from_hgb_predictor,
+    from_sklearn_tree,
+    is_sklearn_boosting,
+    is_sklearn_forest,
+    leaf_proba_rows,
+    leaf_value_rows,
+)
 
 __all__ = [
     "VOTES", "CompiledForest", "ForestBank", "ForestResult",
     "aggregate_votes", "compile_forest", "forest_infer_ref", "train_forest",
     "ForestPlan", "PlanGroup", "plan_forest",
     "from_sklearn_tree", "is_sklearn_forest", "leaf_proba_rows",
+    "from_hgb_predictor", "is_sklearn_boosting", "leaf_value_rows",
     "ForestExecutor", "FOREST_ENGINES", "encode_group",
 ]
 

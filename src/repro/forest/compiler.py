@@ -12,6 +12,13 @@ per-bank matches into an ensemble decision:
   float32 input cast, recorded as ``cast_f32``).
 * ``vote='hard'`` (native CART default): one class vote per bank, argmax
   with ties to the lowest class index.
+* ``vote='margin'`` (a fitted ``HistGradientBoostingClassifier``; derived
+  from the model, never chosen): one bank per (iteration, class) predictor
+  holding its leaf values; the margin starts at the model's baseline, each
+  bank adds to its class column in sklearn's order, and the link (logistic
+  for binary, softmax for multiclass) gives the class probabilities.  The
+  decision, the margin and the probabilities reproduce
+  ``predict``/``decision_function``/``predict_proba`` bit-exactly.
 
 ``forest_infer_ref`` is the pure-numpy reference executor (one
 ``core.simulate`` pass per bank); the batched/vmapped JAX paths live in
@@ -29,14 +36,16 @@ from ..core.compiler import CompiledDT, check_feature_count, compile_tree
 from ..core.encode import encode_inputs
 from ..core.energy import DEFAULT_HW, HardwareParams, forest_figures
 from ..core.simulate import simulate
-from .sklearn_io import from_sklearn_tree, is_sklearn_forest, leaf_proba_rows
+from .sklearn_io import (from_hgb_predictor, from_sklearn_tree,
+                         is_sklearn_boosting, is_sklearn_forest,
+                         leaf_proba_rows, leaf_value_rows)
 
 __all__ = [
     "ForestBank", "CompiledForest", "ForestResult", "compile_forest",
     "train_forest", "aggregate_votes", "forest_infer_ref", "VOTES",
 ]
 
-VOTES = ("soft", "hard")
+VOTES = ("soft", "hard")      # the votes a caller may choose
 
 
 @dataclasses.dataclass
@@ -45,6 +54,8 @@ class ForestBank:
 
     compiled: CompiledDT
     proba: Optional[np.ndarray] = None  # (n_rows, n_classes) f64, soft vote
+    margin: Optional[np.ndarray] = None  # (n_rows,) f64 leaf values, boosted
+    column: int = 0                     # margin column a boosted bank adds to
 
     @property
     def layout(self):
@@ -63,6 +74,9 @@ class CompiledForest:
     ``classes_``, or ``arange(n_classes)`` for native trees); ``cast_f32``
     records whether inputs must round-trip through float32 before encoding
     (sklearn does this inside ``predict`` — required for bit-exact parity).
+    A boosted forest (``vote='margin'``) also carries its ``baseline``, the
+    (K,) float64 margin before any tree (K = 1 for binary, else the class
+    count), and its inverse ``link``.
     """
 
     banks: list[ForestBank]
@@ -72,6 +86,12 @@ class CompiledForest:
     vote: str
     cast_f32: bool
     s: int
+    baseline: Optional[np.ndarray] = None   # (K,) f64, boosted only
+    link: Optional[str] = None              # 'logit' | 'softmax', boosted
+
+    @property
+    def boosted(self) -> bool:
+        return self.vote == "margin"
 
     @property
     def n_banks(self) -> int:
@@ -83,8 +103,14 @@ class CompiledForest:
 
     def prepare_inputs(self, X: np.ndarray, *,
                        who: str = "forest.infer") -> np.ndarray:
-        """Validate the feature count and apply the recorded input cast."""
+        """Validate the feature count, refuse NaN for a boosted forest and
+        apply the recorded input cast."""
         X = check_feature_count(X, self.n_features, who=who)
+        if self.boosted and np.isnan(X).any():
+            raise ValueError(
+                f"{who}: NaN input to a boosted forest; its per-node "
+                "missing-value routing cannot be compiled"
+            )
         if self.cast_f32:
             X = X.astype(np.float32).astype(np.float64)
         return X
@@ -95,8 +121,10 @@ class ForestResult:
     """Ensemble inference outcome + per-bank activity trace.
 
     ``score`` is the sklearn-averaged probability matrix (soft vote,
-    float64) or the integer vote-count matrix (hard vote), in internal class
-    index space; ``predictions`` are already mapped through ``classes``.
+    float64), the link's class probabilities (boosted, float64) or the
+    integer vote-count matrix (hard vote), in internal class index space;
+    ``predictions`` are already mapped through ``classes``.  ``margin`` is
+    a boosted forest's (batch, K) float64 summed margin, else None.
     """
 
     predictions: np.ndarray     # (batch,) output labels
@@ -107,6 +135,7 @@ class ForestResult:
     enabled: np.ndarray         # (n_banks,) bool — banks that voted
     engine: str
     figures: dict               # per-bank + aggregate pipelined figures
+    margin: Optional[np.ndarray] = None   # (batch, K) f64, boosted only
 
     @property
     def total_active_evals(self) -> np.ndarray:
@@ -129,6 +158,37 @@ def _compile_native(
     return banks
 
 
+def _compile_boosted(model, s: int, *, seed: int, spare_rows: int,
+                     nan_full_dontcare: bool) -> CompiledForest:
+    """One bank per (iteration, class) predictor, iteration-major."""
+    predictors = [(k, p) for it in model._predictors
+                  for k, p in enumerate(it)]
+    if not predictors:
+        raise ValueError("boosted model has no predictors")
+    n_features = int(model.n_features_in_)
+    trees = [from_hgb_predictor(p, n_features) for _, p in predictors]
+    banks = _compile_native(
+        trees, s, seed=seed, spare_rows=spare_rows,
+        nan_full_dontcare=nan_full_dontcare,
+    )
+    for bank, (k, p), tree in zip(banks, predictors, trees):
+        bank.margin = leaf_value_rows(p, tree)
+        bank.column = k
+    classes = np.asarray(model.classes_)
+    baseline = np.asarray(model._baseline_prediction, np.float64).reshape(-1)
+    return CompiledForest(
+        banks=banks,
+        n_features=n_features,
+        n_classes=len(classes),
+        classes=classes,
+        vote="margin",
+        cast_f32=False,
+        s=s,
+        baseline=baseline,
+        link="logit" if baseline.size == 1 else "softmax",
+    )
+
+
 def compile_forest(
     model: Union[Sequence[DecisionTree], object],
     s: int = 128,
@@ -138,16 +198,26 @@ def compile_forest(
     spare_rows: int = 0,
     nan_full_dontcare: bool = True,
 ) -> CompiledForest:
-    """Compile an ensemble — a sequence of native ``DecisionTree``s or a
-    fitted ``sklearn.ensemble.RandomForestClassifier`` — into per-bank TCAM
-    layouts plus vote metadata.
+    """Compile an ensemble — a sequence of native ``DecisionTree``s, a
+    fitted ``sklearn.ensemble.RandomForestClassifier`` or a fitted
+    ``sklearn.ensemble.HistGradientBoostingClassifier`` — into per-bank
+    TCAM layouts plus vote metadata.
 
     ``vote`` defaults to 'soft' for sklearn forests (matching
-    ``RandomForestClassifier.predict``) and 'hard' for native trees.
+    ``RandomForestClassifier.predict``) and 'hard' for native trees; a
+    boosted model always aggregates its margin and takes no ``vote``.
     Each bank gets ``seed + bank_index`` for its rogue-row synthesis.
     """
     if vote is not None and vote not in VOTES:
         raise ValueError(f"unknown vote {vote!r}; expected one of {VOTES}")
+
+    if is_sklearn_boosting(model):
+        if vote is not None:
+            raise ValueError(
+                "a boosted model aggregates its margin; vote= does not apply"
+            )
+        return _compile_boosted(model, s, seed=seed, spare_rows=spare_rows,
+                                nan_full_dontcare=nan_full_dontcare)
 
     if is_sklearn_forest(model):
         estimators = list(model.estimators_)
@@ -177,7 +247,8 @@ def compile_forest(
     if not all(isinstance(t, DecisionTree) for t in trees):
         raise TypeError(
             "compile_forest expects a fitted sklearn RandomForestClassifier "
-            "or a sequence of repro DecisionTree objects, got "
+            "or HistGradientBoostingClassifier, or a sequence of repro "
+            "DecisionTree objects, got "
             f"{type(trees[0]).__name__}"
         )
     n_features = trees[0].n_features
@@ -232,22 +303,30 @@ def train_forest(
     return trees
 
 
-def aggregate_votes(
+def _link(forest: CompiledForest, margin: np.ndarray) -> np.ndarray:
+    """Class probabilities from a boosted margin, computed as sklearn's
+    ``predict_proba`` computes them (``HalfBinomialLoss`` /
+    ``HalfMultinomialLoss``), so the bits agree."""
+    if forest.link == "logit":
+        from scipy.special import expit
+
+        proba = np.empty((margin.shape[0], 2), np.float64)
+        proba[:, 1] = expit(margin[:, 0])
+        proba[:, 0] = 1 - proba[:, 1]
+        return proba
+    proba = np.array(margin, copy=True)
+    proba -= proba.max(axis=1).reshape(-1, 1)
+    np.exp(proba, out=proba)
+    proba /= proba.sum(axis=1).reshape(-1, 1)
+    return proba
+
+
+def _aggregate(
     forest: CompiledForest,
-    survivors: np.ndarray,          # (n_banks, batch) int32, -1 = no match
-    enabled: Optional[np.ndarray] = None,
-) -> tuple[np.ndarray, np.ndarray]:
-    """Aggregate per-bank surviving rows into ensemble predictions.
-
-    Soft vote replicates sklearn exactly: probabilities accumulate bank by
-    bank *in estimator order* (float64 addition is not associative), the sum
-    divides by the number of voting banks, and argmax breaks ties toward the
-    lower class index.  Hard vote counts one vote per bank.  ``enabled``
-    masks out banks (BIST/repair degradation): a dead bank drops out of both
-    the accumulation and the divisor, degrading the vote instead of the chip.
-
-    Returns ``(predictions, score)``.
-    """
+    survivors: np.ndarray,
+    enabled: Optional[np.ndarray],
+) -> tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]:
+    """``(predictions, score, margin)``; see ``aggregate_votes``."""
     survivors = np.asarray(survivors)
     n_banks, batch = survivors.shape
     if n_banks != forest.n_banks:
@@ -261,20 +340,7 @@ def aggregate_votes(
     if n_voting == 0:
         raise ValueError("no enabled banks to vote")
 
-    if forest.vote == "soft":
-        acc = np.zeros((batch, forest.n_classes), dtype=np.float64)
-        for b in range(n_banks):
-            if not enabled[b]:
-                continue
-            rows = survivors[b]
-            proba = forest.banks[b].proba
-            assert proba is not None, "soft vote needs per-bank proba tables"
-            contrib = proba[np.maximum(rows, 0)]
-            contrib[rows < 0] = 0.0
-            acc += contrib
-        score = acc / n_voting
-        idx = np.argmax(score, axis=1)
-    else:
+    if forest.vote == "hard":
         score = np.zeros((batch, forest.n_classes), dtype=np.int64)
         cols = np.arange(batch)
         for b in range(n_banks):
@@ -284,8 +350,67 @@ def aggregate_votes(
             valid = rows >= 0
             cls = forest.banks[b].layout.classes[np.maximum(rows, 0)]
             np.add.at(score, (cols[valid], cls[valid]), 1)
-        idx = np.argmax(score, axis=1)
+        predictions = np.asarray(forest.classes)[np.argmax(score, axis=1)]
+        return predictions, score, None
+
+    # soft vote and margin: per-leaf vectors summed bank by bank, in
+    # estimator order (float64 addition is not associative).  The margin is
+    # laid out column-major like sklearn's raw predictions, so a row's
+    # reductions in the link run in the same order.
+    boosted = forest.boosted
+    if boosted:
+        acc = np.zeros((batch, forest.baseline.size), np.float64, order="F")
+        acc += forest.baseline
+    else:
+        acc = np.zeros((batch, forest.n_classes), dtype=np.float64)
+    for b in range(n_banks):
+        if not enabled[b]:
+            continue
+        rows = survivors[b]
+        bank = forest.banks[b]
+        if boosted:
+            table = bank.margin[:, None]
+            cols = slice(bank.column, bank.column + 1)
+        else:
+            table = bank.proba
+            assert table is not None, "soft vote needs per-bank proba tables"
+            cols = slice(None)
+        contrib = table[np.maximum(rows, 0)]
+        contrib[rows < 0] = 0.0
+        acc[:, cols] += contrib
+    if not boosted:
+        score = acc / n_voting
+        predictions = np.asarray(forest.classes)[np.argmax(score, axis=1)]
+        return predictions, score, None
+    # sklearn decides on the margin: binary takes class 1 only when it is
+    # strictly positive, multiclass the argmax (ties to the lower class)
+    idx = ((acc[:, 0] > 0).astype(np.int64) if acc.shape[1] == 1
+           else np.argmax(acc, axis=1))
     predictions = np.asarray(forest.classes)[idx]
+    return predictions, _link(forest, acc), acc
+
+
+def aggregate_votes(
+    forest: CompiledForest,
+    survivors: np.ndarray,          # (n_banks, batch) int32, -1 = no match
+    enabled: Optional[np.ndarray] = None,
+) -> tuple[np.ndarray, np.ndarray]:
+    """Aggregate per-bank surviving rows into ensemble predictions.
+
+    Soft vote replicates sklearn exactly: probabilities accumulate bank by
+    bank *in estimator order* (float64 addition is not associative), the sum
+    divides by the number of voting banks, and argmax breaks ties toward the
+    lower class index.  A boosted forest sums in the same loop: its margin
+    starts at the baseline, each bank adds its leaf value to its class
+    column, the decision is taken on the margin and the score is the link's
+    class probabilities, all as sklearn computes them.  Hard vote counts one
+    vote per bank.  ``enabled`` masks out banks (BIST/repair degradation): a
+    dead bank drops out of the accumulation (and of a vote's divisor),
+    degrading the vote instead of the chip.
+
+    Returns ``(predictions, score)``.
+    """
+    predictions, score, _ = _aggregate(forest, survivors, enabled)
     return predictions, score
 
 
@@ -313,7 +438,7 @@ def forest_infer_ref(
         survivors[i] = res.survivors
         n_survivors[i] = res.n_survivors
         active[i] = res.active_evals
-    predictions, score = aggregate_votes(forest, survivors, enabled)
+    predictions, score, margin = _aggregate(forest, survivors, enabled)
     en = (np.ones(forest.n_banks, bool) if enabled is None
           else np.asarray(enabled, bool))
     figures = forest_figures(
@@ -329,4 +454,5 @@ def forest_infer_ref(
         enabled=en,
         engine="ref",
         figures=figures,
+        margin=margin,
     )

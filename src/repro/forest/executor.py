@@ -29,7 +29,7 @@ from ..core.encode import encode_inputs
 from ..kernels.ops import default_interpret, place_cells, serve_group
 from ..serve.batching import BucketPolicy
 from ..serve.cache import CompileCache
-from .compiler import CompiledForest, ForestResult, aggregate_votes, forest_infer_ref
+from .compiler import CompiledForest, ForestResult, _aggregate, forest_infer_ref
 from .plan import ForestPlan, PlanGroup, plan_forest
 
 __all__ = ["ForestExecutor", "FOREST_ENGINES", "encode_group"]
@@ -172,7 +172,7 @@ class ForestExecutor:
                 else (grp.rows * grp.d_real)[:, None]
             )
 
-        predictions, score = aggregate_votes(forest, survivors, enabled)
+        predictions, score, margin = _aggregate(forest, survivors, enabled)
         en = (np.ones(forest.n_banks, bool) if enabled is None
               else np.asarray(enabled, bool))
         figures = forest_figures(
@@ -188,6 +188,7 @@ class ForestExecutor:
             enabled=en,
             engine=self.engine,
             figures=figures,
+            margin=margin,
         )
 
     __call__ = infer

@@ -14,7 +14,8 @@ index:
   was retrained/delta-programmed from) and free-form metadata;
   ``lineage()`` walks the ancestry;
 * **round-trip** — ``load()`` reconstructs the full compiled object
-  (tree + rule table + LUT + layout, and per-bank proba tables for forests)
+  (tree + rule table + LUT + layout, and per-bank proba tables for forests;
+  a boosted forest adds its leaf values, columns, baseline and link)
   bit-exactly; the lifecycle tests assert array equality and identical
   re-hash.
 
@@ -126,10 +127,16 @@ def _pack_forest(forest) -> dict:
         "f__cast_f32": np.int64(int(forest.cast_f32)),
         "f__s": np.int64(forest.s),
     }
+    if forest.baseline is not None:
+        d["f__baseline"] = forest.baseline
+        d["f__link"] = np.asarray(forest.link)
     for i, bank in enumerate(forest.banks):
         d.update(_pack_compiled(bank.compiled, f"b{i}__"))
         if bank.proba is not None:
             d[f"b{i}__proba"] = bank.proba
+        if bank.margin is not None:
+            d[f"b{i}__margin"] = bank.margin
+            d[f"b{i}__column"] = np.int64(bank.column)
     return d
 
 
@@ -143,7 +150,10 @@ def _unpack_forest(z, vote: str):
         banks.append(ForestBank(
             compiled=_unpack_compiled(z, f"b{i}__"),
             proba=z[f"b{i}__proba"] if f"b{i}__proba" in z else None,
+            margin=z[f"b{i}__margin"] if f"b{i}__margin" in z else None,
+            column=int(z[f"b{i}__column"]) if f"b{i}__column" in z else 0,
         ))
+    boosted = "f__baseline" in z
     return CompiledForest(
         banks=banks,
         n_features=int(z["f__n_features"]),
@@ -152,6 +162,8 @@ def _unpack_forest(z, vote: str):
         vote=vote,
         cast_f32=bool(int(z["f__cast_f32"])),
         s=int(z["f__s"]),
+        baseline=z["f__baseline"] if boosted else None,
+        link=str(z["f__link"]) if boosted else None,
     )
 
 

@@ -47,7 +47,10 @@ aggregated into one ensemble vote per request.  Chip health runs bank by
 bank: BIST and spare-row repair per bank, survivors on remapped spare rows
 translated through a physical->LUT row map back to the right vote entries,
 and a bank whose repair stays degraded is disabled (drops out of the vote
-and the divisor) instead of poisoning the ensemble.
+and the divisor) instead of poisoning the ensemble.  A boosted forest
+(``HistGradientBoostingClassifier``) is served the same way: its margin is
+summed in the same loop as a soft vote, and each ``RequestResult`` carries
+the class probabilities in ``score``.
 
 Run ``background=True`` (default) for a worker thread + Future-based
 completion, or ``background=False`` for deterministic single-threaded tests
@@ -92,7 +95,7 @@ from ..reliability.repair import RepairReport, repair_layout
 from .batching import AdaptiveBatcher, BucketPolicy
 from .cache import CompileCache
 from .errors import ComputeFailed, DeadlineExceeded, Rejected
-from .metrics import PHASES, ServeMetrics
+from .metrics import NESTED, PHASES, ServeMetrics
 
 __all__ = ["PromotionReport", "RequestResult", "ServeConfig", "TCAMServer"]
 
@@ -153,6 +156,9 @@ class RequestResult:
     engine: str
     batch: int = -1               # id of its batch: the batch record's and
                                   # the serve.* spans' ``batch``
+    score: Optional[np.ndarray] = None  # forest: the request's row of class
+                                  # probabilities (soft vote, boosted) or
+                                  # vote counts (hard vote); None for a tree
 
     @property
     def total_s(self) -> float:
@@ -171,16 +177,22 @@ class _BatchSpans:
     annotation ``serve.<phase>`` carrying the batch id, so it lands in the
     same trace as the device's operations, on that trace's clock; its
     seconds on the server's clock add to the phase's total (a phase entered
-    once per plan group sums over the groups).  ``h2d_bytes`` and
-    ``d2h_bytes`` count the arrays copied to and from the device."""
+    once per plan group sums over the groups).  A ``NESTED`` span (``vote``)
+    runs inside a phase and is recorded apart from the ``PHASES`` that tile
+    the batch.  ``h2d_bytes`` and ``d2h_bytes`` count the arrays copied to
+    and from the device; ``match_cells`` the cells the device compared
+    (padding included) and ``lut_cells`` the model's own cells, times the
+    requests."""
 
     def __init__(self, batch: int, clock: Callable[[], float]) -> None:
         self.batch = batch
         self._clock = clock
         self.t_form = clock()
-        self.seconds = dict.fromkeys(PHASES, 0.0)
+        self.seconds = dict.fromkeys(PHASES + NESTED, 0.0)
         self.h2d_bytes = 0
         self.d2h_bytes = 0
+        self.match_cells = 0
+        self.lut_cells = 0
 
     @contextlib.contextmanager
     def span(self, phase: str):
@@ -195,8 +207,10 @@ class _BatchSpans:
         """Close the batch's own span and write its record."""
         self.seconds["batch"] = self._clock() - self.t_form
         metrics.on_batch_record(self.batch, self.t_form, n, bucket,
-                                self.seconds.values(), self.h2d_bytes,
-                                self.d2h_bytes)
+                                [self.seconds[p] for p in PHASES],
+                                self.h2d_bytes, self.d2h_bytes,
+                                [self.seconds[p] for p in NESTED],
+                                self.match_cells, self.lut_cells)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -430,6 +444,7 @@ class TCAMServer:
                 )
                 self._f_kmax_banks[i] = sa_kmax(lay, offsets, self._hw)
         self._f_enabled = np.ones(n, dtype=bool)
+        self._f_lut_cells = sum(b.lut.n_total for b in forest.banks)
         # physical row -> LUT (vote-table) row; spares start unassigned and
         # inherit a LUT row when repair remaps a defective rule onto them
         self._f_row_map = []
@@ -600,6 +615,12 @@ class TCAMServer:
             raise FeatureMismatch(
                 f"TCAMServer.submit: input has {x.shape[0]} features but the "
                 f"served model expects {self._n_features}"
+            )
+        if (self._forest is not None and self._forest.boosted
+                and np.isnan(x).any()):
+            raise ValueError(
+                "TCAMServer.submit: NaN input to a boosted forest; its "
+                "per-node missing-value routing cannot be compiled"
             )
         fut: Future = Future()
         now = self._clock()
@@ -785,6 +806,9 @@ class TCAMServer:
             fn = self.cache.get(bucket, self.engine)
             out = fn(jnp.asarray(xpad))
             sp.h2d_bytes += xpad.nbytes
+            lay = self._layout
+            sp.match_cells += lay.cells.shape[0] * lay.n_cwd * lay.s * bucket
+            sp.lut_cells += self._lut.n_total * n
         with sp.span("device_wait"):
             jax.block_until_ready(out)
         compute_s = self._clock() - t_form
@@ -881,6 +905,9 @@ class TCAMServer:
             with sp.span("dispatch"):
                 pending.append((grp, fn(jnp.asarray(xpad))))
                 sp.h2d_bytes += xpad.nbytes
+                sp.match_cells += (grp.n_banks * grp.r_pad * grp.width
+                                   * bucket)
+        sp.lut_cells += self._f_lut_cells * n
 
         survivors = np.empty((forest.n_banks, n), np.int32)
         n_survivors = np.empty((forest.n_banks, n), np.int32)
@@ -903,9 +930,10 @@ class TCAMServer:
         compute_s = self._clock() - t_form
 
         with sp.span("finalize"):
-            predictions, _score = aggregate_votes(
-                forest, survivors, self._f_enabled
-            )
+            with sp.span("vote"):
+                predictions, score = aggregate_votes(
+                    forest, survivors, self._f_enabled
+                )
             enabled = self._f_enabled
             n_voting = int(enabled.sum())
             active_total = active[enabled].sum(axis=0)
@@ -938,6 +966,7 @@ class TCAMServer:
                         bucket=bucket,
                         engine=self.engine,
                         batch=sp.batch,
+                        score=score[i],
                     )
                 )
             with self._cond:

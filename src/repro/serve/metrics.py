@@ -5,8 +5,11 @@ figures of merit (nJ/decision, M decisions/s) the paper reports.
 demand.  ``ServeMetrics`` aggregates everything a load test needs into one
 ``snapshot()`` dict (JSON-serializable — the serve benchmark dumps it as-is),
 and keeps a bounded ring of per-batch records (``BATCH_RECORD``): the
-seconds of each of the batch's spans (``PHASES``) and the bytes it copied to
-and from the device.
+seconds of each of the batch's spans (``PHASES``, and ``vote_s`` for the
+``NESTED`` span), the bytes it copied to and from the device, and the
+cells its match compared (``match_cells``: every placed cell, padding
+included, times the bucket) against the cells the model has (``lut_cells``:
+LUT rows times LUT columns, summed over banks, times the requests).
 """
 from __future__ import annotations
 
@@ -14,18 +17,23 @@ import threading
 
 import numpy as np
 
-__all__ = ["BATCH_CAPACITY", "BATCH_RECORD", "PHASES", "LatencyStats",
-           "ServeMetrics"]
+__all__ = ["BATCH_CAPACITY", "BATCH_RECORD", "NESTED", "PHASES",
+           "LatencyStats", "ServeMetrics"]
 
 # The spans of one batch, each a ``serve.<phase>`` profiler annotation:
 # ``batch`` is the parent, the others tile it in this order.
 PHASES = ("batch", "encode", "dispatch", "device_wait", "d2h", "finalize",
           "resolve")
+# Spans nested inside a phase, outside the tiling: ``vote``, the forest's
+# aggregation, inside ``finalize``.
+NESTED = ("vote",)
 BATCH_CAPACITY = 16384          # batch records kept, newest last
 BATCH_RECORD = np.dtype(
     [("batch", "i8"), ("t_form", "f8"), ("n", "i8"), ("bucket", "i8")]
     + [(f"{p}_s", "f8") for p in PHASES]
-    + [("h2d_bytes", "i8"), ("d2h_bytes", "i8")])
+    + [("h2d_bytes", "i8"), ("d2h_bytes", "i8")]
+    + [(f"{p}_s", "f8") for p in NESTED]
+    + [("match_cells", "i8"), ("lut_cells", "i8")])
 
 
 class LatencyStats:
@@ -221,12 +229,15 @@ class ServeMetrics:
 
     def on_batch_record(self, batch: int, t_form: float, n: int,
                         bucket: int, seconds, h2d_bytes: int,
-                        d2h_bytes: int) -> None:
+                        d2h_bytes: int, nested=(0.0,) * len(NESTED),
+                        match_cells: int = 0, lut_cells: int = 0) -> None:
         """One batch's record: its id, formation time, size, the seconds of
-        each span in ``PHASES`` order, and its copies' bytes."""
+        each span in ``PHASES`` order, its copies' bytes, the seconds of
+        each ``NESTED`` span, and its match's and model's cells."""
         with self._lock:
             self._records[self._n_records % self._records.size] = (
-                batch, t_form, n, bucket, *seconds, h2d_bytes, d2h_bytes)
+                batch, t_form, n, bucket, *seconds, h2d_bytes, d2h_bytes,
+                *nested, match_cells, lut_cells)
             self._n_records += 1
             self.h2d_bytes += h2d_bytes
             self.d2h_bytes += d2h_bytes

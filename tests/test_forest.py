@@ -8,10 +8,13 @@ modelled aggregate dec/s must grow monotonically with bank count; and
 forest-mode serving must survive per-bank BIST/repair with spare-row
 survivors resolving to the right vote entries.
 """
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from hgb_cases import HGB_CASES, categorical_model, hgb_case
 from hypothesis_compat import given, settings, st
 
 import repro
@@ -391,6 +394,129 @@ def test_disable_bank_degrades_gracefully(served_forest):
             srv.disable_bank(b)
     with pytest.raises(RuntimeError, match="last voting bank"):
         srv.disable_bank(forest.n_banks - 1)
+
+
+# --------------------------------------------------------------------------
+# boosted forests: HistGradientBoostingClassifier
+# --------------------------------------------------------------------------
+@pytest.fixture(scope="module", params=sorted(HGB_CASES))
+def hgb(request):
+    model, X = hgb_case(request.param)
+    return model, compile_forest(model, s=32), X
+
+
+def _margin(model, X):
+    return model.decision_function(X).reshape(X.shape[0], -1)
+
+
+def test_hgb_compiles_one_bank_per_predictor(hgb):
+    model, forest, _ = hgb
+    k = model.n_trees_per_iteration_
+    assert forest.boosted and forest.vote == "margin"
+    assert forest.n_banks == len(model._predictors) * k
+    assert [b.column for b in forest.banks] == list(range(k)) * len(
+        model._predictors)
+    assert not forest.cast_f32
+    np.testing.assert_array_equal(forest.baseline,
+                                  model._baseline_prediction.ravel())
+    assert forest.link == ("logit" if k == 1 else "softmax")
+    for bank, (i, j) in zip(forest.banks, np.ndindex(len(model._predictors),
+                                                     k)):
+        p = model._predictors[i][j]
+        assert bank.margin.shape == (p.get_n_leaf_nodes(),)
+        assert bank.lut.n_rows == p.get_n_leaf_nodes()
+
+
+def test_hgb_parity_ref(hgb):
+    model, forest, X = hgb
+    res = forest_infer_ref(forest, X)
+    np.testing.assert_array_equal(res.predictions, model.predict(X))
+    np.testing.assert_array_equal(res.margin, _margin(model, X))
+    np.testing.assert_array_equal(res.score, model.predict_proba(X))
+    assert (res.n_survivors == 1).all()
+
+
+@pytest.mark.parametrize("engine", ["banked", "ref"])
+def test_hgb_parity_executor(hgb, engine):
+    model, forest, X = hgb
+    ref = forest_infer_ref(forest, X)
+    res = repro.ForestExecutor(forest, engine=engine).infer(X)
+    np.testing.assert_array_equal(res.predictions, model.predict(X))
+    np.testing.assert_array_equal(res.margin, _margin(model, X))
+    np.testing.assert_array_equal(res.score, model.predict_proba(X))
+    for k in ("survivors", "n_survivors", "active_evals"):
+        np.testing.assert_array_equal(getattr(res, k), getattr(ref, k),
+                                      err_msg=k)
+
+
+def test_hgb_leaf_values_in_lut_row_order(hgb):
+    """Each bank's survivor holds the value of the leaf that the
+    predictor's own walk reaches."""
+    model, forest, X = hgb
+    res = forest_infer_ref(forest, X)
+    bitsets, f_idx_map = model._bin_mapper.make_known_categories_bitsets()
+    preds = [p for it in model._predictors for p in it]
+    for b, (bank, p) in enumerate(zip(forest.banks, preds)):
+        np.testing.assert_array_equal(
+            bank.margin[res.survivors[b]],
+            p.predict(X, bitsets, f_idx_map, 1), err_msg=str(b))
+
+
+def test_hgb_decides_on_the_margin_not_the_probabilities():
+    """Binary: class 1 only for a strictly positive margin, so a margin of
+    exactly 0 is class 0; multiclass: argmax, ties to the lower class."""
+    def zeroed(forest, baseline):
+        return dataclasses.replace(
+            forest, baseline=np.asarray(baseline, np.float64),
+            banks=[dataclasses.replace(b, margin=np.zeros_like(b.margin))
+                   for b in forest.banks])
+
+    model, _ = hgb_case("binary")
+    forest = zeroed(compile_forest(model, s=32), [0.0])
+    pred, score = aggregate_votes(forest,
+                                  np.zeros((forest.n_banks, 3), np.int32))
+    np.testing.assert_array_equal(pred, [model.classes_[0]] * 3)
+    np.testing.assert_array_equal(score, np.full((3, 2), 0.5))
+    model, _ = hgb_case("multiclass")
+    forest = zeroed(compile_forest(model, s=32), [0.0, 1.0, 1.0])
+    pred, _ = aggregate_votes(forest,
+                              np.zeros((forest.n_banks, 2), np.int32))
+    np.testing.assert_array_equal(pred, [model.classes_[1]] * 2)
+
+
+def test_hgb_refuses_nan_inputs(hgb):
+    model, forest, X = hgb
+    Xn = X[:4].copy()
+    Xn[1, 0] = np.nan
+    with pytest.raises(ValueError, match="NaN"):
+        forest_infer_ref(forest, Xn)
+    with pytest.raises(ValueError, match="NaN"):
+        repro.ForestExecutor(forest, engine="banked").infer(Xn)
+
+
+def test_hgb_refuses_categorical_and_nan_splits_and_votes():
+    from repro.forest import from_hgb_predictor
+    model, _ = categorical_model()
+    with pytest.raises(ValueError, match="categorical"):
+        compile_forest(model, s=32)
+    model, _ = hgb_case("binary")
+    with pytest.raises(ValueError, match="vote"):
+        compile_forest(model, s=32, vote="soft")
+    p = model._predictors[0][0]
+    nodes = p.nodes.copy()
+    nodes["num_threshold"][0] = np.inf
+    with pytest.raises(ValueError, match="missing"):
+        from_hgb_predictor(type("P", (), {"nodes": nodes})(),
+                           model.n_features_in_)
+
+
+def test_soft_vote_score_equals_predict_proba(rf_case):
+    """The soft vote still scores as ``predict_proba`` does, bit for bit,
+    now that it shares its accumulation loop with the margin."""
+    name, rf, forest, Xte, yte = rf_case
+    res = repro.ForestExecutor(forest, engine="banked").infer(Xte)
+    np.testing.assert_array_equal(res.score, rf.predict_proba(Xte))
+    assert res.margin is None
 
 
 # --------------------------------------------------------------------------

@@ -111,6 +111,27 @@ def test_registry_forest_round_trip(tmp_path):
     assert content_hash(loaded) == rv.content_hash
 
 
+@pytest.mark.parametrize("case", ["binary", "multiclass"])
+def test_registry_boosted_forest_round_trip(tmp_path, case):
+    """A boosted forest keeps its leaf values, columns, baseline and link
+    through the registry, and answers as before, margin and score too."""
+    from hgb_cases import hgb_case
+    model, X = hgb_case(case)
+    forest = repro.compile_forest(model, s=32)
+    reg = ModelRegistry(tmp_path / "reg")
+    rv = reg.publish(forest, "boosted")
+    loaded = reg.load(rv.version_id)
+    assert loaded.vote == "margin" and loaded.link == forest.link
+    np.testing.assert_array_equal(loaded.baseline, forest.baseline)
+    for lb, fb in zip(loaded.banks, forest.banks):
+        np.testing.assert_array_equal(lb.margin, fb.margin)
+        assert lb.column == fb.column
+    got, want = (repro.forest_infer_ref(f, X) for f in (loaded, forest))
+    for k in ("predictions", "score", "margin"):
+        np.testing.assert_array_equal(getattr(got, k), getattr(want, k))
+    assert content_hash(loaded) == rv.content_hash
+
+
 def test_registry_rejects_bad_names_and_unknown_refs(retrained_pair,
                                                      tmp_path):
     v1, _, _ = retrained_pair

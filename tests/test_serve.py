@@ -12,7 +12,9 @@ from repro.dt import load_split
 from repro.serve import (AdaptiveBatcher, BucketPolicy, CompileCache,
                          ComputeFailed, DeadlineExceeded, LatencyStats,
                          Rejected, ServeConfig, TCAMServer)
-from repro.serve.metrics import BATCH_CAPACITY, PHASES
+from repro.serve.metrics import BATCH_CAPACITY, NESTED, PHASES
+
+from hgb_cases import HGB_CASES, hgb_case
 
 
 @pytest.fixture(scope="module")
@@ -584,3 +586,115 @@ def test_background_worker_records_every_batch(iris_model):
     rec = srv.metrics_store.batch_records()      # the worker has stopped
     assert rec["n"].sum() == len(res) == 50
     assert {r.batch for r in res} == set(rec["batch"])
+
+
+# --------------------------------------------------------------------------
+# forest scores, the vote span and the match's cells
+# --------------------------------------------------------------------------
+@pytest.fixture(scope="module", params=sorted(HGB_CASES))
+def hgb_served(request):
+    from repro.forest import compile_forest, forest_infer_ref
+    model, X = hgb_case(request.param)
+    forest = compile_forest(model, s=32)
+    srv = TCAMServer(forest, config=ServeConfig(
+        engine="banked", max_batch=16, min_bucket=4, background=False))
+    return model, forest, X, srv.serve(X), forest_infer_ref(forest, X)
+
+
+def test_hgb_served_answers_equal_sklearn(hgb_served):
+    model, forest, X, results, ref = hgb_served
+    np.testing.assert_array_equal([r.prediction for r in results],
+                                  model.predict(X))
+    np.testing.assert_array_equal(np.stack([r.score for r in results]),
+                                  model.predict_proba(X))
+    np.testing.assert_array_equal([r.n_survivors for r in results],
+                                  (ref.n_survivors > 0).sum(axis=0))
+    np.testing.assert_array_equal([r.active_evals for r in results],
+                                  ref.active_evals.sum(axis=0))
+    assert all(r.survivor == -1 for r in results)
+
+
+def test_hgb_submit_refuses_nan(hgb_served):
+    _, forest, X, _, _ = hgb_served
+    srv = TCAMServer(forest, config=ServeConfig(background=False))
+    x = X[0].copy()
+    x[-1] = np.nan
+    with pytest.raises(ValueError, match="NaN"):
+        srv.submit(x)
+    assert srv.metrics()["requests_enqueued"] == 0
+
+
+@pytest.mark.parametrize("vote", ["soft", "hard"])
+def test_forest_served_score(vote):
+    """A soft-vote forest serves ``predict_proba``'s row, bit for bit; a
+    hard vote serves its vote counts."""
+    from sklearn.ensemble import RandomForestClassifier
+
+    from repro.forest import compile_forest, forest_infer_ref
+    Xtr, ytr, Xte, _ = load_split("iris")
+    rf = RandomForestClassifier(n_estimators=5, max_depth=4,
+                                random_state=0).fit(Xtr, ytr)
+    forest = compile_forest(rf, s=32, vote=vote)
+    srv = TCAMServer(forest, config=ServeConfig(max_batch=16,
+                                                background=False))
+    score = np.stack([r.score for r in srv.serve(Xte)])
+    np.testing.assert_array_equal(score, forest_infer_ref(forest, Xte).score)
+    if vote == "soft":
+        np.testing.assert_array_equal(score, rf.predict_proba(Xte))
+    else:
+        assert (score.sum(axis=1) == forest.n_banks).all()
+
+
+def test_tree_mode_serves_no_score(iris_model):
+    m, Xte, _ = iris_model
+    srv = TCAMServer(m.compiled, config=ServeConfig(background=False))
+    assert all(r.score is None for r in srv.serve(Xte[:5]))
+
+
+@pytest.mark.parametrize("mode", ["tree", "forest"])
+def test_batch_records_vote_and_cells(mode, iris_model, iris_forest):
+    """``vote_s`` is the forest's aggregation, inside ``finalize_s`` and
+    outside the tiling phases; ``match_cells`` and ``lut_cells`` count the
+    placed and the model's cells from the shapes."""
+    model, X = ((iris_model[0].compiled, iris_model[1]) if mode == "tree"
+                else iris_forest)
+    srv, _ = _served(model, X, [16, 3, 9])
+    rec = srv.metrics_store.batch_records()
+    assert "vote" not in PHASES and NESTED == ("vote",)
+    if srv._forest is None:
+        lay = srv._layout
+        per_row = lay.cells.shape[0] * lay.n_cwd * lay.s
+        lut = model.lut.n_total
+        assert (rec["vote_s"] == 0).all()
+    else:
+        per_row = sum(g.n_banks * g.r_pad * g.width
+                      for g in srv._f_plan.groups)
+        lut = sum(b.lut.n_total for b in model.banks)
+        assert (rec["vote_s"] > 0).all()
+        assert (rec["vote_s"] <= rec["finalize_s"]).all()
+    np.testing.assert_array_equal(rec["match_cells"],
+                                  per_row * rec["bucket"])
+    np.testing.assert_array_equal(rec["lut_cells"], lut * rec["n"])
+    assert (rec["lut_cells"] < rec["match_cells"]).all()
+
+
+def test_serve_vote_span_in_profiler_trace(iris_forest, tmp_path):
+    import glob
+
+    import jax
+    forest, Xte = iris_forest
+    srv = TCAMServer(forest, config=ServeConfig(
+        max_batch=16, min_bucket=4, background=False))
+    srv.warmup()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        srv.serve(Xte[np.arange(40) % len(Xte)])
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    ids = [v for plane in data.planes for line in plane.lines
+           for ev in line.events if ev.name == "serve.vote"
+           for k, v in ev.stats if k == "batch"]
+    rec = srv.metrics_store.batch_records()
+    assert sorted(ids) == sorted(rec["batch"])
