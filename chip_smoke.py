@@ -164,10 +164,9 @@ def warm_batch_s(server, repeats: int = 5) -> float:
     bucket = server.policy.buckets[-1]
     fn = server.cache.get(bucket, server.engine)
     if isinstance(fn, list):   # forest: one program per plan group
-        groups = server.metrics()["layout"]["groups"]
-        args = [jnp.zeros((g["banks"], bucket, g["d_pad"] * g["s"]),
-                          jnp.uint8) for g in groups]
-        call = lambda: [f(a) for f, a in zip(fn, args)]
+        f = server.metrics()["layout"]["n_features"]
+        u = jnp.zeros((bucket, f), jnp.int32)   # range indices, all groups
+        call = lambda: [g(u) for g in fn]
     else:
         w = server.metrics()["layout"]["width"]
         x = jnp.zeros((bucket, w), jnp.uint8)

@@ -4,9 +4,11 @@ Runs a compiled forest's execution plan on the banked kernels: every
 ``PlanGroup`` evaluates as ONE batched/vmapped kernel invocation (engine
 'banked' = batched einsum, 'mxu' = vmapped Pallas bitplane kernel), reduced
 on the device to each bank's first survivor, survivor count and active
-evaluations (``kernels.ops.serve_group``), with groups *pipelined* — group
-g+1's host-side input encoding overlaps group g's device compute via JAX
-async dispatch.  Engine 'ref' delegates to the pure-numpy oracle
+evaluations (``kernels.ops.serve_group``).  The host encodes a batch once,
+as (B, F) range indices against the union of every bank's thresholds per
+feature (``encode_ranges``); each group expands them into its banks'
+thermometer codes on the device through its column tables
+(``group_columns``).  Engine 'ref' delegates to the pure-numpy oracle
 (``forest_infer_ref``); all engines produce bit-identical survivors and
 therefore bit-identical votes.
 
@@ -32,7 +34,8 @@ from ..serve.cache import CompileCache
 from .compiler import CompiledForest, ForestResult, _aggregate, forest_infer_ref
 from .plan import ForestPlan, PlanGroup, plan_forest
 
-__all__ = ["ForestExecutor", "FOREST_ENGINES", "encode_group"]
+__all__ = ["ForestExecutor", "FOREST_ENGINES", "encode_group",
+           "encode_ranges", "group_columns", "union_thresholds"]
 
 FOREST_ENGINES = ("banked", "mxu", "ref")
 
@@ -44,7 +47,9 @@ def encode_group(
 
     Each bank encodes the SAME raw inputs through its OWN thresholds — banks
     cannot share search words, which is why the stacked input carries a bank
-    axis instead of broadcasting one batch.
+    axis instead of broadcasting one batch.  The host reference for the
+    served path, which builds these words on the device from
+    ``encode_ranges`` and ``group_columns`` (``kernels.ops.expand_ranges``).
     """
     b = Xp.shape[0]
     out = np.zeros((group.n_banks, b, group.width), dtype=np.uint8)
@@ -53,6 +58,53 @@ def encode_group(
         xpad = bank.layout.pad_inputs(encode_inputs(bank.lut, Xp))
         out[slot, :, : xpad.shape[1]] = xpad
     return out
+
+
+# a column table entry no range index reaches: the decoder bit and the
+# padding columns, always 0
+_NEVER = np.iinfo(np.int32).max
+
+
+def union_thresholds(forest: CompiledForest) -> list[np.ndarray]:
+    """Per feature j, the sorted float64 union of every bank's thresholds."""
+    return [np.unique(np.concatenate(
+        [np.asarray(b.lut.thresholds[j], np.float64) for b in forest.banks]))
+        for j in range(forest.n_features)]
+
+
+def group_columns(forest: CompiledForest, group: PlanGroup,
+                  union: list[np.ndarray]) -> tuple[np.ndarray, np.ndarray]:
+    """A plan group's column tables ``(feat, q)``, both (G, W_pad) int32:
+    column c of bank slot g's search word is ``u[:, feat[g, c]] >=
+    q[g, c]`` for the range indices ``u = encode_ranges(union, Xp)``.
+
+    Bit p of a bank's n-wide code for feature j is 1 iff that bank has at
+    least m = n - 1 - p thresholds below the value: always for m = 0, else
+    iff its m-th threshold, at index i of ``union[j]``, is below it, that
+    is iff ``u >= i + 1``."""
+    feat = np.zeros((group.n_banks, group.width), np.int32)
+    q = np.full((group.n_banks, group.width), _NEVER, np.int32)
+    for slot, bank_id in enumerate(group.bank_ids):
+        lut = forest.banks[int(bank_id)].lut
+        for j, th in enumerate(lut.thresholds):
+            lo, hi = 1 + lut.feat_offsets[j], 1 + lut.feat_offsets[j + 1]
+            at_least = np.concatenate(
+                [[0], 1 + np.searchsorted(union[j], th)])   # by m
+            feat[slot, lo:hi] = j
+            q[slot, lo:hi] = at_least[::-1]                 # m = n-1 .. 0
+    return feat, q
+
+
+def encode_ranges(union: list[np.ndarray], Xp: np.ndarray,
+                  bucket: Optional[int] = None) -> np.ndarray:
+    """(bucket, F) int32 range indices: ``u[b, j]`` counts the thresholds in
+    ``union[j]`` below ``Xp[b, j]`` (NaN counts all of them, as in
+    ``encode_inputs``).  Rows past ``len(Xp)`` are zero padding."""
+    Xp = np.asarray(Xp, np.float64)
+    u = np.zeros((bucket or len(Xp), len(union)), np.int32)
+    for j, th in enumerate(union):
+        u[: len(Xp), j] = np.searchsorted(th, Xp[:, j], side="left")
+    return u
 
 
 class ForestExecutor:
@@ -93,13 +145,14 @@ class ForestExecutor:
             [g.kmax0 for g in self.plan.groups] if kmax is None else list(kmax)
         )
         self._placed: dict = {}       # "engine:g<i>" -> serve_group args
+        self._union = union_thresholds(forest)
         self.cache = CompileCache(self._build, self.plan.plan_id)
 
     # -- compile machinery --------------------------------------------------
     def _build(self, bucket: int, key: str):
         """One jit'd ``serve_group`` per (batch-bucket, engine, group); the
-        group's grids, real rows and real divisions are placed on the device
-        once and passed as arguments."""
+        group's grids, real rows, real divisions and column tables are
+        placed on the device once and passed as arguments."""
         engine, gi = key.rsplit(":g", 1)
         args = self._placed.get(key)
         if args is None:
@@ -109,6 +162,8 @@ class ForestExecutor:
                             engine=engine, block_r=self.block_r),
                 jnp.asarray(grp.rows, jnp.int32),
                 jnp.asarray(grp.d_real, jnp.int32),
+                *map(jnp.asarray,
+                     group_columns(self.forest, grp, self._union)),
             )
         return functools.partial(serve_group, *args, block_b=self.block_b,
                                  interpret=self.interpret)
@@ -126,10 +181,10 @@ class ForestExecutor:
             return 0
         before = self.cache.misses
         bucket = self._bucket_for(batch)
-        for gi, grp in enumerate(self.plan.groups):
+        u = jnp.zeros((bucket, self.forest.n_features), jnp.int32)
+        for gi in range(self.plan.n_groups):
             fn = self.cache.get(bucket, f"{self.engine}:g{gi}")
-            x = jnp.zeros((grp.n_banks, bucket, grp.width), jnp.uint8)
-            jax.block_until_ready(fn(x))
+            jax.block_until_ready(fn(u))
         return self.cache.misses - before
 
     # -- execution ----------------------------------------------------------
@@ -150,15 +205,13 @@ class ForestExecutor:
         b = Xp.shape[0]
         bucket = self._bucket_for(b)
 
-        # pipelined dispatch: JAX queues group g's device compute
-        # asynchronously, so encoding group g+1 on the host overlaps it
-        pending = []
-        for gi, grp in enumerate(self.plan.groups):
-            xpad = encode_group(forest, grp, Xp)
-            if bucket > b:
-                xpad = np.pad(xpad, ((0, 0), (0, bucket - b), (0, 0)))
-            fn = self.cache.get(bucket, f"{self.engine}:g{gi}")
-            pending.append((grp, fn(jnp.asarray(xpad))))
+        # one (bucket, F) copy of range indices serves every group; each
+        # expands it into its banks' search words on the device
+        u = jnp.asarray(encode_ranges(self._union, Xp, bucket))
+        pending = [
+            (grp, self.cache.get(bucket, f"{self.engine}:g{gi}")(u))
+            for gi, grp in enumerate(self.plan.groups)
+        ]
 
         survivors = np.empty((forest.n_banks, b), np.int32)
         n_survivors = np.empty((forest.n_banks, b), np.int32)

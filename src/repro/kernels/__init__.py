@@ -12,9 +12,10 @@ analog-ReCAM -> TPU mapping.
   banked.py      — multi-bank (ensemble) batched/vmapped match
 """
 from .banked import BANKED_ENGINES, tcam_match_banked
-from .ops import (ENGINES, CellOperands, default_interpret, finalize_result,
-                  match_cells, place_cells, sa_kmax, select_engine,
-                  serve_batch, serve_group, tcam_infer, tcam_match)
+from .ops import (ENGINES, CellOperands, default_interpret, expand_ranges,
+                  finalize_result, match_cells, place_cells, sa_kmax,
+                  select_engine, serve_batch, serve_group, tcam_infer,
+                  tcam_match)
 from .ref import (pack_bits, tcam_match_banked_ref, tcam_match_packed_ref,
                   tcam_match_ref)
 from .tcam_match import tcam_match_pallas
@@ -23,7 +24,8 @@ from .tcam_packed import tcam_match_packed_pallas
 __all__ = [
     "ENGINES", "default_interpret", "finalize_result", "sa_kmax",
     "select_engine", "tcam_infer", "tcam_match",
-    "CellOperands", "place_cells", "match_cells", "serve_batch", "serve_group",
+    "CellOperands", "place_cells", "match_cells", "serve_batch",
+    "expand_ranges", "serve_group",
     "pack_bits", "tcam_match_packed_ref", "tcam_match_ref",
     "tcam_match_pallas", "tcam_match_packed_pallas",
     "BANKED_ENGINES", "tcam_match_banked", "tcam_match_banked_ref",

@@ -18,6 +18,8 @@ layout's cell grid; outputs are (survive, evals) as defined in ref.py.
 The serving paths reduce those (…, B, R) outputs on the device and copy back
 only what the host needs: ``serve_batch`` (one tree) returns four (B,)
 arrays, ``serve_group`` (one forest plan group) one (3, G, B) array.
+``serve_group`` takes (B, F) range indices and builds each bank's search
+words itself (``expand_ranges``).
 
 A cell grid is placed on the device once (``place_cells``) in the layout its
 engine's kernel reads, and every batch passes those arrays to the jitted
@@ -45,7 +47,8 @@ from .tcam_packed import tcam_match_packed_pallas
 
 __all__ = ["tcam_match", "tcam_infer", "sa_kmax", "select_engine",
            "finalize_result", "default_interpret", "ENGINES", "CellOperands",
-           "place_cells", "match_cells", "serve_batch", "serve_group"]
+           "place_cells", "match_cells", "serve_batch", "expand_ranges",
+           "serve_group"]
 
 ENGINES = ("auto", "mxu", "packed", "ref")
 
@@ -270,12 +273,32 @@ def serve_batch(ops: CellOperands, classes: jax.Array, xpad: jax.Array, *,
     return _finalize(survive, evals, classes)
 
 
+def expand_ranges(feat: jax.Array, q: jax.Array, u: jax.Array) -> jax.Array:
+    """Per-bank search words from per-feature range indices: (G, W) column
+    tables ``feat``, ``q`` and (B, F) ``u`` -> (G, B, W) uint8 with
+
+      bit[g, b, c] = u[b, feat[g, c]] >= q[g, c].
+
+    ``u[b, j]`` counts the thresholds of feature j, over every bank, below
+    request b's value (``forest.executor.encode_ranges``); a column's
+    ``q`` places one bank's threshold in that union, so the compare is that
+    bank's thermometer bit (``forest.executor.group_columns``).  One select
+    per feature picks each column's index, so no gather runs."""
+    g, w = feat.shape
+    picked = jnp.zeros((g, u.shape[0], w), jnp.int32)
+    for j in range(u.shape[1]):
+        picked = jnp.where(feat[:, None, :] == j, u[None, :, j, None], picked)
+    return (picked >= q[:, None, :]).astype(jnp.uint8)
+
+
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def serve_group(ops: CellOperands, rows: jax.Array, d_real: jax.Array,
-                xpad: jax.Array, *, block_b: int = 128,
-                interpret: bool = False) -> jax.Array:
-    """One served batch of a forest plan group: (G, B, W) padded search
-    words -> one (3, G, B) int32 array holding, per bank and request,
+                feat: jax.Array, q: jax.Array, u: jax.Array, *,
+                block_b: int = 128, interpret: bool = False) -> jax.Array:
+    """One served batch of a forest plan group: (B, F) int32 range indices
+    ``u``, expanded on the device into the group's (G, B, W) search words
+    through its column tables ``feat`` and ``q`` (``expand_ranges``) ->
+    one (3, G, B) int32 array holding, per bank and request,
 
       0: the first surviving row (0 when none survives),
       1: the number of surviving rows,
@@ -284,9 +307,10 @@ def serve_group(ops: CellOperands, rows: jax.Array, d_real: jax.Array,
          trivially match).
 
     Rows at or above a bank's real row count ``rows[g]`` count in none of
-    the three.  ``rows`` and ``d_real`` are (G,) int32 arguments like the
-    placed cells, so a plan rebuilt with the same shapes reuses the
+    the three.  ``rows``, ``d_real``, ``feat`` and ``q`` are arguments like
+    the placed cells, so a plan rebuilt with the same shapes reuses the
     compiled program."""
+    xpad = expand_ranges(feat, q, u)
     survive, evals = match_cells(ops, xpad, block_b=block_b,
                                  interpret=interpret)
     real = jnp.arange(survive.shape[-1]) < rows[:, None, None]

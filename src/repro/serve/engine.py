@@ -41,9 +41,10 @@ endurance ledger.  The circuit-breaker ladder gains a first rung: drifted ->
 scrub + refresh -> canary re-vote, before BIST+repair.
 
 Forest mode: constructed with a ``repro.forest.CompiledForest`` the server
-shards the batch path across TCAM banks — per-group batched kernels
-(``kernels.banked``) pipelined via jax async dispatch, per-bank survivors
-aggregated into one ensemble vote per request.  Chip health runs bank by
+shards the batch path across TCAM banks — the batch encoded once on the
+host as per-feature range indices, each bank's thermometer code expanded
+from them on the device, per-group batched kernels (``kernels.banked``),
+per-bank survivors aggregated into one ensemble vote per request.  Chip health runs bank by
 bank: BIST and spare-row repair per bank, survivors on remapped spare rows
 translated through a physical->LUT row map back to the right vote entries,
 and a bank whose repair stays degraded is disabled (drops out of the vote
@@ -416,6 +417,8 @@ class TCAMServer:
         """Forest mode: every bank is its own physical array with its own
         sampled stuck-fault mask and SA offsets; a defective bank degrades
         the ensemble vote instead of taking down the chip."""
+        from ..forest.executor import union_thresholds
+
         forest = self._forest
         self._n_features = forest.n_features
         n = forest.n_banks
@@ -445,6 +448,7 @@ class TCAMServer:
                 self._f_kmax_banks[i] = sa_kmax(lay, offsets, self._hw)
         self._f_enabled = np.ones(n, dtype=bool)
         self._f_lut_cells = sum(b.lut.n_total for b in forest.banks)
+        self._f_union = union_thresholds(forest)
         # physical row -> LUT (vote-table) row; spares start unassigned and
         # inherit a LUT row when repair remaps a defective rule onto them
         self._f_row_map = []
@@ -456,11 +460,17 @@ class TCAMServer:
         self.engine = self._resolve_forest_engine(self._config.engine)
 
     def _rebuild_plan(self) -> None:
-        """(Re)shard the served (possibly faulted/repaired) bank layouts and
-        splice each bank's SA-variability kmax into its group slot."""
+        """(Re)shard the served (possibly faulted/repaired) bank layouts,
+        splice each bank's SA-variability kmax into its group slot and build
+        each group's column tables."""
+        from ..forest.executor import group_columns
         from ..forest.plan import plan_forest
 
         self._f_plan = plan_forest(self._f_layouts)
+        self._f_group_cols = [
+            group_columns(self._forest, grp, self._f_union)
+            for grp in self._f_plan.groups
+        ]
         self._f_group_kmax = []
         for grp in self._f_plan.groups:
             km = np.array(grp.kmax0, copy=True)
@@ -559,12 +569,14 @@ class TCAMServer:
 
     def _forest_builder(self):
         """Forest builder: per (bucket, engine), a list of ``serve_group``
-        calls, one per plan group — each evaluates its whole stack of banks
-        in a single kernel invocation on the group's placed grids and
-        reduces the match to (3, G, B) on the device.  The grids and each
-        group's real rows and divisions are placed once and passed as
-        arguments."""
-        groups = list(zip(self._f_plan.groups, self._f_group_kmax))
+        calls, one per plan group — each expands the batch's range indices
+        into its banks' search words, evaluates its whole stack of banks in
+        a single kernel invocation on the group's placed grids and reduces
+        the match to (3, G, B) on the device.  The grids, each group's real
+        rows and divisions and its column tables are placed once and passed
+        as arguments."""
+        groups = list(zip(self._f_plan.groups, self._f_group_kmax,
+                          self._f_group_cols))
         placed = {}
 
         def build(bucket: int, engine: str):
@@ -572,8 +584,9 @@ class TCAMServer:
                 placed[engine] = [
                     (place_cells(g.cells, g.s, km, engine=engine),
                      jnp.asarray(g.rows, jnp.int32),
-                     jnp.asarray(g.d_real, jnp.int32))
-                    for g, km in groups
+                     jnp.asarray(g.d_real, jnp.int32),
+                     *map(jnp.asarray, cols))
+                    for g, km, cols in groups
                 ]
             return [functools.partial(serve_group, *args,
                                       interpret=self.interpret)
@@ -587,11 +600,9 @@ class TCAMServer:
         before = self.cache.misses
         for b in self.policy.buckets:
             if self._forest is not None:
-                fns = self.cache.get(b, self.engine)
-                for grp, fn in zip(self._f_plan.groups, fns):
-                    jax.block_until_ready(fn(
-                        jnp.zeros((grp.n_banks, b, grp.width), jnp.uint8)
-                    ))
+                u = jnp.zeros((b, self._n_features), jnp.int32)
+                for fn in self.cache.get(b, self.engine):
+                    jax.block_until_ready(fn(u))
                 continue
             fn = self.cache.get(b, self.engine)
             w = self._layout.n_cwd * self._layout.s
@@ -870,14 +881,15 @@ class TCAMServer:
 
     def _process_inner_forest(self, batch: list, deadline_flush: bool,
                               sp: _BatchSpans) -> int:
-        """Forest-mode batch: pipelined per-group compute + vote aggregation.
+        """Forest-mode batch: per-group compute + vote aggregation.
 
-        Group g+1's host-side input encoding overlaps group g's device
-        compute (JAX async dispatch), then per-bank survivors aggregate into
-        one ensemble vote per request — disabled (defective) banks drop out
-        of both the vote and the divisor."""
+        The host encodes the batch once, as (bucket, F) range indices, and
+        copies them to the device once; every plan group expands them into
+        its banks' search words there.  Per-bank survivors then aggregate
+        into one ensemble vote per request — disabled (defective) banks drop
+        out of both the vote and the divisor."""
         from ..forest.compiler import aggregate_votes
-        from ..forest.executor import encode_group
+        from ..forest.executor import encode_ranges
 
         forest = self._forest
         t_form = sp.t_form
@@ -894,17 +906,15 @@ class TCAMServer:
                 X = X + self._rng.normal(0.0, self._spec.sigma_in,
                                          size=X.shape)
             Xp = forest.prepare_inputs(X, who="TCAMServer")
+            u = encode_ranges(self._f_union, Xp, bucket)
 
         fns = self.cache.get(bucket, self.engine)
-        pending = []
-        for grp, fn in zip(self._f_plan.groups, fns):
-            with sp.span("encode"):
-                xpad = encode_group(forest, grp, Xp)
-                if bucket > n:
-                    xpad = np.pad(xpad, ((0, 0), (0, bucket - n), (0, 0)))
-            with sp.span("dispatch"):
-                pending.append((grp, fn(jnp.asarray(xpad))))
-                sp.h2d_bytes += xpad.nbytes
+        with sp.span("dispatch"):
+            u_dev = jnp.asarray(u)
+            sp.h2d_bytes += u.nbytes
+            pending = []
+            for grp, fn in zip(self._f_plan.groups, fns):
+                pending.append((grp, fn(u_dev)))
                 sp.match_cells += (grp.n_banks * grp.r_pad * grp.width
                                    * bucket)
         sp.lut_cells += self._f_lut_cells * n
@@ -1616,6 +1626,7 @@ class TCAMServer:
                 forest_figures=figs,
                 layout={
                     "n_banks": self._f_plan.n_banks,
+                    "n_features": self._n_features,
                     "groups": [
                         {"banks": int(g.n_banks), "r_pad": g.r_pad,
                          "d_pad": g.d_pad, "s": g.s}

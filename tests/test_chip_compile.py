@@ -23,8 +23,8 @@ from repro.kernels import (CellOperands, match_cells, place_cells,
 # 8576 physical rows, 39 column divisions.
 CREDIT = dict(s=128, d=39, r=8576)
 # scikit-learn's default 100-tree forest on the COVID-19 shape at S=128:
-# one plan group of 100 banks, 4096 rows, 4 divisions.
-COVID_RF100 = dict(g=100, s=128, d=4, r=4096)
+# one plan group of 100 banks, 4096 rows, 4 divisions, 4 features.
+COVID_RF100 = dict(g=100, s=128, d=4, r=4096, f=4)
 
 
 @pytest.fixture(scope="module")
@@ -127,8 +127,9 @@ def test_forest_mxu_vmapped_banks_compile(one_chip):
 @pytest.mark.parametrize("engine", ["banked", "mxu"])
 def test_forest_group_program_compiles(one_chip, engine):
     """The server's whole batch program for one forest plan group at the
-    100-tree forest's size, bucket 256: it copies back (3, G, B) int32."""
-    g, s, d, r = (COVID_RF100[k] for k in ("g", "s", "d", "r"))
+    100-tree forest's size, bucket 256: (256, F) int32 range indices in,
+    expanded through (G, W) column tables, (3, G, B) int32 back."""
+    g, s, d, r, f = (COVID_RF100[k] for k in ("g", "s", "d", "r", "f"))
     if engine == "banked":
         arrays = tuple(
             jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -139,11 +140,12 @@ def test_forest_group_program_compiles(one_chip, engine):
     else:
         ops = _operands(one_chip, engine, s, d, r, lead=(g,))
     per_bank = jax.ShapeDtypeStruct((g,), jnp.int32, sharding=one_chip)
-    x = jax.ShapeDtypeStruct((g, 256, d * s), jnp.uint8, sharding=one_chip)
+    column = jax.ShapeDtypeStruct((g, d * s), jnp.int32, sharding=one_chip)
+    u = jax.ShapeDtypeStruct((256, f), jnp.int32, sharding=one_chip)
     compiled = jax.jit(
-        lambda o, rows, d_real, xp: serve_group(o, rows, d_real, xp,
-                                                interpret=False)
-    ).lower(ops, per_bank, per_bank, x).compile()
+        lambda o, rows, d_real, feat, q, u: serve_group(
+            o, rows, d_real, feat, q, u, interpret=False)
+    ).lower(ops, per_bank, per_bank, column, column, u).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == (engine == "mxu")
     out = compiled.out_info
     assert (out.shape, out.dtype) == ((3, g, 256), jnp.int32)

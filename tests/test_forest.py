@@ -10,6 +10,7 @@ survivors resolving to the right vote entries.
 """
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -31,8 +32,10 @@ from repro.forest import (
     plan_forest,
     train_forest,
 )
-from repro.kernels import (match_cells, place_cells, serve_group,
-                           tcam_match_banked)
+from repro.forest.executor import (encode_ranges, group_columns,
+                                   union_thresholds)
+from repro.kernels import (expand_ranges, match_cells, place_cells,
+                           serve_group, tcam_match_banked)
 
 sklearn = pytest.importorskip("sklearn")
 from sklearn.ensemble import RandomForestClassifier  # noqa: E402
@@ -184,18 +187,31 @@ def _group_case(rng, s=32, d_pad=4, r_pad=256):
     return cells, kmax, rows, d_real
 
 
+def _range_case(rng, g, w, bucket, n, f=16):
+    """Random column tables and range indices for a (g, w) group, and the
+    (g, bucket, w) search words they expand to, built on the host; rows
+    past ``n`` are zero padding, as ``encode_ranges`` leaves them."""
+    feat = rng.integers(0, f, (g, w)).astype(np.int32)
+    q = rng.integers(0, 9, (g, w)).astype(np.int32)
+    q[:, 0] = np.iinfo(np.int32).max            # a decoder bit: always 0
+    u = np.zeros((bucket, f), np.int32)
+    u[:n] = rng.integers(0, 9, (n, f))
+    x = (u[:, feat].swapaxes(0, 1) >= q[:, None, :]).astype(np.uint8)
+    return feat, q, u, x
+
+
 @pytest.mark.parametrize("n", [5, 16])
 @pytest.mark.parametrize("engine", ["banked", "mxu", "ref"])
 def test_serve_group_equals_host_reduction(engine, n):
     rng = np.random.default_rng(7)
     cells, kmax, rows, d_real = _group_case(rng)
     bucket = 8 if n <= 8 else 16
-    x = np.zeros((3, bucket, cells.shape[-1]), np.uint8)
-    x[:, :n] = rng.integers(0, 2, (3, n, cells.shape[-1]))
+    feat, q, u, x = _range_case(rng, 3, cells.shape[-1], bucket, n)
+    assert (x[:, :, 0] == 0).all() and 0.3 < x[:, :n].mean() < 0.7
     ops = place_cells(cells, 32, kmax, engine=engine)
     got = np.asarray(serve_group(
         ops, jnp.asarray(rows, jnp.int32), jnp.asarray(d_real, jnp.int32),
-        jnp.asarray(x), interpret=True))
+        jnp.asarray(feat), jnp.asarray(q), jnp.asarray(u), interpret=True))
     assert got.shape == (3, 3, bucket) and got.dtype == np.int32
     survive, evals = (np.asarray(o) for o in match_cells(ops, jnp.asarray(x),
                                                           interpret=True))
@@ -211,6 +227,79 @@ def test_serve_group_equals_host_reduction(engine, n):
     # more; neither counts
     assert (evals[:, :n, rows[2]:] > 0).all()
     assert (evals[2, :n, :rows[2]] > d_real[2]).any()
+
+
+# --------------------------------------------------------------------------
+# search words expanded on the device from per-feature range indices
+# --------------------------------------------------------------------------
+def _rf(dataset, vote, **kw):
+    Xtr, ytr, Xte, _ = load_split(dataset)
+    rf = RandomForestClassifier(random_state=0, **kw).fit(Xtr, ytr)
+    return compile_forest(rf, s=32, vote=vote), Xte
+
+
+def _edge_rows(forest, X):
+    """Rows of ``X`` with one feature each set exactly on a threshold of
+    the union, to +inf, -inf or NaN."""
+    rows = []
+    for j, th in enumerate(union_thresholds(forest)):
+        for v in (*th[:: max(1, th.size // 3)], np.inf, -np.inf, np.nan):
+            row = X[len(rows) % len(X)].copy()
+            row[j] = v
+            rows.append(row)
+    return np.array(rows)
+
+
+def _expansion_case(case):
+    """(forest, X, bucket) for one case of the expansion test."""
+    if case.startswith("hgb-"):
+        model, X = hgb_case(case[4:])
+        return compile_forest(model, s=32), X, None
+    if case == "featureless-bank":
+        forest, X = _rf("cancer", "soft", n_estimators=5, max_depth=2)
+        union = union_thresholds(forest)
+        assert any(b.lut.thresholds[j].size == 0 and union[j].size > 0
+                   for b in forest.banks for j in range(forest.n_features))
+        return forest, X, None
+    forest, X = _rf("cancer", "hard" if case == "rf-hard" else "soft",
+                    n_estimators=6, max_depth=6)
+    if case == "edges":
+        X = _edge_rows(forest, X)
+        Xp = forest.prepare_inputs(X)
+        assert any(np.isin(Xp[:, j], th).any()
+                   for j, th in enumerate(union_thresholds(forest)))
+    if case == "padded":
+        return forest, X[:13], 16
+    return forest, X, None
+
+
+@pytest.mark.parametrize("case", ["rf-soft", "rf-hard", "hgb-binary",
+                                  "hgb-multiclass", "edges", "padded",
+                                  "featureless-bank"])
+def test_device_expansion_equals_encode_group(case):
+    """For every plan group, the search words the device builds from the
+    (bucket, F) range indices equal the host's per-bank ``encode_group``
+    bit for bit; rows padded up to the bucket are ignored."""
+    forest, X, bucket = _expansion_case(case)
+    assert forest.cast_f32 == (not case.startswith("hgb-"))
+    Xp = forest.prepare_inputs(X)
+    n = len(X)
+    union = union_thresholds(forest)
+    u = encode_ranges(union, Xp, bucket)
+    assert u.shape == (bucket or n, forest.n_features) and u.dtype == np.int32
+    assert (u[n:] == 0).all()
+    for grp in plan_forest(forest).groups:
+        feat, q = group_columns(forest, grp, union)
+        got = np.asarray(jax.jit(expand_ranges)(feat, q, u))
+        assert got.shape == (grp.n_banks, len(u), grp.width)
+        np.testing.assert_array_equal(got[:, :n],
+                                      encode_group(forest, grp, Xp))
+    if bucket is not None:
+        res = repro.ForestExecutor(forest, engine="banked").infer(X)
+        ref = forest_infer_ref(forest, X)
+        for k in ("predictions", "survivors", "n_survivors", "active_evals"):
+            np.testing.assert_array_equal(getattr(res, k), getattr(ref, k),
+                                          err_msg=k)
 
 
 # --------------------------------------------------------------------------

@@ -1,13 +1,14 @@
 """Serving engine: adaptive batching, bucket-bounded jit compiles, metrics,
 engine fallback, and the >=1k-request smoke test from the PR acceptance
 criteria."""
+import dataclasses
 import threading
 import time
 
 import numpy as np
 import pytest
 
-from repro.core import DT2CAM, NonIdealSpec
+from repro.core import DEFAULT_HW, DT2CAM, NonIdealSpec
 from repro.dt import load_split
 from repro.serve import (AdaptiveBatcher, BucketPolicy, CompileCache,
                          ComputeFailed, DeadlineExceeded, LatencyStats,
@@ -497,7 +498,7 @@ def _copy_bytes(srv, bucket):
     if srv._forest is None:
         return bucket * srv._layout.n_cwd * srv._layout.s, 4 * 4 * bucket
     groups = srv._f_plan.groups
-    return (sum(g.n_banks * bucket * g.width for g in groups),
+    return (bucket * srv._forest.n_features * 4,
             sum(3 * 4 * g.n_banks * bucket for g in groups))
 
 
@@ -586,6 +587,71 @@ def test_background_worker_records_every_batch(iris_model):
     rec = srv.metrics_store.batch_records()      # the worker has stopped
     assert rec["n"].sum() == len(res) == 50
     assert {r.batch for r in res} == set(rec["batch"])
+
+
+@pytest.fixture(scope="module")
+def spared_forest():
+    """A hard-vote forest on 30 features with spare rows for repair."""
+    from repro.forest import compile_forest, train_forest
+    Xtr, ytr, Xte, _ = load_split("cancer")
+    trees = train_forest(Xtr, ytr, n_trees=4, max_depth=6, seed=0)
+    return compile_forest(trees, s=16, spare_rows=4), Xte[:37]
+
+
+def _served_fields(results) -> dict:
+    keys = ("prediction", "survivor", "n_survivors", "active_evals",
+            "energy_j", "score")
+    return {k: np.array([getattr(r, k) for r in results]) for k in keys}
+
+
+def _ref_served_fields(forest, X, enabled) -> dict:
+    """What the server answers, from ``forest_infer_ref``."""
+    from repro.forest import forest_infer_ref
+    ref = forest_infer_ref(forest, X, enabled=enabled)
+    total = ref.active_evals[enabled].sum(axis=0)
+    return {
+        "prediction": ref.predictions,
+        "survivor": np.full(len(X), -1),
+        "n_survivors": (ref.survivors[enabled] >= 0).sum(axis=0),
+        "active_evals": total,
+        "energy_j": (total.astype(np.float64) * DEFAULT_HW.e_row
+                     + int(enabled.sum()) * DEFAULT_HW.e_mem),
+        "score": ref.score,
+    }
+
+
+@pytest.mark.parametrize("state", ["ideal", "disable_bank", "repair"])
+def test_forest_served_from_range_indices_equals_ref(state, spared_forest):
+    """Forest mode copies (bucket, F) int32 range indices once a batch and
+    expands them on the device; every field still equals the reference —
+    after ``disable_bank``, and after a repair that re-plans the banks and
+    rebuilds the column tables, against the repaired chip's layouts."""
+    forest, X = spared_forest
+    nonideal = (NonIdealSpec(p_sa0=0.003, p_sa1=0.003) if state == "repair"
+                else NonIdealSpec())
+    srv = TCAMServer(forest, config=ServeConfig(
+        engine="banked", max_batch=16, min_bucket=4, background=False),
+        nonideal=nonideal, rng=np.random.default_rng(3))
+    ref_forest = forest
+    if state == "disable_bank":
+        srv.disable_bank(1)
+    elif state == "repair":
+        plan, cols = srv._f_plan, srv._f_group_cols
+        reports = srv.repair(srv.self_test())
+        assert sum(r.rows_repaired for r in reports) > 0
+        assert srv._f_plan is not plan and srv._f_group_cols is not cols
+        ref_forest = dataclasses.replace(forest, banks=[
+            dataclasses.replace(b, compiled=dataclasses.replace(
+                b.compiled, layout=lay))
+            for b, lay in zip(forest.banks, srv._f_layouts)])
+    got = _served_fields(srv.serve(X))
+    want = _ref_served_fields(ref_forest, X, srv._f_enabled)
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+    rec = srv.metrics_store.batch_records()
+    assert (rec["n"] < rec["bucket"]).any()
+    np.testing.assert_array_equal(rec["h2d_bytes"],
+                                  rec["bucket"] * forest.n_features * 4)
 
 
 # --------------------------------------------------------------------------
