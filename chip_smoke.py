@@ -15,7 +15,7 @@ One process holds the chip and runs every phase in turn:
   faulty  the credit tree on a chip with stuck-at faults and sense-amp
           offsets (CELL_MM cells: 'auto' -> mxu; kmax != 0), 1024 rows.
   forest  an 8-tree bagged forest on cancer (depth 8, S=128) in forest
-          mode, engines 'banked' and 'mxu', and through ``ForestExecutor``.
+          mode on engine 'banked', and through ``ForestExecutor``.
 
 Every phase fails the run unless each Future resolves to a ``RequestResult``
 whose prediction, survivor, survivor count, active evaluations and energy
@@ -362,7 +362,7 @@ def main() -> int:
     tree_phase("credit", credit, 128, [("auto", "mxu")], 1024,
                nonideal=repro.NonIdealSpec(p_sa0=0.01, p_sa1=0.01,
                                            sa_sigma=0.05))
-    forest_phase(["banked", "mxu"], 512)
+    forest_phase(["banked"], 512)
 
     emit({"phase": "total", "seconds": time.perf_counter() - t_start,
           "compile_cache_dir": cache_dir,

@@ -133,8 +133,8 @@ __all__ = [
     "WritePlan", "plan_delta", "plan_full", "plan_forest_delta",
     "WearTracker", "RemapResult", "wear_level_rows", "LifecycleManager",
     # jax-dependent (lazy): kernels
-    "tcam_infer", "tcam_match", "tcam_match_banked", "ENGINES",
-    "BANKED_ENGINES", "select_engine", "finalize_result",
+    "tcam_infer", "tcam_match", "ENGINES", "select_engine",
+    "finalize_result",
     # jax-dependent (lazy): executors + serving
     "ForestExecutor", "FOREST_ENGINES",
     "TCAMServer", "ServeConfig", "RequestResult", "PromotionReport",
@@ -146,9 +146,7 @@ __all__ = [
 _LAZY = {
     "tcam_infer": "kernels",
     "tcam_match": "kernels",
-    "tcam_match_banked": "kernels",
     "ENGINES": "kernels",
-    "BANKED_ENGINES": "kernels",
     "select_engine": "kernels",
     "finalize_result": "kernels",
     "ForestExecutor": "forest",

@@ -8,10 +8,10 @@ on one chip to a forest sharded across TCAM banks:
   compiler.py   — compile_forest / ForestBank / CompiledForest + the
                   pure-numpy reference executor and vote aggregation
   plan.py       — ForestPlan: power-of-two shape bucketing, bank stacking
-  executor.py   — ForestExecutor: batched/vmapped JAX execution, each
-                  bank's input code expanded on the device from one copy
-                  of per-feature range indices (imported lazily — needs
-                  jax)
+  executor.py   — ForestExecutor: the one owner of a served forest, its
+                  placed plan groups and batch steps (encode, dispatch,
+                  gather), used by TCAMServer's forest mode; engines
+                  'banked' and 'ref' (imported lazily — needs jax)
 
 ``compile_forest`` + ``forest_infer_ref`` are numpy-only; accessing
 ``ForestExecutor`` (or anything from ``executor``) pulls in jax on demand.

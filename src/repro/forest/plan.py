@@ -2,7 +2,7 @@
 
 Each bank is an independent tiled TCAM, but banks whose padded shapes agree
 can be evaluated by ONE batched kernel invocation over a leading bank axis
-(``repro.kernels.banked``).  ``plan_forest`` buckets every bank's physical
+(``kernels.ops.serve_group``).  ``plan_forest`` buckets every bank's physical
 (rows, divisions) up a power-of-two ladder — the same ``BucketPolicy``
 machinery the serving engine uses for batch shapes — and stacks banks with
 equal bucketed shape into a ``PlanGroup``:

@@ -8,10 +8,10 @@ analog-ReCAM -> TPU mapping.
   ops.py         — engine selection, cell placement, padding,
                    SA-variability lowering, jit'd serving paths (a tree's
                    batch, a forest plan group's batch)
-  ref.py         — pure-jnp oracles both kernels are validated against
-  banked.py      — multi-bank (ensemble) batched/vmapped match
+  ref.py         — pure-jnp oracles both kernels are validated against,
+                   and the forest 'banked' engine (a batched einsum over a
+                   leading bank axis)
 """
-from .banked import BANKED_ENGINES, tcam_match_banked
 from .ops import (ENGINES, CellOperands, default_interpret, expand_ranges,
                   finalize_result, match_cells, place_cells, sa_kmax,
                   select_engine, serve_batch, serve_group, tcam_infer,
@@ -28,5 +28,5 @@ __all__ = [
     "expand_ranges", "serve_group",
     "pack_bits", "tcam_match_packed_ref", "tcam_match_ref",
     "tcam_match_pallas", "tcam_match_packed_pallas",
-    "BANKED_ENGINES", "tcam_match_banked", "tcam_match_banked_ref",
+    "tcam_match_banked_ref",
 ]

@@ -116,7 +116,18 @@ def tcam_match_banked_ref(
     s: int,
     kmax: jax.Array,    # (G, R, D) int32; -1 rows always mismatch
 ) -> tuple[jax.Array, jax.Array]:
-    """Batched-einsum banked match: (survive, evals), both (G, B, R) int32."""
+    """The forest 'banked' engine: one batched einsum over a plan group's
+    G same-shape banks, each with its own search words (each tree has its
+    own thresholds).  Returns (survive, evals), both (G, B, R) int32, with
+    the single-bank selective-precharge semantics of ``tcam_match_ref``:
+
+      mism[g, b, r, d] = Σ_{w∈d} x[g]·is0[g] + (1 - x[g])·is1[g]
+
+    Padding rows carry ``kmax = -1`` (always mismatch) and padding
+    divisions are all-CELL_X (trivially match).  Padding rows still report
+    one evaluation each and padding divisions inflate the counts, so whoever
+    reduces them masks rows at or above each bank's real row count and
+    clamps with ``min(evals, d_real)`` (``ops.serve_group``)."""
     g, b, w = xpad.shape
     r = is0.shape[1]
     assert w % s == 0, (w, s)

@@ -41,14 +41,15 @@ endurance ledger.  The circuit-breaker ladder gains a first rung: drifted ->
 scrub + refresh -> canary re-vote, before BIST+repair.
 
 Forest mode: constructed with a ``repro.forest.CompiledForest`` the server
-shards the batch path across TCAM banks — the batch encoded once on the
-host as per-feature range indices, each bank's thermometer code expanded
-from them on the device, per-group batched kernels (``kernels.banked``),
-per-bank survivors aggregated into one ensemble vote per request.  Chip health runs bank by
-bank: BIST and spare-row repair per bank, survivors on remapped spare rows
-translated through a physical->LUT row map back to the right vote entries,
-and a bank whose repair stays degraded is disabled (drops out of the vote
-and the divisor) instead of poisoning the ensemble.  A boosted forest
+builds one ``repro.forest.ForestExecutor`` on the chip's per-bank faulted
+layouts and serves every batch through its steps — the batch encoded once
+on the host as per-feature range indices, one batched match per plan group
+on the device, per-bank survivors through the physical->LUT row maps —
+then aggregates one ensemble vote per request.  Chip health runs bank by
+bank: BIST and spare-row repair per bank (the server keeps each bank's
+intent and stuck-fault mask; the executor serves the repaired layouts and
+row maps), and a bank whose repair stays degraded is disabled (drops out of
+the vote and the divisor) instead of poisoning the ensemble.  A boosted forest
 (``HistGradientBoostingClassifier``) is served the same way: its margin is
 summed in the same loop as a soft vote, and each ``RequestResult`` carries
 the class probabilities in ``score``.
@@ -89,7 +90,7 @@ from ..core.nonideal import (
 from ..degradation import ScrubPolicy, ScrubReport, ScrubScheduler, \
     layout_margins
 from ..kernels.ops import (default_interpret, place_cells, sa_kmax,
-                           select_engine, serve_batch, serve_group)
+                           select_engine, serve_batch)
 from ..reliability.bist import BistReport, run_bist
 from ..reliability.canary import CanaryProbe, CircuitBreaker, make_canary
 from ..reliability.repair import RepairReport, repair_layout
@@ -115,7 +116,8 @@ class ServeConfig:
     max_batch: int = 256          # flush as soon as this many are pending
     max_delay_s: float = 0.002    # oldest-request queueing deadline
     min_bucket: int = 8           # smallest padded batch shape
-    engine: str = "auto"          # 'auto' | 'mxu' | 'packed' | 'ref'
+    engine: str = "auto"          # 'auto' | 'mxu' | 'packed' | 'ref';
+                                  # forests: 'auto' | 'banked' | 'ref'
     interpret: Optional[bool] = None   # Pallas interpret mode (None: on
                                        # unless the backend is a TPU)
     background: bool = True       # worker thread vs explicit pump()/drain()
@@ -295,6 +297,9 @@ class TCAMServer:
         self._drift: Optional[DriftModel] = None
         self._scrub: Optional[ScrubScheduler] = None
         self._batches_since_scrub = 0
+        self.policy = BucketPolicy(
+            max_batch=config.max_batch, min_bucket=config.min_bucket
+        )
 
         # multi-bank (forest) mode: a CompiledForest shards the serving path
         # across banks (duck-typed to keep repro.forest an optional import)
@@ -308,11 +313,7 @@ class TCAMServer:
             self._init_forest_state(nonideal)
         else:
             self._init_single_state(compiled, nonideal)
-
-        self.policy = BucketPolicy(
-            max_batch=config.max_batch, min_bucket=config.min_bucket
-        )
-        self.cache = self._make_cache()
+            self.cache = self._make_cache()
 
         # -- lifecycle: shadow slot + atomic model swap --------------------
         # every batch/canary runs its whole compute under this lock, so a
@@ -416,8 +417,9 @@ class TCAMServer:
     def _init_forest_state(self, nonideal: NonIdealSpec) -> None:
         """Forest mode: every bank is its own physical array with its own
         sampled stuck-fault mask and SA offsets; a defective bank degrades
-        the ensemble vote instead of taking down the chip."""
-        from ..forest.executor import union_thresholds
+        the ensemble vote instead of taking down the chip.  The executor
+        serves the faulted layouts."""
+        from ..forest.executor import ForestExecutor
 
         forest = self._forest
         self._n_features = forest.n_features
@@ -425,7 +427,7 @@ class TCAMServer:
         self._f_intent = [np.array(b.layout.cells, copy=True)
                           for b in forest.banks]
         self._f_masks: list[Optional[SAFMask]] = [None] * n
-        self._f_layouts = []
+        layouts = []
         for i, bank in enumerate(forest.banks):
             lay = bank.layout
             if nonideal.has_saf:
@@ -437,53 +439,28 @@ class TCAMServer:
                 faulted = apply_saf_mask(self._f_intent[i], mask)
                 faulted[:, 1 + lay.width:] = CELL_X
                 lay = dataclasses.replace(lay, cells=faulted)
-            self._f_layouts.append(lay)
-        self._f_kmax_banks: list[Optional[np.ndarray]] = [None] * n
+            layouts.append(lay)
+        kmax: list[Optional[np.ndarray]] = [None] * n
         if nonideal.sa_sigma > 0:
-            for i, lay in enumerate(self._f_layouts):
+            for i, lay in enumerate(layouts):
                 offsets = self._rng.normal(
                     0.0, nonideal.sa_sigma,
                     size=(lay.cells.shape[0], lay.n_cwd),
                 )
-                self._f_kmax_banks[i] = sa_kmax(lay, offsets, self._hw)
-        self._f_enabled = np.ones(n, dtype=bool)
-        self._f_lut_cells = sum(b.lut.n_total for b in forest.banks)
-        self._f_union = union_thresholds(forest)
-        # physical row -> LUT (vote-table) row; spares start unassigned and
-        # inherit a LUT row when repair remaps a defective rule onto them
-        self._f_row_map = []
-        for lay in self._f_layouts:
-            rm = np.full(lay.cells.shape[0], -1, dtype=np.int32)
-            rm[: lay.n_rows] = np.arange(lay.n_rows, dtype=np.int32)
-            self._f_row_map.append(rm)
-        self._rebuild_plan()
-        self.engine = self._resolve_forest_engine(self._config.engine)
-
-    def _rebuild_plan(self) -> None:
-        """(Re)shard the served (possibly faulted/repaired) bank layouts,
-        splice each bank's SA-variability kmax into its group slot and build
-        each group's column tables."""
-        from ..forest.executor import group_columns
-        from ..forest.plan import plan_forest
-
-        self._f_plan = plan_forest(self._f_layouts)
-        self._f_group_cols = [
-            group_columns(self._forest, grp, self._f_union)
-            for grp in self._f_plan.groups
-        ]
-        self._f_group_kmax = []
-        for grp in self._f_plan.groups:
-            km = np.array(grp.kmax0, copy=True)
-            for slot, bank_id in enumerate(grp.bank_ids):
-                k = self._f_kmax_banks[int(bank_id)]
-                if k is not None:
-                    km[slot, : k.shape[0], : k.shape[1]] = k
-            self._f_group_kmax.append(km)
+                kmax[i] = sa_kmax(lay, offsets, self._hw)
+        requested = self._config.engine
+        self._fx = ForestExecutor(
+            forest, engine=requested, hw=self._hw, interpret=self.interpret,
+            layouts=layouts, kmax=kmax, policy=self.policy,
+            cache_size=self._config.compile_cache_size,
+        )
+        if self._fx.engine != requested and requested != "auto":
+            self.metrics_store.on_fallback()
+        self.engine = self._fx.engine
+        self.cache = self._fx.cache
 
     # -- engine & compile machinery ---------------------------------------
     def _layout_id(self, layout=None) -> str:
-        if layout is None and self._forest is not None:
-            return "forest-" + self._f_plan.plan_id
         lay = self._layout if layout is None else layout
         return hashlib.sha1(
             lay.cells.tobytes()
@@ -494,31 +471,10 @@ class TCAMServer:
     def _make_cache(self, builder=None, layout_id: Optional[str] = None
                     ) -> CompileCache:
         return CompileCache(
-            builder if builder is not None else self._builder(),
+            builder if builder is not None
+            else self._single_builder(self._layout, self._kmax),
             layout_id if layout_id is not None else self._layout_id(),
             maxsize=self._config.compile_cache_size,
-        )
-
-    def _resolve_forest_engine(self, requested: str) -> str:
-        """Forest engines: 'banked' (batched einsum), 'mxu' (vmapped Pallas),
-        'ref' (oracle).  'auto' means 'banked'; 'packed' is unrepresentable
-        for stacked banks and falls back with a warning."""
-        if requested == "auto":
-            return "banked"
-        if requested in ("banked", "mxu", "ref"):
-            return requested
-        if requested == "packed":
-            warnings.warn(
-                "engine 'packed' is not available in forest mode; "
-                "falling back to 'banked'",
-                RuntimeWarning,
-                stacklevel=3,
-            )
-            self.metrics_store.on_fallback()
-            return "banked"
-        raise ValueError(
-            f"unknown forest engine {requested!r}; expected 'auto', "
-            "'banked', 'mxu' or 'ref'"
         )
 
     def _resolve_engine(self, requested: str, layout=None) -> str:
@@ -537,15 +493,6 @@ class TCAMServer:
             )
             self.metrics_store.on_fallback()
             return "mxu"
-
-    def _builder(self):
-        """Batch-function builder for the live chip state: one jit'd batch
-        function per (bucket, engine) — (bucket, W) padded search words ->
-        (preds, survivors, n_survivors, active_evals).  Forest mode builds
-        one jit'd ``serve_group`` per plan group instead."""
-        if self._forest is not None:
-            return self._forest_builder()
-        return self._single_builder(self._layout, self._kmax)
 
     def _single_builder(self, layout, kmax):
         """Single-model builder for an explicit chip state — shared by the
@@ -567,43 +514,13 @@ class TCAMServer:
 
         return build
 
-    def _forest_builder(self):
-        """Forest builder: per (bucket, engine), a list of ``serve_group``
-        calls, one per plan group — each expands the batch's range indices
-        into its banks' search words, evaluates its whole stack of banks in
-        a single kernel invocation on the group's placed grids and reduces
-        the match to (3, G, B) on the device.  The grids, each group's real
-        rows and divisions and its column tables are placed once and passed
-        as arguments."""
-        groups = list(zip(self._f_plan.groups, self._f_group_kmax,
-                          self._f_group_cols))
-        placed = {}
-
-        def build(bucket: int, engine: str):
-            if engine not in placed:
-                placed[engine] = [
-                    (place_cells(g.cells, g.s, km, engine=engine),
-                     jnp.asarray(g.rows, jnp.int32),
-                     jnp.asarray(g.d_real, jnp.int32),
-                     *map(jnp.asarray, cols))
-                    for g, km, cols in groups
-                ]
-            return [functools.partial(serve_group, *args,
-                                      interpret=self.interpret)
-                    for args in placed[engine]]
-
-        return build
-
     def warmup(self) -> int:
         """Pre-compile every bucket shape for the resolved engine so no
         request ever pays the trace+compile cost; returns #compiles."""
+        if self._forest is not None:
+            return self._fx.warmup()
         before = self.cache.misses
         for b in self.policy.buckets:
-            if self._forest is not None:
-                u = jnp.zeros((b, self._n_features), jnp.int32)
-                for fn in self.cache.get(b, self.engine):
-                    jax.block_until_ready(fn(u))
-                continue
             fn = self.cache.get(b, self.engine)
             w = self._layout.n_cwd * self._layout.s
             jax.block_until_ready(fn(jnp.zeros((b, w), jnp.uint8)))
@@ -881,17 +798,17 @@ class TCAMServer:
 
     def _process_inner_forest(self, batch: list, deadline_flush: bool,
                               sp: _BatchSpans) -> int:
-        """Forest-mode batch: per-group compute + vote aggregation.
+        """Forest-mode batch: the executor's steps, then the vote.
 
-        The host encodes the batch once, as (bucket, F) range indices, and
-        copies them to the device once; every plan group expands them into
-        its banks' search words there.  Per-bank survivors then aggregate
-        into one ensemble vote per request — disabled (defective) banks drop
-        out of both the vote and the divisor."""
+        The executor encodes the batch once, as (bucket, F) range indices,
+        copies them to the device once and starts every plan group on them;
+        each group's output is waited for, copied back and gathered into
+        per-bank survivors.  Those aggregate into one ensemble vote per
+        request — disabled (defective) banks drop out of both the vote and
+        the divisor."""
         from ..forest.compiler import aggregate_votes
-        from ..forest.executor import encode_ranges
 
-        forest = self._forest
+        forest, fx = self._forest, self._fx
         t_form = sp.t_form
         reqs: Sequence[_Request] = [p.item for p in batch]
         queue_lat = np.array([t_form - p.t_enqueue for p in batch])
@@ -905,46 +822,32 @@ class TCAMServer:
             if self._spec.sigma_in > 0:
                 X = X + self._rng.normal(0.0, self._spec.sigma_in,
                                          size=X.shape)
-            Xp = forest.prepare_inputs(X, who="TCAMServer")
-            u = encode_ranges(self._f_union, Xp, bucket)
+            u = fx.encode(X, who="TCAMServer")
 
-        fns = self.cache.get(bucket, self.engine)
         with sp.span("dispatch"):
-            u_dev = jnp.asarray(u)
+            pending = fx.dispatch(u)
             sp.h2d_bytes += u.nbytes
-            pending = []
-            for grp, fn in zip(self._f_plan.groups, fns):
-                pending.append((grp, fn(u_dev)))
-                sp.match_cells += (grp.n_banks * grp.r_pad * grp.width
-                                   * bucket)
-        sp.lut_cells += self._f_lut_cells * n
+            sp.match_cells += fx.match_cells * bucket
+        sp.lut_cells += fx.lut_cells * n
 
-        survivors = np.empty((forest.n_banks, n), np.int32)
-        n_survivors = np.empty((forest.n_banks, n), np.int32)
-        active = np.empty((forest.n_banks, n), np.int64)
-        for grp, out in pending:
+        out = fx.outputs(n)
+        survivors, n_survivors, active = out
+        for gi, dev in enumerate(pending):
             with sp.span("device_wait"):
-                jax.block_until_ready(out)
+                jax.block_until_ready(dev)
             with sp.span("d2h"):
-                host = np.asarray(out)
+                host = np.asarray(dev)
                 sp.d2h_bytes += host.nbytes
             with sp.span("finalize"):
-                first, ns, act = host[:, :, :n]
-                n_survivors[grp.bank_ids] = ns
-                active[grp.bank_ids] = act
-                # translate physical rows (spares after repair) to LUT rows
-                for slot, bank_id in enumerate(grp.bank_ids):
-                    rm = self._f_row_map[int(bank_id)]
-                    survivors[bank_id] = np.where(ns[slot] > 0,
-                                                  rm[first[slot]], -1)
+                fx.gather(gi, host, out)
         compute_s = self._clock() - t_form
 
         with sp.span("finalize"):
             with sp.span("vote"):
                 predictions, score = aggregate_votes(
-                    forest, survivors, self._f_enabled
+                    forest, survivors, fx.enabled
                 )
-            enabled = self._f_enabled
+            enabled = fx.enabled
             n_voting = int(enabled.sum())
             active_total = active[enabled].sum(axis=0)
             energy = (active_total.astype(np.float64) * self._hw.e_row
@@ -984,7 +887,7 @@ class TCAMServer:
                 self._cond.notify_all()
             # the groups' (3, G, B) outputs, on the device and on the host,
             # go last and inside the span, as in tree mode
-            del pending, out, host
+            del pending, dev, host
         return bucket
 
     # -- lifecycle: shadow deployment, promotion, rollback ------------------
@@ -1244,7 +1147,7 @@ class TCAMServer:
             return [
                 run_bist(lay.cells, intent,
                          used=1 + lay.width, n_rows=lay.n_rows)
-                for lay, intent in zip(self._f_layouts, self._f_intent)
+                for lay, intent in zip(self._fx.layouts, self._f_intent)
             ]
         return run_bist(
             self._layout.cells, self._intent,
@@ -1306,48 +1209,33 @@ class TCAMServer:
             )
         if defects is None:
             defects = self.self_test()
-        reports = []
+        reports, repaired = [], {}
         for i, bist in enumerate(defects):
             if bist.defective_rows.size == 0 or self._f_masks[i] is None:
                 continue
-            new_layout, new_intent, report = repair_layout(
-                self._f_layouts[i], self._f_intent[i], self._f_masks[i],
+            new_layout, self._f_intent[i], report = repair_layout(
+                self._fx.layouts[i], self._f_intent[i], self._f_masks[i],
                 bist.defective_rows,
             )
-            self._f_layouts[i] = new_layout
-            self._f_intent[i] = new_intent
-            # spare rows inherit the LUT row they now carry, so post-repair
-            # survivors (physical spare indices) resolve in vote-table space
-            rm = self._f_row_map[i]
-            for orig, spare in report.assignments.items():
-                rm[int(spare)] = rm[int(orig)]
+            repaired[i] = (new_layout, report.assignments)
             reports.append(report)
             self.metrics_store.on_repair(report.rows_repaired)
             if report.degraded:
-                self._f_enabled[i] = False
+                self._fx.enabled[i] = False
         self._repair_reports.extend(reports)
-        self._rebuild_compute()
+        self._fx.repair(repaired)
+        self.cache = self._fx.cache
         return reports
 
     def disable_bank(self, bank: int) -> None:
         """Drop one bank out of the ensemble vote (degraded operation)."""
         if self._forest is None:
             raise RuntimeError("disable_bank is only valid in forest mode")
-        mask = self._f_enabled.copy()
-        mask[int(bank)] = False
-        if not mask.any():
-            raise RuntimeError("cannot disable the last voting bank")
-        self._f_enabled = mask
+        self._fx.disable_bank(bank)
 
     def _rebuild_compute(self) -> None:
         """Re-key the compile cache after the layout changed (repair) and
         re-resolve engine legality (repair writes can add/remove CELL_MM)."""
-        if self._forest is not None:
-            if self.engine != "ref":
-                self.engine = self._resolve_forest_engine(self._config.engine)
-            self._rebuild_plan()
-            self.cache = self._make_cache()
-            return
         if self.engine != "ref":
             self.engine = self._resolve_engine(self._config.engine)
         self.cache = self._make_cache()
@@ -1540,10 +1428,10 @@ class TCAMServer:
     def health(self) -> dict:
         """Chip-health snapshot: breaker state, canary, spares, repairs."""
         if self._forest is not None:
-            spares_total = sum(l.n_spares for l in self._f_layouts)
+            spares_total = sum(l.n_spares for l in self._fx.layouts)
             spares_free = sum(
                 int((intent[lay.spare_row_indices, 0] == CELL_1).sum())
-                for lay, intent in zip(self._f_layouts, self._f_intent)
+                for lay, intent in zip(self._fx.layouts, self._f_intent)
                 if lay.n_spares
             )
             return {
@@ -1552,7 +1440,7 @@ class TCAMServer:
                 "breaker": self.breaker.snapshot(),
                 "mode": "forest",
                 "n_banks": self._forest.n_banks,
-                "banks_enabled": int(self._f_enabled.sum()),
+                "banks_enabled": int(self._fx.enabled.sum()),
                 "spares_total": spares_total,
                 "spares_free": spares_free,
                 "repair_attempts": len(self._repair_reports),
@@ -1610,7 +1498,7 @@ class TCAMServer:
         """JSON-ready snapshot: serving counters/latency + compile cache +
         chip health + modelled ReCAM hardware figures of merit."""
         if self._forest is not None:
-            figs = forest_figures(self._f_layouts, self._hw)
+            figs = forest_figures(self._fx.layouts, self._hw)
             agg = figs["aggregate"]
             return self.metrics_store.snapshot(
                 engine=self.engine,
@@ -1625,12 +1513,12 @@ class TCAMServer:
                 modelled_mdecs_ensemble=agg["ensemble_decs_pipe"] / 1e6,
                 forest_figures=figs,
                 layout={
-                    "n_banks": self._f_plan.n_banks,
+                    "n_banks": self._fx.plan.n_banks,
                     "n_features": self._n_features,
                     "groups": [
                         {"banks": int(g.n_banks), "r_pad": g.r_pad,
                          "d_pad": g.d_pad, "s": g.s}
-                        for g in self._f_plan.groups
+                        for g in self._fx.plan.groups
                     ],
                 },
             )

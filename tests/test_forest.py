@@ -35,7 +35,7 @@ from repro.forest import (
 from repro.forest.executor import (encode_ranges, group_columns,
                                    union_thresholds)
 from repro.kernels import (expand_ranges, match_cells, place_cells,
-                           serve_group, tcam_match_banked)
+                           serve_group)
 
 sklearn = pytest.importorskip("sklearn")
 from sklearn.ensemble import RandomForestClassifier  # noqa: E402
@@ -73,22 +73,6 @@ def test_sklearn_forest_parity_banked_engine(rf_case):
     ex = repro.ForestExecutor(forest, engine="banked")
     res = ex.infer(Xte)
     np.testing.assert_array_equal(res.predictions, rf.predict(Xte))
-    np.testing.assert_array_equal(res.survivors, ref.survivors)
-    np.testing.assert_array_equal(res.active_evals, ref.active_evals)
-
-
-def test_sklearn_forest_parity_mxu_engine():
-    # one dataset, small batch: the vmapped Pallas kernel runs in interpret
-    # mode on CPU and is slow
-    Xtr, ytr, Xte, yte = load_split("cancer")
-    rf = RandomForestClassifier(
-        n_estimators=5, max_depth=6, random_state=1
-    ).fit(Xtr, ytr)
-    forest = compile_forest(rf, s=128)
-    Xq = Xte[:32]
-    ref = forest_infer_ref(forest, Xq)
-    res = repro.ForestExecutor(forest, engine="mxu").infer(Xq)
-    np.testing.assert_array_equal(res.predictions, rf.predict(Xq))
     np.testing.assert_array_equal(res.survivors, ref.survivors)
     np.testing.assert_array_equal(res.active_evals, ref.active_evals)
 
@@ -201,7 +185,7 @@ def _range_case(rng, g, w, bucket, n, f=16):
 
 
 @pytest.mark.parametrize("n", [5, 16])
-@pytest.mark.parametrize("engine", ["banked", "mxu", "ref"])
+@pytest.mark.parametrize("engine", ["banked", "ref"])
 def test_serve_group_equals_host_reduction(engine, n):
     rng = np.random.default_rng(7)
     cells, kmax, rows, d_real = _group_case(rng)
@@ -403,7 +387,7 @@ def test_forest_repair_keeps_serving(served_forest):
     assert 1 <= health["banks_enabled"] <= forest.n_banks
 
 
-@pytest.mark.parametrize("engine", ["banked", "mxu", "ref"])
+@pytest.mark.parametrize("engine", ["banked", "ref"])
 def test_forest_serving_every_field_per_engine(padded_forest, engine):
     """Every field of every answer equals the oracle's, through batches
     that leave the last bucket partly filled."""
@@ -418,12 +402,12 @@ def test_forest_serving_every_field_per_engine(padded_forest, engine):
     _assert_fields_equal(_fields(results), _ref_fields(forest, X))
 
 
-@pytest.mark.parametrize("engine", ["banked", "mxu", "ref"])
+@pytest.mark.parametrize("engine", ["banked", "ref"])
 def test_forest_repair_survivors_resolve_through_row_map(
         padded_forest, engine, monkeypatch):
     """After spare-row repair, every per-bank survivor the vote sees is the
     host reduction's physical row of the same faulted layouts, translated
-    through ``_f_row_map``; some of them sit on spare rows."""
+    through the executor's row maps; some of them sit on spare rows."""
     forest, Xte = padded_forest
     X = Xte[:48]
     cfg = repro.ServeConfig(engine=engine, max_batch=16, background=False)
@@ -433,7 +417,9 @@ def test_forest_repair_survivors_resolve_through_row_map(
         rng=np.random.default_rng(11),
     )
     srv.repair(srv.self_test())
-    assert 0 < srv._f_enabled.sum() < forest.n_banks
+    fx = srv._fx
+    assert 0 < fx.enabled.sum() < forest.n_banks
+    assert all(k is None for k in fx.kmax)     # no SA offsets: kmax0
     voted = []
 
     def spy(forest, survivors, enabled=None):
@@ -447,20 +433,20 @@ def test_forest_repair_survivors_resolve_through_row_map(
     physical = np.empty((forest.n_banks, len(X)), np.int64)
     lut_rows = np.empty_like(physical)
     active = np.empty_like(physical)
-    for grp, km in zip(srv._f_plan.groups, srv._f_group_kmax):
-        survive, evals = (np.asarray(o) for o in tcam_match_banked(
-            grp.cells, encode_group(forest, grp, Xp), grp.s, km,
-            engine="ref"))
+    for grp in fx.plan.groups:
+        survive, evals = (np.asarray(o) for o in match_cells(
+            place_cells(grp.cells, grp.s, grp.kmax0, engine="ref"),
+            jnp.asarray(encode_group(forest, grp, Xp))))
         first, ns, act = _host_reduce(survive, evals, grp.rows, grp.d_real)
         for slot, bank_id in enumerate(grp.bank_ids):
             physical[bank_id] = np.where(ns[slot] > 0, first[slot], -1)
             lut_rows[bank_id] = np.where(
-                ns[slot] > 0, srv._f_row_map[bank_id][first[slot]], -1)
+                ns[slot] > 0, fx.row_maps[bank_id][first[slot]], -1)
             active[bank_id] = act[slot]
-    n_rows = np.array([lay.n_rows for lay in srv._f_layouts])
+    n_rows = np.array([lay.n_rows for lay in fx.layouts])
     assert (physical >= n_rows[:, None]).any()      # survivors on spares
     np.testing.assert_array_equal(np.concatenate(voted, axis=1), lut_rows)
-    enabled = srv._f_enabled
+    enabled = fx.enabled
     predictions, _ = aggregate_votes(forest, lut_rows, enabled)
     _assert_fields_equal(_fields(results), _forest_fields(
         forest, predictions, lut_rows, active, enabled))
@@ -483,6 +469,128 @@ def test_disable_bank_degrades_gracefully(served_forest):
             srv.disable_bank(b)
     with pytest.raises(RuntimeError, match="last voting bank"):
         srv.disable_bank(forest.n_banks - 1)
+
+
+# --------------------------------------------------------------------------
+# one serving path: the server drives the executor's placed groups
+# --------------------------------------------------------------------------
+def _executor_fields(forest, res) -> dict:
+    """What the server answers per request, from an executor's result."""
+    return {**_forest_fields(forest, res.predictions, res.survivors,
+                             res.active_evals, res.enabled),
+            "score": res.score}
+
+
+def _served_fields(results) -> dict:
+    return {**_fields(results),
+            "score": np.stack([r.score for r in results])}
+
+
+def _assert_same(got: dict, want: dict) -> None:
+    assert got.keys() == want.keys()
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+
+
+def _serve(forest, engine, **kw):
+    cfg = repro.ServeConfig(engine=engine, max_batch=16, min_bucket=4,
+                            background=False)
+    return repro.TCAMServer(forest, config=cfg, **kw)
+
+
+@pytest.mark.parametrize("engine", ["banked", "ref"])
+def test_executor_equals_server_on_repaired_chip(padded_forest, engine):
+    """An executor built from a faulted server's layouts and kmax, handed
+    the same repairs, answers as the server does in every field: its row
+    maps send survivors on spare rows to the rules they carry."""
+    forest, Xte = padded_forest
+    X = Xte[:37]
+    srv = _serve(forest, engine, rng=np.random.default_rng(11),
+                 nonideal=NonIdealSpec(p_sa0=0.002, p_sa1=0.002,
+                                       sa_sigma=0.05))
+    assert any(k is not None for k in srv._fx.kmax)
+    ex = repro.ForestExecutor(forest, engine=engine,
+                              layouts=list(srv._fx.layouts),
+                              kmax=srv._fx.kmax)
+    bists = srv.self_test()
+    reports = srv.repair(bists)
+    banks = [i for i, b in enumerate(bists) if b.defective_rows.size]
+    assert len(banks) == len(reports) and sum(
+        r.rows_repaired for r in reports) > 0
+    ex.repair({i: (srv._fx.layouts[i], r.assignments)
+               for i, r in zip(banks, reports)})
+    assert any((rm[lay.n_rows:] >= 0).any()
+               for rm, lay in zip(ex.row_maps, ex.layouts))
+    assert 0 < srv._fx.enabled.sum() < forest.n_banks
+    res = ex.infer(X, enabled=srv._fx.enabled)
+    assert res.engine == srv.engine == engine
+    # every survivor is a LUT row, those on spare rows included
+    n_rows = np.array([lay.n_rows for lay in ex.layouts])
+    assert (res.survivors < n_rows[:, None]).all()
+    _assert_same(_served_fields(srv.serve(X)), _executor_fields(forest, res))
+
+
+@pytest.mark.parametrize("engine", ["banked", "ref"])
+def test_executor_enabled_mask_equals_server_disable_bank(padded_forest,
+                                                          engine):
+    forest, Xte = padded_forest
+    X = Xte[:21]
+    srv = _serve(forest, engine)
+    ex = repro.ForestExecutor(forest, engine=engine)
+    for bank in (0, forest.n_banks - 1):
+        srv.disable_bank(bank)
+        ex.disable_bank(bank)
+    np.testing.assert_array_equal(ex.enabled, srv._fx.enabled)
+    assert ex.enabled.sum() == forest.n_banks - 2
+    res = ex.infer(X)
+    _assert_same(_served_fields(srv.serve(X)), _executor_fields(forest, res))
+    _assert_same(_executor_fields(forest, res), _executor_fields(
+        forest, forest_infer_ref(forest, X, enabled=ex.enabled)))
+
+
+@pytest.mark.parametrize("engine", ["banked", "ref"])
+def test_two_group_forest_server_executor_ref_agree(engine):
+    """Shallow and deep trees plan into separate groups; the server, the
+    executor and the numpy oracle agree in every field."""
+    Xtr, ytr, Xte, _ = load_split("cancer")
+    trees = (train_forest(Xtr, ytr, n_trees=2, max_depth=3, seed=0)
+             + train_forest(Xtr, ytr, n_trees=2, max_depth=10, seed=0))
+    forest = compile_forest(trees, s=32)
+    X = Xte[:45]
+    ex = repro.ForestExecutor(forest, engine=engine)
+    assert ex.plan.n_groups >= 2
+    srv = _serve(forest, engine)
+    assert srv._fx.plan.plan_id == ex.plan.plan_id
+    want = _executor_fields(forest, forest_infer_ref(forest, X))
+    _assert_same(_served_fields(srv.serve(X)), want)
+    _assert_same(_executor_fields(forest, ex.infer(X)), want)
+
+
+@pytest.mark.parametrize("requested", ["auto", "packed", "mxu"])
+@pytest.mark.parametrize("entry", ["server", "executor"])
+def test_forest_engine_resolution(served_forest, entry, requested):
+    """'auto' is 'banked'; 'packed' falls back to 'banked' with a warning
+    (and a fallback tick on the server); any other name, 'mxu' included,
+    is refused, naming the accepted values."""
+    forest, _ = served_forest
+    if entry == "server":
+        make = lambda: repro.TCAMServer(forest, config=repro.ServeConfig(
+            engine=requested, background=False))
+    else:
+        make = lambda: repro.ForestExecutor(forest, engine=requested)
+    if requested == "mxu":
+        with pytest.raises(ValueError, match="'banked', 'ref'"):
+            make()
+        return
+    if requested == "packed":
+        with pytest.warns(RuntimeWarning, match="falling back to 'banked'"):
+            obj = make()
+    else:
+        obj = make()
+    assert obj.engine == "banked"
+    if entry == "server":
+        assert obj.metrics()["engine_fallbacks"] == (requested == "packed")
+        obj.close()
 
 
 # --------------------------------------------------------------------------

@@ -497,7 +497,7 @@ def _copy_bytes(srv, bucket):
     """(h2d, d2h) bytes of one batch of ``bucket`` rows, from the shapes."""
     if srv._forest is None:
         return bucket * srv._layout.n_cwd * srv._layout.s, 4 * 4 * bucket
-    groups = srv._f_plan.groups
+    groups = srv._fx.plan.groups
     return (bucket * srv._forest.n_features * 4,
             sum(3 * 4 * g.n_banks * bucket for g in groups))
 
@@ -636,16 +636,16 @@ def test_forest_served_from_range_indices_equals_ref(state, spared_forest):
     if state == "disable_bank":
         srv.disable_bank(1)
     elif state == "repair":
-        plan, cols = srv._f_plan, srv._f_group_cols
+        plan, cols = srv._fx.plan, srv._fx.columns
         reports = srv.repair(srv.self_test())
         assert sum(r.rows_repaired for r in reports) > 0
-        assert srv._f_plan is not plan and srv._f_group_cols is not cols
+        assert srv._fx.plan is not plan and srv._fx.columns is not cols
         ref_forest = dataclasses.replace(forest, banks=[
             dataclasses.replace(b, compiled=dataclasses.replace(
                 b.compiled, layout=lay))
-            for b, lay in zip(forest.banks, srv._f_layouts)])
+            for b, lay in zip(forest.banks, srv._fx.layouts)])
     got = _served_fields(srv.serve(X))
-    want = _ref_served_fields(ref_forest, X, srv._f_enabled)
+    want = _ref_served_fields(ref_forest, X, srv._fx.enabled)
     for k in want:
         np.testing.assert_array_equal(got[k], want[k], err_msg=k)
     rec = srv.metrics_store.batch_records()
@@ -734,7 +734,7 @@ def test_batch_records_vote_and_cells(mode, iris_model, iris_forest):
         assert (rec["vote_s"] == 0).all()
     else:
         per_row = sum(g.n_banks * g.r_pad * g.width
-                      for g in srv._f_plan.groups)
+                      for g in srv._fx.plan.groups)
         lut = sum(b.lut.n_total for b in model.banks)
         assert (rec["vote_s"] > 0).all()
         assert (rec["vote_s"] <= rec["finalize_s"]).all()
