@@ -763,34 +763,10 @@ class TCAMServer:
                       + self._hw.e_mem)
 
         with sp.span("resolve"):
-            self.metrics_store.on_batch(
-                n, bucket,
-                deadline_flush=deadline_flush,
-                energy_j=float(energy.sum()),
-                active_evals=int(active.sum()),
-            )
-            self.metrics_store.queue.record_many(queue_lat)
-            self.metrics_store.compute.record(compute_s)
-            self.metrics_store.total.record_many(queue_lat + compute_s)
-
-            for i, req in enumerate(reqs):
-                req.future.set_result(
-                    RequestResult(
-                        prediction=int(preds[i]),
-                        survivor=int(survivors[i]),
-                        n_survivors=int(nsurv[i]),
-                        active_evals=int(active[i]),
-                        energy_j=float(energy[i]),
-                        queue_s=float(queue_lat[i]),
-                        compute_s=compute_s,
-                        bucket=bucket,
-                        engine=self.engine,
-                        batch=sp.batch,
-                    )
-                )
-            with self._cond:
-                self._outstanding -= n
-                self._cond.notify_all()
+            self._resolve(sp, reqs, queue_lat, compute_s, bucket,
+                          deadline_flush, predictions=preds,
+                          survivors=survivors, n_survivors=nsurv,
+                          active=active, energy=energy)
             # the outputs go last and inside the span: the callers' threads
             # often hold the interpreter lock while they are freed
             del out, host
@@ -854,41 +830,53 @@ class TCAMServer:
                       + n_voting * self._hw.e_mem)
 
         with sp.span("resolve"):
-            self.metrics_store.on_batch(
-                n, bucket,
-                deadline_flush=deadline_flush,
-                energy_j=float(energy.sum()),
-                active_evals=int(active_total.sum()),
-            )
-            self.metrics_store.queue.record_many(queue_lat)
-            self.metrics_store.compute.record(compute_s)
-            self.metrics_store.total.record_many(queue_lat + compute_s)
-
-            for i, req in enumerate(reqs):
-                pred = predictions[i]
-                req.future.set_result(
-                    RequestResult(
-                        prediction=(int(pred) if np.issubdtype(
-                            np.asarray(pred).dtype, np.integer) else pred),
-                        survivor=-1,   # ensemble decision: no single row
-                        n_survivors=int((n_survivors[enabled, i] > 0).sum()),
-                        active_evals=int(active_total[i]),
-                        energy_j=float(energy[i]),
-                        queue_s=float(queue_lat[i]),
-                        compute_s=compute_s,
-                        bucket=bucket,
-                        engine=self.engine,
-                        batch=sp.batch,
-                        score=score[i],
-                    )
-                )
-            with self._cond:
-                self._outstanding -= n
-                self._cond.notify_all()
+            self._resolve(sp, reqs, queue_lat, compute_s, bucket,
+                          deadline_flush, predictions=predictions,
+                          survivors=None,
+                          n_survivors=(n_survivors[enabled] > 0).sum(axis=0),
+                          active=active_total, energy=energy, score=score)
             # the groups' (3, G, B) outputs, on the device and on the host,
             # go last and inside the span, as in tree mode
             del pending, dev, host
         return bucket
+
+    def _resolve(self, sp: _BatchSpans, reqs: Sequence[_Request],
+                 queue_lat: np.ndarray, compute_s: float, bucket: int,
+                 deadline_flush: bool, *, predictions: np.ndarray,
+                 survivors: Optional[np.ndarray], n_survivors: np.ndarray,
+                 active: np.ndarray, energy: np.ndarray,
+                 score: Optional[np.ndarray] = None) -> None:
+        """Record a batch's metrics, resolve each request's Future and
+        release the batch.  Every per-request field is turned into a Python
+        list once a batch (``tolist`` gives Python ints, floats and the
+        labels' own values), so the loop does no numpy work per request.
+        ``survivors`` is None for an ensemble decision (no single row: -1);
+        ``score`` rows are views of the batch's array."""
+        n = len(reqs)
+        self.metrics_store.on_batch(
+            n, bucket,
+            deadline_flush=deadline_flush,
+            energy_j=float(energy.sum()),
+            active_evals=int(active.sum()),
+        )
+        self.metrics_store.queue.record_many(queue_lat)
+        self.metrics_store.compute.record(compute_s)
+        self.metrics_store.total.record_many(queue_lat + compute_s)
+
+        rows = zip(
+            reqs, predictions.tolist(),
+            [-1] * n if survivors is None else survivors.tolist(),
+            n_survivors.tolist(), active.tolist(), energy.tolist(),
+            queue_lat.tolist(), [None] * n if score is None else score,
+        )
+        engine, batch = self.engine, sp.batch
+        for req, pred, surv, nsurv, act, energy_j, queue_s, row in rows:
+            req.future.set_result(RequestResult(
+                pred, surv, nsurv, act, energy_j, queue_s, compute_s, bucket,
+                engine, batch, row))
+        with self._cond:
+            self._outstanding -= n
+            self._cond.notify_all()
 
     # -- lifecycle: shadow deployment, promotion, rollback ------------------
     _SWAP_ATTRS = ("_lut", "_intent", "_saf_mask", "_layout", "_base_layout",

@@ -599,6 +599,15 @@ def spared_forest():
 
 
 def _served_fields(results) -> dict:
+    """The answer fields of ``results``, as arrays, once each result has
+    been checked to hold Python scalars (not numpy ones)."""
+    for r in results:
+        for k in ("survivor", "n_survivors", "active_evals"):
+            assert type(getattr(r, k)) is int, k
+        for k in ("energy_j", "queue_s", "compute_s"):
+            assert type(getattr(r, k)) is float, k
+        if np.issubdtype(np.asarray(r.prediction).dtype, np.integer):
+            assert type(r.prediction) is int
     keys = ("prediction", "survivor", "n_survivors", "active_evals",
             "energy_j", "score")
     return {k: np.array([getattr(r, k) for r in results]) for k in keys}
@@ -652,6 +661,72 @@ def test_forest_served_from_range_indices_equals_ref(state, spared_forest):
     assert (rec["n"] < rec["bucket"]).any()
     np.testing.assert_array_equal(rec["h2d_bytes"],
                                   rec["bucket"] * forest.n_features * 4)
+
+
+def _served_case(case, iris_model, spared_forest):
+    """(server, rows, reference fields) for one kind of served model."""
+    cfg = ServeConfig(max_batch=16, min_bucket=4, background=False)
+    if case == "tree":
+        m, X, _ = iris_model
+        ref = m.infer(X, backend="sim")
+        return TCAMServer(m.compiled, config=cfg), X, {
+            "prediction": ref.predictions, "survivor": ref.survivors,
+            "n_survivors": ref.n_survivors,
+            "active_evals": ref.active_evals,
+            "energy_j": ref.energy_per_dec, "score": np.full(len(X), None)}
+    from repro.forest import compile_forest
+    if case == "soft":
+        from sklearn.ensemble import RandomForestClassifier
+        Xtr, ytr, X, _ = load_split("iris")
+        forest = compile_forest(RandomForestClassifier(
+            n_estimators=5, max_depth=4, random_state=0).fit(Xtr, ytr),
+            s=32, vote="soft")
+    elif case == "boosted":
+        model, X = hgb_case("multiclass")
+        forest = compile_forest(model, s=32)
+    else:
+        forest, X = spared_forest
+    srv = TCAMServer(forest, config=cfg)
+    if case == "hard-disable_bank":
+        srv.disable_bank(1)
+    return srv, X, _ref_served_fields(forest, X, srv._fx.enabled)
+
+
+@pytest.mark.parametrize("case",
+                         ["tree", "soft", "hard-disable_bank", "boosted"])
+def test_served_fields_are_python_scalars_equal_to_ref(
+        case, iris_model, spared_forest):
+    """Requests are resolved from per-batch lists: every field of every
+    kind of model still equals its reference, and holds a Python ``int``
+    or ``float``."""
+    srv, X, want = _served_case(case, iris_model, spared_forest)
+    results = srv.serve(X)
+    got = _served_fields(results)
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+    assert all(type(r.prediction) is int for r in results)
+
+
+@pytest.mark.parametrize("mode", ["tree", "forest"])
+def test_batch_resolved_at_once_shares_fields_and_counters(
+        mode, iris_model, iris_forest):
+    """The requests of one batch share its id, bucket and ``compute_s``,
+    and the served counters are the sums of the results."""
+    model, X = ((iris_model[0].compiled, iris_model[1]) if mode == "tree"
+                else iris_forest)
+    srv, results = _served(model, X, [16, 3, 9])
+    batches = {}
+    for r in results:
+        batches.setdefault(r.batch, []).append(r)
+    assert [len(b) for b in batches.values()] == [16, 3, 9]
+    energy_j = 0.0
+    for b in batches.values():
+        assert len({(r.bucket, r.compute_s, r.engine) for r in b}) == 1
+        energy_j += float(np.sum([r.energy_j for r in b]))
+    m = srv.metrics_store
+    assert m.requests_served == len(results)
+    assert m.active_evals == sum(r.active_evals for r in results)
+    assert m.energy_j == energy_j
 
 
 # --------------------------------------------------------------------------
