@@ -19,8 +19,10 @@ which ``TCAMServer`` calls inside its spans and ``infer`` composes:
 * ``gather`` — one group's (3, G, B) host array into per-bank survivors,
   translated through the row maps, survivor counts and active evaluations.
 
-Engines: 'banked' (the batched einsum, ``kernels.ref.tcam_match_banked_ref``;
-what 'auto' picks) and 'ref' (the vmapped jnp oracle ``tcam_match_ref``),
+Engines: 'banked' (the batched einsum, ``kernels.ref.tcam_match_banked_ref``,
+or for a group too large for it the same match streamed division by
+division, ``tcam_match_banked_stream``; what 'auto' picks) and 'ref' (the
+vmapped jnp oracle ``tcam_match_ref``),
 both through the same ``serve_group``; the numpy ``forest_infer_ref`` is the
 oracle the tests hold them to.
 
@@ -215,6 +217,9 @@ class ForestExecutor:
         # cells the device compares for one request, padding included
         self.match_cells = sum(g.n_banks * g.r_pad * g.width
                                for g in self.plan.groups)
+        # bytes of the placed operands the match reads (bitplanes, kmax)
+        self.match_bytes = sum(a.nbytes for ops, *_ in self._placed
+                               for a in ops.arrays)
         self.cache = CompileCache(self._build, self.plan.plan_id,
                                   maxsize=self._cache_size)
 

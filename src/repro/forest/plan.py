@@ -116,7 +116,7 @@ def plan_forest(layouts_or_forest) -> ForestPlan:
             cells=cells, kmax0=kmax0,
             rows=rows[ids], d_real=divs[ids],
         ))
-        digest.update(cells.tobytes())
+        digest.update(cells)          # its buffer: no copy of the grid
         digest.update(np.asarray(ids, np.int64).tobytes())
     for lay in layouts:
         digest.update(lay.classes.tobytes())

@@ -10,14 +10,15 @@ analog-ReCAM -> TPU mapping.
                    batch, a forest plan group's batch)
   ref.py         — pure-jnp oracles both kernels are validated against,
                    and the forest 'banked' engine (a batched einsum over a
-                   leading bank axis)
+                   leading bank axis, streamed division by division for
+                   groups too large for one einsum)
 """
-from .ops import (ENGINES, CellOperands, default_interpret, expand_ranges,
-                  finalize_result, match_cells, place_cells, sa_kmax,
-                  select_engine, serve_batch, serve_group, tcam_infer,
-                  tcam_match)
-from .ref import (pack_bits, tcam_match_banked_ref, tcam_match_packed_ref,
-                  tcam_match_ref)
+from .ops import (ENGINES, CellOperands, banked_streams, default_interpret,
+                  expand_ranges, finalize_result, match_cells, place_cells,
+                  sa_kmax, select_engine, serve_batch, serve_group,
+                  tcam_infer, tcam_match)
+from .ref import (pack_bits, tcam_match_banked_ref, tcam_match_banked_stream,
+                  tcam_match_packed_ref, tcam_match_ref)
 from .tcam_match import tcam_match_pallas
 from .tcam_packed import tcam_match_packed_pallas
 
@@ -28,5 +29,5 @@ __all__ = [
     "expand_ranges", "serve_group",
     "pack_bits", "tcam_match_packed_ref", "tcam_match_ref",
     "tcam_match_pallas", "tcam_match_packed_pallas",
-    "tcam_match_banked_ref",
+    "tcam_match_banked_ref", "tcam_match_banked_stream", "banked_streams",
 ]

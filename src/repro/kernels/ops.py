@@ -9,7 +9,9 @@ Engines:
              requires S % 32 == 0 and no CELL_MM cells.
   'ref'    — pure-jnp oracle (ref.py).
   'auto'   — packed when legal, else mxu.
-  'banked' — batched einsum over a leading bank axis (forest groups only).
+  'banked' — batched einsum over a leading bank axis (forest groups only);
+             a group whose one-shot einsum would not fit the device streams
+             its divisions instead (``banked_streams``).
 
 All engines share the contract: inputs are the *padded search words* from
 ``TCAMLayout.pad_inputs`` (decoder bit + encoded features + padding) and the
@@ -38,19 +40,24 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.energy import DEFAULT_HW, HardwareParams, f_max, t_cwd
-from ..core.lut import CELL_MM, bitplanes
+from ..core.lut import CELL_0, CELL_1, CELL_MM, bitplanes
 from ..core.simulate import SimResult, sense_voltage
 from ..core.synth import TCAMLayout
-from .ref import pack_bits, tcam_match_banked_ref, tcam_match_ref
+from .ref import (pack_bits, tcam_match_banked_ref, tcam_match_banked_stream,
+                  tcam_match_ref)
 from .tcam_match import tcam_match_pallas
 from .tcam_packed import tcam_match_packed_pallas
 
 __all__ = ["tcam_match", "tcam_infer", "sa_kmax", "select_engine",
            "finalize_result", "default_interpret", "ENGINES", "CellOperands",
            "place_cells", "match_cells", "serve_batch", "expand_ranges",
-           "serve_group"]
+           "serve_group", "banked_streams", "ONE_SHOT_BYTES"]
 
 ENGINES = ("auto", "mxu", "packed", "ref")
+
+# The most f32 intermediate the one-shot banked einsum may build: a quarter
+# of one TPU v5e chip's 16 GB of HBM.
+ONE_SHOT_BYTES = 2 ** 32
 
 
 def default_interpret() -> bool:
@@ -93,6 +100,16 @@ class CellOperands:
     block_r: int
 
 
+def banked_streams(g: int, b: int, r: int, d: int, s: int) -> bool:
+    """Whether a plan group of ``g`` banks of ``r`` rows and ``d`` divisions
+    of width ``s``, matched against a ``b``-row batch, takes the streamed
+    banked form (``ref.tcam_match_banked_stream``).  The one-shot form
+    (``ref.tcam_match_banked_ref``) builds the (G, B, R, D) f32 mismatch
+    counts and casts both (G, R, D·S) planes to f32; it is kept while those
+    stay within ``ONE_SHOT_BYTES``."""
+    return 4 * g * r * (b * d + 2 * d * s) > ONE_SHOT_BYTES
+
+
 def _division_major(a, width: int):
     """(..., N, D·width) -> (..., D, N, width): one division per leading
     slice."""
@@ -107,6 +124,19 @@ def _pack_words(bits: np.ndarray) -> np.ndarray:
     assert w % 32 == 0, w
     b = bits.astype(np.uint32).reshape(*lead, w // 32, 32)
     return (b << np.arange(32, dtype=np.uint32)).sum(-1, dtype=np.uint32)
+
+
+def _device_plane(cells: np.ndarray, state: int) -> jax.Array:
+    """``bitplanes``' u8 plane of ``state`` (a ``CELL_MM`` cell sets both),
+    built one bank at a time and copied to the device: the host holds one
+    plane, not each comparison's full-size temporary."""
+    plane = np.empty(cells.shape, np.uint8)
+    grid = cells.shape[-2:]
+    for src, dst in zip(cells.reshape(-1, *grid),
+                        plane.reshape(-1, *grid).view(bool)):
+        np.equal(src, state, out=dst)
+        dst |= src == CELL_MM
+    return jnp.asarray(plane)
 
 
 def place_cells(
@@ -125,10 +155,11 @@ def place_cells(
     d = w // s
     km = (np.zeros((*lead, r, d), np.int32) if kmax is None
           else np.asarray(kmax, np.int32))
-    is0, is1 = bitplanes(cells)
     if engine in ("ref", "banked"):
-        arrays = (is0, is1, km)
+        arrays = (_device_plane(cells, CELL_0), _device_plane(cells, CELL_1),
+                  km)
     elif engine in ("mxu", "packed"):
+        is0, is1 = bitplanes(cells)
         rows_pad = [(0, 0)] * len(lead) + [(0, (-r) % block_r), (0, 0)]
         km = np.pad(km, rows_pad, constant_values=-1)
         km = km.swapaxes(-1, -2)[..., None, :]              # (..., D, 1, R)
@@ -178,7 +209,10 @@ def match_cells(
     *lead, b, _ = xpad.shape
     s = ops.s
     if ops.engine == "banked":
-        return tcam_match_banked_ref(xpad, *ops.arrays[:2], s, ops.arrays[2])
+        g, r, w = ops.arrays[0].shape
+        fn = (tcam_match_banked_stream if banked_streams(g, b, r, w // s, s)
+              else tcam_match_banked_ref)
+        return fn(xpad, *ops.arrays[:2], s, ops.arrays[2])
     if ops.engine == "ref":
         fn = lambda x, p0, p1, km: tcam_match_ref(x, p0, p1, s, km)
     else:
@@ -311,8 +345,9 @@ def serve_group(ops: CellOperands, rows: jax.Array, d_real: jax.Array,
     the placed cells, so a plan rebuilt with the same shapes reuses the
     compiled program."""
     xpad = expand_ranges(feat, q, u)
-    survive, evals = match_cells(ops, xpad, block_b=block_b,
-                                 interpret=interpret)
+    with jax.named_scope("forest.match"):
+        survive, evals = match_cells(ops, xpad, block_b=block_b,
+                                     interpret=interpret)
     real = jnp.arange(survive.shape[-1]) < rows[:, None, None]
     survive = jnp.where(real, survive, 0)
     evals = jnp.where(real, jnp.minimum(evals, d_real[:, None, None]), 0)

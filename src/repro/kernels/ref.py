@@ -25,9 +25,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 __all__ = ["tcam_match_ref", "tcam_match_packed_ref", "tcam_match_banked_ref",
-           "pack_bits"]
+           "tcam_match_banked_stream", "pack_bits"]
 
 
 def tcam_match_ref(
@@ -156,3 +157,55 @@ def tcam_match_banked_ref(
     survive = (prior[:, :, :, -1] & match[:, :, :, -1]).astype(jnp.int32)
     evals = prior.sum(axis=3).astype(jnp.int32)
     return survive, evals
+
+
+@functools.partial(jax.jit, static_argnames=("s",))
+def tcam_match_banked_stream(
+    xpad: jax.Array,    # (G, B, W) {0,1} search words, per-bank encodings
+    is0: jax.Array,     # (G, R, W)
+    is1: jax.Array,     # (G, R, W)
+    s: int,
+    kmax: jax.Array,    # (G, R, D) int32; -1 rows always mismatch
+) -> tuple[jax.Array, jax.Array]:
+    """``tcam_match_banked_ref`` streamed division by division, as the
+    array precharges them: the same (survive, evals), both (G, B, R) int32,
+    bit for bit, without its (G, B, R, D) counts or f32 copies of the
+    planes, so a group of deep, wide banks fits the device.
+
+    Each step is one einsum of division d's (G, B, S) search words against
+    its (G, R, S) slice of ``is0 - is1``, bf16 operands in {-1, 0, 1}
+    accumulated in f32, so the counts are exact; adding the row's count of
+    ``is1`` cells in the division gives the mismatch count
+
+      mism[g, b, r] = Σ_{w∈d} x·is0 + (1 - x)·is1
+                    = Σ_{w∈d} x·(is0 - is1) + Σ_{w∈d} is1.
+
+    The loop carries the precharge as one (G, B, R) array: the division in
+    which each row first mismatched, -1 while it is still alive.  A row
+    alive after the last division survives; a row's evaluations are its
+    first mismatching division plus one, or D."""
+    g, b, w = xpad.shape
+    r = is0.shape[1]
+    assert w % s == 0, (w, s)
+    d = w // s
+    bf16 = jnp.bfloat16
+    x = xpad.astype(bf16)
+    dt = jnp.int8 if d <= 128 else jnp.int32     # holds -1 .. d - 1
+
+    def step(j, died):
+        lo = j * s
+        xs = lax.dynamic_slice_in_dim(x, lo, s, axis=2)
+        p0 = lax.dynamic_slice_in_dim(is0, lo, s, axis=2)
+        p1 = lax.dynamic_slice_in_dim(is1, lo, s, axis=2)
+        diff = p0.astype(bf16) - p1.astype(bf16)
+        m = jnp.einsum("gbs,grs->gbr", xs, diff,
+                       preferred_element_type=jnp.float32)
+        km = lax.dynamic_index_in_dim(kmax, j, axis=2, keepdims=False)
+        km = (km - p1.astype(jnp.int32).sum(axis=2)).astype(jnp.float32)
+        miss = m > km[:, None, :]
+        return jnp.where((died < 0) & miss, j.astype(dt), died)
+
+    died = lax.fori_loop(0, d, step, jnp.full((g, b, r), -1, dt))
+    alive = died < 0
+    return (alive.astype(jnp.int32),
+            jnp.where(alive, d, died.astype(jnp.int32) + 1))

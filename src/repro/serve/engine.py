@@ -185,7 +185,8 @@ class _BatchSpans:
     the batch.  ``h2d_bytes`` and ``d2h_bytes`` count the arrays copied to
     and from the device; ``match_cells`` the cells the device compared
     (padding included) and ``lut_cells`` the model's own cells, times the
-    requests."""
+    requests; ``match_bytes`` the bytes of the placed operands the forest's
+    match read."""
 
     def __init__(self, batch: int, clock: Callable[[], float]) -> None:
         self.batch = batch
@@ -196,6 +197,7 @@ class _BatchSpans:
         self.d2h_bytes = 0
         self.match_cells = 0
         self.lut_cells = 0
+        self.match_bytes = 0
 
     @contextlib.contextmanager
     def span(self, phase: str):
@@ -213,7 +215,8 @@ class _BatchSpans:
                                 [self.seconds[p] for p in PHASES],
                                 self.h2d_bytes, self.d2h_bytes,
                                 [self.seconds[p] for p in NESTED],
-                                self.match_cells, self.lut_cells)
+                                self.match_cells, self.lut_cells,
+                                self.match_bytes)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -804,6 +807,7 @@ class TCAMServer:
             pending = fx.dispatch(u)
             sp.h2d_bytes += u.nbytes
             sp.match_cells += fx.match_cells * bucket
+            sp.match_bytes += fx.match_bytes
         sp.lut_cells += fx.lut_cells * n
 
         out = fx.outputs(n)

@@ -9,7 +9,8 @@ seconds of each of the batch's spans (``PHASES``, and ``vote_s`` for the
 ``NESTED`` span), the bytes it copied to and from the device, and the
 cells its match compared (``match_cells``: every placed cell, padding
 included, times the bucket) against the cells the model has (``lut_cells``:
-LUT rows times LUT columns, summed over banks, times the requests).
+LUT rows times LUT columns, summed over banks, times the requests), and the
+bytes of the placed operands a forest's match read (``match_bytes``).
 """
 from __future__ import annotations
 
@@ -33,7 +34,7 @@ BATCH_RECORD = np.dtype(
     + [(f"{p}_s", "f8") for p in PHASES]
     + [("h2d_bytes", "i8"), ("d2h_bytes", "i8")]
     + [(f"{p}_s", "f8") for p in NESTED]
-    + [("match_cells", "i8"), ("lut_cells", "i8")])
+    + [("match_cells", "i8"), ("lut_cells", "i8"), ("match_bytes", "i8")])
 
 
 class LatencyStats:
@@ -230,14 +231,16 @@ class ServeMetrics:
     def on_batch_record(self, batch: int, t_form: float, n: int,
                         bucket: int, seconds, h2d_bytes: int,
                         d2h_bytes: int, nested=(0.0,) * len(NESTED),
-                        match_cells: int = 0, lut_cells: int = 0) -> None:
+                        match_cells: int = 0, lut_cells: int = 0,
+                        match_bytes: int = 0) -> None:
         """One batch's record: its id, formation time, size, the seconds of
         each span in ``PHASES`` order, its copies' bytes, the seconds of
-        each ``NESTED`` span, and its match's and model's cells."""
+        each ``NESTED`` span, its match's and model's cells, and the bytes
+        of the placed operands its match read."""
         with self._lock:
             self._records[self._n_records % self._records.size] = (
                 batch, t_form, n, bucket, *seconds, h2d_bytes, d2h_bytes,
-                *nested, match_cells, lut_cells)
+                *nested, match_cells, lut_cells, match_bytes)
             self._n_records += 1
             self.h2d_bytes += h2d_bytes
             self.d2h_bytes += d2h_bytes

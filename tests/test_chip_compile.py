@@ -25,6 +25,12 @@ CREDIT = dict(s=128, d=39, r=8576)
 # scikit-learn's default 100-tree forest on the COVID-19 shape at S=128:
 # one plan group of 100 banks, 4096 rows, 4 divisions, 4 features.
 COVID_RF100 = dict(g=100, s=128, d=4, r=4096, f=4)
+# One chip's 10-tree share of the full-depth 100-tree forest on the credit
+# shape at S=128: one plan group of 10 banks of 32768 rows and 128
+# divisions (19840-20480 rows and 84-86 divisions before padding).
+CREDIT_RF100_SHARD10 = dict(g=10, s=128, d=128, r=32768, f=10)
+# what a one-chip cell may hold on the device (memory_peak_bytes)
+CHIP_BYTES = 14e9
 
 
 @pytest.fixture(scope="module")
@@ -116,25 +122,45 @@ def test_narrow_tiles_compile(one_chip, engine, s, b):
                       ops, x)
 
 
-@pytest.mark.parametrize("engine", ["banked"])
-def test_forest_group_program_compiles(one_chip, engine):
-    """The server's whole batch program for one forest plan group at the
-    100-tree forest's size, bucket 256: (256, F) int32 range indices in,
-    expanded through (G, W) column tables, (3, G, B) int32 back."""
-    g, s, d, r, f = (COVID_RF100[k] for k in ("g", "s", "d", "r", "f"))
+def _group_program(sharding, g, s, d, r, f, b, engine="banked"):
+    """The server's whole batch program for one forest plan group,
+    compiled: (b, F) int32 range indices in, expanded through (G, W)
+    column tables, (3, G, b) int32 back."""
     arrays = tuple(
-        jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+        jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
         for shape, dt in [((g, r, d * s), jnp.uint8)] * 2
         + [((g, r, d), jnp.int32)])
     ops = CellOperands(arrays=arrays, engine=engine, s=s, rows=r,
                        block_r=128)
-    per_bank = jax.ShapeDtypeStruct((g,), jnp.int32, sharding=one_chip)
-    column = jax.ShapeDtypeStruct((g, d * s), jnp.int32, sharding=one_chip)
-    u = jax.ShapeDtypeStruct((256, f), jnp.int32, sharding=one_chip)
+    per_bank = jax.ShapeDtypeStruct((g,), jnp.int32, sharding=sharding)
+    column = jax.ShapeDtypeStruct((g, d * s), jnp.int32, sharding=sharding)
+    u = jax.ShapeDtypeStruct((b, f), jnp.int32, sharding=sharding)
     compiled = jax.jit(
         lambda o, rows, d_real, feat, q, u: serve_group(
             o, rows, d_real, feat, q, u, interpret=False)
     ).lower(ops, per_bank, per_bank, column, column, u).compile()
     assert "tpu_custom_call" not in compiled.as_text()   # no Pallas kernel
     out = compiled.out_info
-    assert (out.shape, out.dtype) == ((3, g, 256), jnp.int32)
+    assert (out.shape, out.dtype) == ((3, g, b), jnp.int32)
+    return compiled
+
+
+@pytest.mark.parametrize("engine", ["banked"])
+def test_forest_group_program_compiles(one_chip, engine):
+    """The 100-tree covid forest's group, bucket 256."""
+    _group_program(one_chip, **COVID_RF100, b=256, engine=engine)
+
+
+@pytest.mark.parametrize("b", [8, 256])
+def test_deep_forest_group_streams_within_one_chip(one_chip, b):
+    """The credit forest share's group takes the streamed banked form at
+    every bucket, and its placed planes with the program's temporaries
+    fit one chip: the one-shot form's (G, B, R, D) counts alone would be
+    43 GB at bucket 256."""
+    from repro.kernels import banked_streams
+    cfg = CREDIT_RF100_SHARD10
+    assert banked_streams(cfg["g"], b, cfg["r"], cfg["d"], cfg["s"])
+    mem = _group_program(one_chip, **cfg, b=b).memory_analysis()
+    assert mem.argument_size_in_bytes > 2 * 5.3e9        # the two planes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < CHIP_BYTES

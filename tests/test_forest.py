@@ -726,3 +726,154 @@ def test_top_level_api_resolves():
     assert repro.TCAMServer.__module__.startswith("repro.serve")
     with pytest.raises(AttributeError):
         repro.not_a_public_name
+
+
+# --------------------------------------------------------------------------
+# deep banks: the banked match streamed division by division
+# --------------------------------------------------------------------------
+def _deep_group_case(seed, g, r_pad, d_pad, s):
+    """A plan group of deep, sparse banks: real rows and divisions below
+    the padded shape (pad rows kmax = -1, all-X pad divisions), and some
+    real rows that tolerate one or two mismatches (kmax > 0)."""
+    rng = np.random.default_rng(seed)
+    cells = np.full((g, r_pad, d_pad * s), CELL_X, np.int8)
+    kmax = np.zeros((g, r_pad, d_pad), np.int32)
+    rows = rng.integers(r_pad // 2, r_pad + 1, g)
+    d_real = rng.integers(d_pad // 2, d_pad + 1, g)
+    for i in range(g):
+        r, w = rows[i], d_real[i] * s
+        care = rng.random((r, w)) < 2.0 / w
+        cells[i, :r, :w] = np.where(care, rng.integers(0, 2, (r, w)), CELL_X)
+        kmax[i, :r] = np.where(rng.random((r, 1)) < 0.2,
+                               rng.integers(1, 3, (r, d_pad)), 0)
+        kmax[i, r:] = -1
+    return cells, kmax
+
+
+@pytest.mark.parametrize("seed,g,b,r_pad,d_pad,s", [
+    (0, 3, 8, 64, 16, 8),
+    (1, 2, 5, 40, 20, 8),
+    (2, 4, 16, 32, 17, 16),
+    (3, 1, 3, 96, 33, 4),
+])
+def test_streamed_banked_match_equals_one_shot(seed, g, b, r_pad, d_pad, s):
+    """``tcam_match_banked_stream`` gives the one-shot form's survive and
+    evals bit for bit, pad rows and all-X pad divisions included."""
+    from repro.core.lut import bitplanes
+    from repro.kernels import tcam_match_banked_ref, tcam_match_banked_stream
+    cells, kmax = _deep_group_case(seed, g, r_pad, d_pad, s)
+    rng = np.random.default_rng(seed + 100)
+    x = rng.integers(0, 2, (g, b, d_pad * s)).astype(np.uint8)
+    is0, is1 = bitplanes(cells)
+    want = [np.asarray(a) for a in tcam_match_banked_ref(x, is0, is1, s,
+                                                         kmax)]
+    got = [np.asarray(a) for a in tcam_match_banked_stream(x, is0, is1, s,
+                                                           kmax)]
+    for w, o in zip(want, got):
+        assert o.shape == (g, b, r_pad) and o.dtype == np.int32
+        np.testing.assert_array_equal(o, w)
+    survive, evals = want
+    assert survive.any() and (evals == 1).any() and (evals == d_pad).any()
+    assert ((evals > 1) & (evals < d_pad)).any()
+    tolerant = np.broadcast_to(kmax[:, None, :, 0] > 0, survive.shape)
+    assert survive[tolerant].any()            # a tolerant row lives
+
+
+# the plan groups of the benchmark's forests at S=128, (G, R, D): every
+# bucket of the serving ladder
+_BUCKETS = (8, 16, 32, 64, 128, 256)
+
+
+@pytest.mark.parametrize("g,r,d,streams", [
+    (100, 4096, 4, False),      # covid-rf100: one group
+    (229, 128, 1, False),       # credit-hgb500: three groups
+    (43, 128, 2, False),
+    (228, 256, 2, False),
+    (10, 32768, 128, True),     # credit-rf100-shard10: one group
+])
+def test_banked_form_chosen_from_group_shapes(g, r, d, streams):
+    from repro.kernels import banked_streams
+    assert [banked_streams(g, b, r, d, 128) for b in _BUCKETS] == \
+        [streams] * len(_BUCKETS)
+
+
+def _deep_rf(n_estimators=4, seed=0):
+    """A small full-depth random forest on noisy data: at S=8 every bank
+    spans 16 or more divisions."""
+    rng = np.random.default_rng(seed)
+    X = np.round(rng.normal(size=(900, 8)), 2)
+    y = ((X[:, 0] + X[:, 1] * X[:, 2] > 0) ^ (rng.random(900) < 0.2))
+    rf = RandomForestClassifier(n_estimators=n_estimators,
+                                random_state=0).fit(X[:700], y[:700])
+    return rf, X[700:]
+
+
+def test_full_depth_forest_served_through_streamed_match(monkeypatch):
+    """A full-depth forest whose banks take the streamed banked form is
+    served by ``TCAMServer`` equal to the oracle and to scikit-learn in
+    every field."""
+    import repro.kernels.ops as kops
+    rf, X = _deep_rf()
+    forest = compile_forest(rf, s=8)
+    assert min(lay.n_cwd for lay in forest.layouts) >= 16
+    traced = []
+    stream = kops.tcam_match_banked_stream
+
+    def spy(*args):
+        traced.append(args[1].shape)
+        return stream(*args)
+
+    monkeypatch.setattr(kops, "ONE_SHOT_BYTES", 0)
+    monkeypatch.setattr(kops, "tcam_match_banked_stream", spy)
+    jax.clear_caches()
+    srv = repro.TCAMServer(forest, config=repro.ServeConfig(
+        max_batch=32, min_bucket=8, background=False))
+    results = srv.serve(X)
+    jax.clear_caches()
+    groups = srv._fx.plan.groups
+    assert {(g.n_banks, g.r_pad, g.width) for g in groups} <= set(traced)
+    got = _fields(results)
+    _assert_fields_equal(got, _ref_fields(forest, X))
+    np.testing.assert_array_equal(got["prediction"], rf.predict(X))
+
+
+def test_ten_tree_share_is_the_first_ten_trees():
+    """A 10-tree fit with ``random_state=0`` holds the first ten trees of
+    the 20-tree fit, and the two 10-tree shares' per-bank soft-vote sums
+    add up to the 20-tree forest's."""
+    import copy
+    rf20, X = _deep_rf(20)
+    rf10, _ = _deep_rf(10)
+    for a, b in zip(rf10.estimators_, rf20.estimators_[:10]):
+        for k in ("feature", "threshold", "children_left", "children_right",
+                  "value"):
+            np.testing.assert_array_equal(getattr(a.tree_, k),
+                                          getattr(b.tree_, k))
+    rest = copy.copy(rf20)
+    rest.estimators_ = rf20.estimators_[10:]
+
+    def vote_sum(model):
+        forest = compile_forest(model, s=32)
+        res = repro.ForestExecutor(forest).infer(X)
+        return sum(bank.proba[row]
+                   for bank, row in zip(forest.banks, res.survivors))
+
+    first, second = vote_sum(rf10), vote_sum(rest)
+    np.testing.assert_allclose(first + second, vote_sum(rf20), rtol=0,
+                               atol=1e-12)
+    assert not np.array_equal(first, second)
+
+
+def test_plan_id_hashes_the_grids_in_place():
+    """``plan_forest`` hashes each stacked grid through its buffer: the
+    same ``plan_id`` as a digest of the grids' bytes copied out."""
+    import hashlib
+    forest, _ = _rf("cancer", "soft", n_estimators=5, max_depth=6)
+    plan = plan_forest(forest)
+    digest = hashlib.sha1()
+    for grp in plan.groups:
+        digest.update(grp.cells.tobytes())
+        digest.update(np.asarray(grp.bank_ids, np.int64).tobytes())
+    for lay in forest.layouts:
+        digest.update(lay.classes.tobytes())
+    assert plan.plan_id == digest.hexdigest()[:12]

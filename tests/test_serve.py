@@ -819,6 +819,26 @@ def test_batch_records_vote_and_cells(mode, iris_model, iris_forest):
     assert (rec["lut_cells"] < rec["match_cells"]).all()
 
 
+@pytest.mark.parametrize("mode", ["tree", "forest"])
+def test_batch_records_match_bytes(mode, iris_model, iris_forest):
+    """A forest batch records, once, the bytes of the placed operands its
+    match reads: each plan group's two u8 bitplanes and its int32 kmax, the
+    ``nbytes`` of what the executor placed.  Tree mode records none."""
+    model, X = ((iris_model[0].compiled, iris_model[1]) if mode == "tree"
+                else iris_forest)
+    srv, _ = _served(model, X, [16, 3, 9])
+    rec = srv.metrics_store.batch_records()
+    if srv._forest is None:
+        assert (rec["match_bytes"] == 0).all()
+        return
+    placed = sum(a.nbytes for ops, *_ in srv._fx._placed
+                 for a in ops.arrays)
+    shapes = sum(g.n_banks * g.r_pad * (2 * g.width + 4 * g.d_pad)
+                 for g in srv._fx.plan.groups)
+    assert srv._fx.match_bytes == placed == shapes
+    np.testing.assert_array_equal(rec["match_bytes"], placed)
+
+
 def test_serve_vote_span_in_profiler_trace(iris_forest, tmp_path):
     import glob
 
